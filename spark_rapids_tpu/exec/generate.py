@@ -126,7 +126,8 @@ class TpuGenerateExec(CpuGenerateExec):
                     fan = jnp.where(in_row & (lens == 0), 1, lens)
                 else:
                     fan = lens
-                cum = jnp.cumsum(fan)
+                from spark_rapids_tpu.ops.batch_ops import prefix_sum
+                cum = prefix_sum(fan, jnp)
                 total = int(cum[-1])           # ONE sync: output size
                 if total == 0:
                     continue

@@ -4,10 +4,17 @@ Reference: GpuSortExec.scala + SortUtils.scala lower sorting to cuDF
 ``Table.sortOrder``/``gather``.  TPU-first redesign: every key column is
 normalized into one or more integer "sortable words" such that plain
 ascending integer order == the SQL order (nulls-first/last, asc/desc, NaN
-ordering, string lexicographic order), then a single ``jax.lax.sort`` over
-all words (variadic operands, ``num_keys``) yields the permutation.  This
-keeps the whole sort one fused XLA op on static shapes — no comparator
+ordering, string lexicographic order); ``lex_sort_perm`` turns the words
+into the row permutation, and every data, validity and length plane then
+moves by ``jnp.take`` with it.  Static shapes throughout — no comparator
 callbacks, no dynamic shapes.
+
+Only what orders is sorted, and it is sorted as ONE packed 32-bit word at
+a time: XLA:TPU's build time for a variadic ``lax.sort`` grows steeply
+with its operand count (v5e, 2^20 rows: 1 operand 4 s, 3 operands 39 s,
+6 operands 156 s, ten operands with int64 payloads over 4 minutes), while
+the one-operand sort inside a ``lax.scan`` is built once whatever the key
+width (``scripts/tpu_rehearsal.py`` is how to check a new program).
 
 Normalization rules:
 - padding rows (>= row_count) sort last via a leading global rank word
@@ -107,21 +114,97 @@ def sortable_words(col: DeviceColumn, jnp) -> List:
     if isinstance(dt, T.DoubleType):
         return _float_sortable(col.data, jnp, np.uint64)
     if isinstance(dt, T.BooleanType):
-        return [col.data.astype(np.int8)]
+        return [col.data.astype(bool)]
     # integral / date / timestamp / decimal64: native integer order
     return [col.data]
 
 
 def _order_words(col: DeviceColumn, order: SortOrder, jnp) -> List:
-    """null-rank word + (possibly flipped) value words for one sort key."""
-    rank_null = np.int8(0 if order.nulls_first else 1)
-    rank_val = np.int8(1 if order.nulls_first else 0)
-    words = [jnp.where(col.validity, rank_val, rank_null)]
+    """null-rank word + (possibly flipped) value words for one sort key.
+    The rank is a bool (one key bit): False sorts first."""
+    words = [col.validity if order.nulls_first else ~col.validity]
     for w in sortable_words(col, jnp):
         if not order.ascending:
             w = ~w
         words.append(w)
     return words
+
+
+def _unsigned_word(w, jnp):
+    """(unsigned array, bit width) whose unsigned order is ``w``'s order:
+    bool is one bit, a signed integer flips its sign bit."""
+    import jax
+    if isinstance(w, tuple):
+        return w                       # caller-declared (unsigned, nbits)
+    dt = np.dtype(w.dtype)
+    if dt == np.bool_:
+        return w.astype(np.uint32), 1
+    bits = dt.itemsize * 8
+    if dt.kind == "i":
+        udt = np.dtype(f"uint{bits}")
+        w = jax.lax.bitcast_convert_type(w, udt) ^ udt.type(1 << (bits - 1))
+    elif dt.kind != "u":
+        raise TypeError(f"sort word of dtype {dt}: want bool or integer")
+    return w, bits
+
+
+def lex_sort_perm(words: Sequence, n: int, jnp):
+    """int32[n] stable permutation that sorts rows by ``words``, most
+    significant first.  A word is a bool or integer array (ordered as its
+    dtype orders), or ``(unsigned array, nbits)`` where the caller knows
+    that only the low ``nbits`` bits vary.
+
+    Least-significant-digit radix sort: the key bits are cut into digits of
+    ``32 - log2(n)`` bits; each pass packs (digit, current position) into
+    one uint32 and sorts that alone, unstable — positions are unique, so
+    the result is the stable order.  The passes run in one ``lax.scan``, so
+    the compiler builds one one-operand sort whatever the key width."""
+    import jax
+    logn = max(1, (n - 1).bit_length())
+    if logn > 30:
+        raise ValueError(f"lex_sort_perm: {n} rows do not fit a packed key")
+    digit_bits = 32 - logn
+    digits: List = []
+    cur, fill = None, 0
+    for word in reversed(list(words)):
+        u, nbits = _unsigned_word(word, jnp)
+        off = 0
+        while off < nbits:
+            take = min(digit_bits - fill, nbits - off)
+            piece = u if off == 0 else u >> u.dtype.type(off)
+            if off + take < np.dtype(u.dtype).itemsize * 8:
+                piece = piece & u.dtype.type((1 << take) - 1)
+            piece = piece.astype(np.uint32)
+            if fill:
+                piece = piece << np.uint32(fill)
+            cur = piece if cur is None else cur | piece
+            fill += take
+            off += take
+            if fill == digit_bits:
+                digits.append(cur)
+                cur, fill = None, 0
+    if cur is not None:
+        digits.append(cur)
+    pos = jnp.arange(n, dtype=np.uint32)
+    low = np.uint32((1 << logn) - 1)
+
+    def rank(digit):
+        packed = jax.lax.sort((digit << np.uint32(logn)) | pos,
+                              is_stable=False)
+        return (packed & low).astype(np.int32)
+
+    if not digits:
+        return pos.astype(np.int32)
+    if len(digits) == 1:
+        return rank(digits[0])
+
+    def one_pass(perm, digit):
+        idx = rank(jnp.take(digit, perm, axis=0))
+        return jnp.take(perm, idx, axis=0), None
+
+    perm, _ = jax.lax.scan(one_pass, pos.astype(np.int32),
+                           jnp.stack(digits))
+    return perm
 
 
 # ---------------------------------------------------------------------------
@@ -205,7 +288,6 @@ def _col_sig(c: DeviceColumn) -> Tuple:
 def sort_permutation(batch: ColumnarBatch, orders: Sequence[SortOrder]):
     """Returns int32[bucket] permutation placing rows in SQL order,
     padding rows last.  One jitted program per (shapes, orders) signature."""
-    import jax
     jnp = _jx()
     orders = tuple(orders)
     key = ("perm", tuple(_col_sig(c) for c in batch.columns), orders)
@@ -219,12 +301,10 @@ def sort_permutation(batch: ColumnarBatch, orders: Sequence[SortOrder]):
             cols = [DeviceColumn(d, v, bucket, dtypes[i], ln)
                     for i, (d, v, ln) in enumerate(arrs)]
             rowpos = jnp.arange(bucket, dtype=np.int32)
-            words = [(rowpos >= row_count).astype(np.int8)]  # padding last
+            words = [rowpos >= row_count]  # padding last
             for o in orders:
                 words.extend(_order_words(cols[o.ordinal], o, jnp))
-            out = jax.lax.sort(tuple(words) + (rowpos,),
-                               num_keys=len(words), is_stable=True)
-            return out[-1]
+            return lex_sort_perm(words, bucket, jnp)
 
         return run
     from spark_rapids_tpu.exec.stage_compiler import get_or_build
@@ -243,7 +323,6 @@ def sort_gather_batch(batch: ColumnarBatch, orders: Sequence[SortOrder],
     projection, permutation and gather were three programs (the gather
     even dispatched per column).  The payload keeps the input layout;
     key columns never materialize in HBM."""
-    import jax
     jnp = _jx()
     orders = tuple(orders)
     key_exprs = list(key_exprs or ())
@@ -276,11 +355,10 @@ def sort_gather_batch(batch: ColumnarBatch, orders: Sequence[SortOrder],
                                                 bucket, e.data_type,
                                                 dc.lengths))
             rowpos = jnp.arange(bucket, dtype=np.int32)
-            words = [(rowpos >= row_count).astype(np.int8)]  # padding last
+            words = [rowpos >= row_count]  # padding last
             for o in orders:
                 words.extend(_order_words(keycols[o.ordinal], o, jnp))
-            perm = jax.lax.sort(tuple(words) + (rowpos,),
-                                num_keys=len(words), is_stable=True)[-1]
+            perm = lex_sort_perm(words, bucket, jnp)
             outs = []
             for c in cols:
                 d = jnp.take(c.data, perm, axis=0)

@@ -37,6 +37,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
+from spark_rapids_tpu.ops.batch_ops import prefix_sum
 
 # widest bounded ROWS frame lowered to the unrolled min/max gather
 MAX_UNROLLED_FRAME = 256
@@ -86,8 +87,10 @@ def compute_windows(batch: ColumnarBatch, num_payload: int, num_pkeys: int,
     are (ordinal, ascending, nulls_first) into the batch."""
     import jax
     jnp = _jx()
-    from spark_rapids_tpu.ops.sort_ops import SortOrder, _order_words
-    from spark_rapids_tpu.ops.agg_ops import _masked_group_words
+    from spark_rapids_tpu.ops.sort_ops import (SortOrder, _order_words,
+                                               lex_sort_perm)
+    from spark_rapids_tpu.ops.agg_ops import (_masked_group_words,
+                                              _take_columns)
     bucket = batch.bucket
     funcs = tuple(tuple(f) for f in funcs)
     key = ("window", tuple(_col_sig(c) for c in batch.columns), num_payload,
@@ -105,19 +108,11 @@ def compute_windows(batch: ColumnarBatch, num_payload: int, num_pkeys: int,
             rowpos = jnp.arange(bucket, dtype=np.int64)
             inrow = rowpos < row_count
             # ---- sort by partition keys then order keys, padding last ----
-            words = [(~inrow).astype(np.int8)]
+            words = [~inrow]
             for o in orders:
                 words.extend(_order_words(cols[o.ordinal], o, jnp))
-            perm = jax.lax.sort(
-                tuple(words) + (rowpos.astype(np.int32),),
-                num_keys=len(words), is_stable=True)[-1]
-            scols = []
-            for c in cols:
-                d = jnp.take(c.data, perm, axis=0)
-                v = jnp.take(c.validity, perm, axis=0)
-                ln = None if c.lengths is None else \
-                    jnp.take(c.lengths, perm, axis=0)
-                scols.append(DeviceColumn(d, v, bucket, c.data_type, ln))
+            scols = _take_columns(
+                cols, lex_sort_perm(words, bucket, jnp), bucket, jnp)
             # ---- partition / peer boundaries ----
             def boundaries(idxs):
                 b = jnp.zeros(bucket, dtype=bool).at[0].set(True)
@@ -131,10 +126,10 @@ def compute_windows(batch: ColumnarBatch, num_payload: int, num_pkeys: int,
             seg_b = boundaries(list(pk_range))
             peer_b = boundaries(list(pk_range) +
                                 [o for o, _, _ in order_specs])
-            seg = jnp.cumsum(seg_b.astype(np.int64)) - 1
+            seg = prefix_sum(seg_b.astype(np.int64), jnp) - 1
             # first/last row position of each row's partition / peer group
             def first_last(bnd):
-                gid = jnp.cumsum(bnd.astype(np.int64)) - 1
+                gid = prefix_sum(bnd.astype(np.int64), jnp) - 1
                 fp = jax.ops.segment_min(rowpos, gid, num_segments=bucket)
                 lp = jax.ops.segment_max(jnp.where(inrow, rowpos, -1), gid,
                                          num_segments=bucket)
@@ -181,7 +176,7 @@ def _one_func(f, scols, jnp, rowpos, inrow, seg, sfp, slp, pfp, plp,
     if kind == "dense_rank":
         # segment-rebased count of peer-group starts
         peer_start = (rowpos == pfp).astype(np.int64)
-        c = jnp.cumsum(peer_start)
+        c = prefix_sum(peer_start, jnp)
         dense = c - jnp.take(c, sfp) + 1
         return (dense.astype(np.int32), inrow, None)
     if kind == "ntile":
@@ -235,7 +230,7 @@ def _one_func(f, scols, jnp, rowpos, inrow, seg, sfp, slp, pfp, plp,
                     jnp.take(zrow, lo_c, axis=0)
                 return at_hi - at_lo
 
-            n_ = jnp.cumsum(src.astype(np.int64))
+            n_ = prefix_sum(src.astype(np.int64), jnp)
             cnt = win(n_, src.astype(np.int64))
             cnt = jnp.where(empty, 0, cnt)
             if agg == "count":
@@ -256,15 +251,15 @@ def _one_func(f, scols, jnp, rowpos, inrow, seg, sfp, slp, pfp, plp,
                               jnp.zeros_like(x))
             else:
                 z = jnp.where(present, x, jnp.zeros_like(x))
-            cs = jnp.cumsum(z, axis=0)
+            cs = prefix_sum(z, jnp)
             s = win(cs, z)
             s = jnp.where(empty | (cnt == 0), jnp.zeros_like(s), s)
             if is_float:
-                nan_w = win(jnp.cumsum(nan_i), nan_i) > 0
+                nan_w = win(prefix_sum(nan_i, jnp), nan_i) > 0
                 p_i = isp.astype(np.int64)
                 m_i = ism.astype(np.int64)
-                p_w = win(jnp.cumsum(p_i), p_i) > 0
-                m_w = win(jnp.cumsum(m_i), m_i) > 0
+                p_w = win(prefix_sum(p_i, jnp), p_i) > 0
+                m_w = win(prefix_sum(m_i, jnp), m_i) > 0
                 s = jnp.where(nan_w | (p_w & m_w),
                               jnp.asarray(np.nan, s.dtype),
                               jnp.where(p_w, jnp.asarray(np.inf, s.dtype),

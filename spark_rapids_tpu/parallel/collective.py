@@ -185,8 +185,9 @@ def collective_hash_shuffle(ctx: MeshContext, cols, counts, pids):
     from spark_rapids_tpu.aux.faults import maybe_fire
     maybe_fire("parallel.collective")
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+    from spark_rapids_tpu.ops.batch_ops import compaction_perm
+    from spark_rapids_tpu.ops.sort_ops import lex_sort_perm
     jnp = _jx()
     n = ctx.num_devices
     total = int(cols[0][0].shape[0])
@@ -206,7 +207,8 @@ def collective_hash_shuffle(ctx: MeshContext, cols, counts, pids):
             inrow = rowpos < count
             dest = jnp.where(inrow, jnp.clip(pids, 0, n - 1), n)
             # 1. destination-major stable order
-            order = jnp.argsort(dest, stable=True)
+            order = lex_sort_perm(
+                [(dest.astype(np.uint32), n.bit_length())], B, jnp)
             sdest = jnp.take(dest, order)
             dcount = jnp.bincount(sdest, length=n + 1)[:n]
             doff = jnp.cumsum(dcount) - dcount
@@ -238,7 +240,7 @@ def collective_hash_shuffle(ctx: MeshContext, cols, counts, pids):
             blockpos = jnp.arange(B, dtype=np.int64)
             live = blockpos[None, :] < recv_counts[:, None]   # [n, B]
             live_flat = live.reshape(n * B)
-            corder = jnp.argsort(~live_flat, stable=True)
+            corder = compaction_perm(live_flat, jnp)
             new_count = jnp.sum(recv_counts)
             final = []
             for (rd, rv, rl) in outs:
@@ -253,13 +255,13 @@ def collective_hash_shuffle(ctx: MeshContext, cols, counts, pids):
         def build_specs(template, spec):
             return jax.tree_util.tree_map(lambda _: spec, template)
 
-        return shard_map(per_device, mesh=ctx.mesh,
+        return jax.shard_map(per_device, mesh=ctx.mesh,
                          in_specs=(build_specs([tuple(c) for c in cols],
                                                P(axis)),
                                    P(axis), P(axis)),
                          out_specs=(build_specs([tuple(c) for c in cols],
                                                 P(axis)), P(axis)),
-                         check_rep=False)
+                         check_vma=False)
 
     # memoized by (mesh, devices, bucket, schema shapes) in the shared
     # executable cache: a fresh jax.jit here re-traced the whole SPMD

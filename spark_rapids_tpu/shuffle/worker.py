@@ -30,8 +30,10 @@ import time
 
 
 def run_worker(executor_id: str, port: int, ctrl) -> None:
-    # workers never touch the device: the shuffle data plane is host-side
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # workers never touch the device: the shuffle data plane is host-side,
+    # and a child process that reached for the chip would take it from (or
+    # hang on) the parent that holds it
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import numpy as np
 
     from spark_rapids_tpu.columnar.batch import batch_from_pydict

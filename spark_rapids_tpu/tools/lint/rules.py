@@ -814,7 +814,7 @@ class CollectiveSiteRule(Rule):
         if pf.rel.startswith(self.ALLOWED_DIRS):
             return
         # names imported straight from jax modules
-        # ('from jax.experimental.shard_map import shard_map',
+        # ('from jax import shard_map',
         #  'from jax.lax import all_to_all')
         imported: Set[str] = set()
         for node in pf.nodes:

@@ -336,7 +336,7 @@ def test_collective_site_rule(tmp_path):
     from spark_rapids_tpu.tools.lint.rules import CollectiveSiteRule
     bad = """
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax import lax
 
         def my_exchange(fn, mesh, x):

@@ -326,7 +326,26 @@ def test_different_schema_and_bucket_get_their_own_programs():
 # tier 2 (persistent disk cache) + async compile
 # ---------------------------------------------------------------------------
 
-def test_persistent_cache_dir_conf(tmp_path):
+def test_persistent_cache_dir_stands_aside_for_the_environment(
+        tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR places the cache, JAX reads the
+    variable itself: the conf assigns nothing."""
+    import jax
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        SC.set_persistent_cache_dir(str(tmp_path / "from-conf"))
+        assert jax.config.jax_compilation_cache_dir == before
+        assert SC.stats()["disk_cache_dir"] == placed
+    finally:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        SC.set_persistent_cache_dir("")
+    assert SC.stats()["disk_cache_dir"] is None
+
+
+def test_persistent_cache_dir_conf(tmp_path, monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     d = str(tmp_path / "xla-cache")
     s = tpu_session({"spark.rapids.sql.compile.cacheDir": d})
     try:

@@ -1,0 +1,150 @@
+"""The smoke's main programs, compiled for a v5e that is described and not
+attached (``on-chip-measurement`` guide, section 2.3): each must be
+accepted by the chip's compiler at the smoke's shapes.
+
+A compile that passes is not a chip run.  What these guard is that no
+later PR hands the TPU compiler a program it refuses, or a sort that
+carries whole rows (``ops/sort_ops.py``: build time grows steeply with a
+variadic sort's operand count) — asserted as an operand COUNT, not a
+clock, so busy test workers cannot fail it.
+
+One file, one process: the topology is described inside a module-scoped
+fixture (never at import), which loads libtpu and keeps its lock.
+"""
+
+import numpy as np
+import pytest
+
+from spark_rapids_tpu import types as T
+from spark_rapids_tpu.testing import tpu_compile as TC
+
+SMALL = 32_768
+LARGE = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        return TC.describe_v5e()
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip: keep the cache out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def tpu_branch(monkeypatch, topo, no_compile_cache):
+    """Trace the TPU branch of the 64-bit-float kernels: under
+    ``JAX_PLATFORMS=cpu`` ``f64_bitcast_ok()`` otherwise answers for the
+    CPU and traces a bitcast the TPU lacks."""
+    from spark_rapids_tpu.ops import f64bits
+    monkeypatch.setattr(f64bits, "_BITCAST64", False)
+    return topo
+
+
+def _column(n, dt):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    valid = jnp.ones(n, dtype=bool)
+    if dt is T.STRING:
+        return DeviceColumn(jnp.zeros((n, 16), dtype=np.uint8), valid, n,
+                            dt, jnp.zeros(n, dtype=np.int32))
+    return DeviceColumn(jnp.zeros(n, dtype=dt.np_dtype), valid, n, dt)
+
+
+def _batch(n, *dtypes):
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch
+    return ColumnarBatch([_column(n, dt) for dt in dtypes], n,
+                         [f"c{i}" for i in range(len(dtypes))])
+
+
+def _compiles(topo, fn, *args, **kwargs):
+    prog, specs = TC.ProgramRecorder().capture(fn, *args, **kwargs)
+    compiled = TC.compile_for_chip(prog, specs, topo)
+    assert compiled.memory_analysis() is not None
+    return prog, specs
+
+
+def test_flagship_fused_stage(tpu_branch):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    from __graft_entry__ import _fused_pipeline_fn
+    one_chip = SingleDeviceSharding(tpu_branch.devices[0])
+    args = [jax.ShapeDtypeStruct((LARGE,), dt, sharding=one_chip)
+            for dt in (np.int64, np.float64, np.int32, np.bool_)]
+    compiled = jax.jit(_fused_pipeline_fn(LARGE)).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("rows", [SMALL, LARGE])
+def test_compact_batch_sorts_only_what_orders(tpu_branch, rows):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.batch_ops import compact_batch
+    batch = _batch(rows, T.LONG, T.DOUBLE, T.INT, T.STRING)
+    prog, specs = _compiles(tpu_branch, compact_batch, batch,
+                            jnp.ones(rows, dtype=bool))
+    counts = TC.sort_operand_counts(prog, specs)
+    assert counts and max(counts) <= 2, counts
+
+
+def test_double_key_sort(tpu_branch):
+    from spark_rapids_tpu.ops.sort_ops import SortOrder, sort_gather_batch
+    batch = _batch(LARGE, T.DOUBLE, T.LONG, T.STRING)
+    prog, specs = _compiles(tpu_branch, sort_gather_batch, batch,
+                            [SortOrder.desc(0), SortOrder.asc(1)])
+    assert max(TC.sort_operand_counts(prog, specs)) <= 2
+
+
+def test_two_key_group_by(tpu_branch):
+    from spark_rapids_tpu.ops.agg_ops import segmented_aggregate
+    batch = _batch(LARGE, T.INT, T.STRING, T.DOUBLE, T.LONG)
+    prog, specs = _compiles(
+        tpu_branch, segmented_aggregate, batch, 2,
+        [(2, "sum", False, T.DOUBLE), (3, "count", True, T.LONG)])
+    assert max(TC.sort_operand_counts(prog, specs)) <= 2
+
+
+def test_join_build_and_probe(tpu_branch):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import join_ops as J
+    build = _batch(LARGE, T.LONG, T.DOUBLE)
+    probe = _batch(1 << 19, T.LONG, T.INT)
+    prog, specs = _compiles(tpu_branch, J.build_side, build, (0,),
+                            [probe.columns[0]])
+    assert max(TC.sort_operand_counts(prog, specs)) <= 2
+    built = J.BuiltSide(build, (0,), jnp.zeros(LARGE, dtype=np.uint64),
+                        jnp.zeros(LARGE, dtype=np.int32), [1])
+    _compiles(tpu_branch, J._probe_ranges, [probe.columns[0]], built)
+
+
+def test_window_over_partition(tpu_branch):
+    from spark_rapids_tpu.ops.window_ops import compute_windows
+    # payload (string, double) ++ partition key (string) ++ value (double)
+    batch = _batch(LARGE, T.STRING, T.DOUBLE, T.STRING, T.DOUBLE)
+    prog, specs = _compiles(
+        tpu_branch, compute_windows, batch, 2, 1, [],
+        [("agg", "sum", 3, "range", None, None, False), ("row_number",)])
+    assert max(TC.sort_operand_counts(prog, specs)) <= 2
+
+
+def test_result_pack(tpu_branch):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.transfer import _pack_planes
+    batch = _batch(LARGE, T.LONG, T.DOUBLE, T.INT, T.STRING)
+    planes = []
+    for c in batch.columns:
+        planes += [p for p in (c.data, c.validity, c.lengths)
+                   if p is not None]
+    _compiles(tpu_branch, _pack_planes, planes, 4096, jnp.int64(100))

@@ -31,12 +31,14 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
-def pytest_collection_modifyitems(config, items):
-    if jax.default_backend() in ("cpu",):
-        skip = pytest.mark.skip(reason="no accelerator backend; the real-TPU "
-                                       "tier needs a TPU device")
-        for item in items:
-            item.add_marker(skip)
+@pytest.fixture(scope="session", autouse=True)
+def _needs_accelerator():
+    """Asks for the backend when the first test starts, never while
+    pytest collects: initializing it takes the chip, and a collection
+    hook runs in every worker."""
+    if jax.default_backend() == "cpu":
+        pytest.skip("no accelerator backend; the real-TPU tier needs a TPU "
+                    "device")
 
 
 @pytest.fixture
