@@ -360,23 +360,41 @@ def phase_serving(tpu, cpu, moys=SERVING_Q3_MOYS,
 # phase: counters (hard checks over what the phases left behind)
 # ---------------------------------------------------------------------------
 
-def phase_counters(native_info: dict, cache: CacheCounters,
-                   cache_dir: str) -> dict:
-    import jax
-    from spark_rapids_tpu.aux import faults, transitions
+def hiding_counters() -> dict:
+    """The process-wide counts of paths that hide the device.  They are
+    never reset, so a phase reads them when it starts and checks what it
+    added itself: what ran in the process before it is not its fault."""
+    from spark_rapids_tpu.aux import faults
     st = _compile_stats()
-    rec = faults.recovery_stats()
-    check(st["async_failures"] == 0,
+    return {"async_failures": st["async_failures"],
+            "ledger_errors": st["ledger_errors"],
+            "recoveries": faults.recovery_stats()}
+
+
+def phase_counters(native_info: dict, cache: CacheCounters,
+                   cache_dir: str, baseline: dict) -> dict:
+    """``baseline`` is ``hiding_counters()`` from before the smoke's first
+    phase."""
+    import jax
+    from spark_rapids_tpu.aux import transitions
+    st = _compile_stats()
+    now = hiding_counters()
+    rec = {k: v - baseline["recoveries"].get(k, 0)
+           for k, v in now["recoveries"].items()
+           if v != baseline["recoveries"].get(k, 0)}
+    async_failures = now["async_failures"] - baseline["async_failures"]
+    ledger_errors = now["ledger_errors"] - baseline["ledger_errors"]
+    check(async_failures == 0,
           f"background compiles failed: {st['async_error']}")
-    check(st["ledger_errors"] == 0,
-          f"{st['ledger_errors']} audit-ledger recording(s) raised")
+    check(ledger_errors == 0,
+          f"{ledger_errors} audit-ledger recording(s) raised")
     check(rec.get("collective_fallbacks", 0) == 0,
           f"collective exchange fell back to the host: {rec}")
     mem = jax.devices()[0].memory_stats() or {}
     out = {"programs": st["programs"], "compiles": st["compiles"],
            "compile_s": st["compile_s"], "traces": st["traces"],
-           "async_failures": st["async_failures"],
-           "ledger_errors": st["ledger_errors"],
+           "async_failures": async_failures,
+           "ledger_errors": ledger_errors,
            "collective_fallbacks": rec.get("collective_fallbacks", 0),
            "recoveries": rec, "transitions": transitions.totals(),
            "peak_device_bytes": mem.get("peak_bytes_in_use"),
@@ -432,6 +450,7 @@ def phase_mesh(tpu, cpu, n_devices: int = 4, queries=("q3",)) -> dict:
     from spark_rapids_tpu.aux import faults
     from spark_rapids_tpu.testing.rowcompare import rows_equal
     from spark_rapids_tpu.testing.tpcds_queries import QUERIES
+    fallbacks_before = faults.recovery_stats().get("collective_fallbacks", 0)
     devices = shard_devices(n_devices)
     check(len(devices) == n_devices,
           f"sharded columns sit on devices {devices}, not on {n_devices} "
@@ -458,7 +477,8 @@ def phase_mesh(tpu, cpu, n_devices: int = 4, queries=("q3",)) -> dict:
         seen = now
     rec = faults.recovery_stats()
     out["ici_exchanges"] = seen
-    out["collective_fallbacks"] = rec.get("collective_fallbacks", 0)
+    out["collective_fallbacks"] = \
+        rec.get("collective_fallbacks", 0) - fallbacks_before
     check(out["collective_fallbacks"] == 0,
           f"collective exchange fell back to the host: {rec}")
     check(out["queries"]["store_sales_groupby"]["ici_exchanges"] > 0,
@@ -495,6 +515,7 @@ def main(argv=None) -> int:
     cache_dir = place_compile_cache()
     native_info = build_native()
     data_dir = tempfile.mkdtemp(prefix="chip_smoke_tpcds_")
+    baseline = hiding_counters()
     try:
         if args.chips == 4:
             # in-memory tables of four partitions: every hash exchange is
@@ -514,7 +535,7 @@ def main(argv=None) -> int:
             oracles: dict = {}
             phase_tpcds(tpu, cpu, oracles=oracles)
             phase_serving(tpu, cpu, oracles=oracles)
-        phase_counters(native_info, cache, cache_dir)
+        phase_counters(native_info, cache, cache_dir, baseline)
     finally:
         shutil.rmtree(data_dir, ignore_errors=True)
     print(json.dumps({"ok": True, "device": {

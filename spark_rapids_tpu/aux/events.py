@@ -614,13 +614,4 @@ def render_prometheus() -> str:
             lines.append(f'{full}_sum{{stage="{lbl}"}} '
                          f'{round(h["sum"], 6)}')
             lines.append(f'{full}_count{{stage="{lbl}"}} {h["count"]}')
-    from spark_rapids_tpu.aux import profiler as _prof
-    for op, s in sorted(_prof.range_stats().items()):
-        full = "spark_rapids_tpu_op_range_seconds_total"
-        if f"# TYPE {full} counter" not in lines:
-            lines.append(f"# HELP {full} Wall seconds inside operator "
-                         "ranges")
-            lines.append(f"# TYPE {full} counter")
-        lines.append(f'{full}{{op="{escape_label_value(op)}"}} '
-                     f'{s["total_s"]}')
     return "\n".join(lines) + "\n"

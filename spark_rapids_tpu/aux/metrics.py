@@ -6,10 +6,11 @@ with levels ESSENTIAL/MODERATE/DEBUG selected by
 numOutputRows, numOutputBatches, ...).
 
 Instrumentation wraps each exec's ``execute_partition`` with counters,
-a wall-clock timer, the profiler's operator range (NVTX analog, gated on
-the ranges-enabled flag so the disabled path stays zero-cost) and — when
-a ``QueryExecution`` is active — a per-partition child span so layer
-events attribute to the operator that triggered them.
+a wall-clock timer and — when a ``QueryExecution`` is active — a
+per-partition child span so layer events attribute to the operator that
+triggered them.  Every pull runs under that span and writes
+``srt.exec.<node name>`` into the profiler's trace
+(``tracing.partition_pull``).
 ``collect_metrics`` renders the tree's totals."""
 
 from __future__ import annotations
@@ -19,7 +20,7 @@ import time
 from typing import Dict, List, Optional
 
 from spark_rapids_tpu.aux import events as EV
-from spark_rapids_tpu.aux import profiler as _prof
+from spark_rapids_tpu.aux import tracing as _tracing
 from spark_rapids_tpu.plan.base import Exec
 
 
@@ -124,20 +125,8 @@ def instrument_plan(plan: Exec, level: MetricLevel) -> Exec:
             try:
                 while True:
                     t0 = time.perf_counter()
-                    if pspan is not None:
-                        EV.push_span(pspan.span_id)
-                    try:
-                        # NVTX-range analog around the pull that does this
-                        # operator's work; ranges_enabled() keeps the
-                        # disabled path to one module-global read
-                        if _prof.ranges_enabled():
-                            with _prof.op_range(_name):
-                                b = next(it, _END)
-                        else:
-                            b = next(it, _END)
-                    finally:
-                        if pspan is not None:
-                            EV.pop_span()
+                    with _tracing.partition_pull(q, pspan, _name):
+                        b = next(it, _END)
                     if b is _END:
                         break
                     dt = time.perf_counter() - t0
@@ -161,6 +150,9 @@ def instrument_plan(plan: Exec, level: MetricLevel) -> Exec:
                         optime.add(dt)
                     if pspan is not None:
                         pspan.batches += 1
+                        # rows with their padding: the bucket is a host
+                        # int, so no sync (host batches have none)
+                        pspan.padded_rows += getattr(b, "bucket", 0)
                     yield b
             finally:
                 if q is not None and pspan is not None:

@@ -1,73 +1,16 @@
-"""Profiler + trace ranges.
+"""The profiler driver.
 
-Reference: (a) NVTX ranges around operators/metrics (NvtxWithMetrics.scala,
-conf ``spark.rapids.sql.nvtx.enabled``) — here jax profiler
-TraceAnnotations, visible in xprof/tensorboard traces; (b) the built-in
-CUPTI profiler (profiler.scala:37,315 ProfilerOnExecutor/Driver) writing
-trace files to a path, scoped by job/time ranges — here
-``jax.profiler.start_trace`` (xprof) driven by the same conf shape."""
+Reference: the built-in CUPTI profiler (profiler.scala:37,315
+ProfilerOnExecutor/Driver) writing trace files to a path, scoped by
+job/time ranges — here ``jax.profiler.start_trace`` (xprof) driven by the
+same shape.  What the trace holds of the program's own host work is
+written by ``aux.tracing.span`` (``srt.*`` annotations, always on: a
+TraceMe is inert while no trace runs)."""
 
 from __future__ import annotations
 
 import contextlib
 import os
-import threading
-import time
-from typing import Dict, Optional
-
-
-_ENABLED = False
-
-
-def set_ranges_enabled(on: bool) -> None:
-    global _ENABLED
-    _ENABLED = bool(on)
-
-
-def ranges_enabled() -> bool:
-    """Hot-path gate for callers that wrap work in op_range (the exec
-    instrumentation): one module-global read when disabled."""
-    return _ENABLED
-
-
-@contextlib.contextmanager
-def op_range(name: str):
-    """NVTX-range analog: annotates the jax trace when profiling and always
-    records wall time into the thread's range stats."""
-    if not _ENABLED:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        import jax.profiler
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    except ImportError:            # pragma: no cover
-        yield
-    finally:
-        _range_stats_add(name, time.perf_counter() - t0)
-
-
-_STATS_LOCK = threading.Lock()
-_RANGE_STATS: Dict[str, list] = {}
-
-
-def _range_stats_add(name: str, secs: float) -> None:
-    with _STATS_LOCK:
-        s = _RANGE_STATS.setdefault(name, [0, 0.0])
-        s[0] += 1
-        s[1] += secs
-
-
-def range_stats() -> Dict[str, dict]:
-    with _STATS_LOCK:
-        return {k: {"count": v[0], "total_s": round(v[1], 6)}
-                for k, v in _RANGE_STATS.items()}
-
-
-def reset_range_stats() -> None:
-    with _STATS_LOCK:
-        _RANGE_STATS.clear()
 
 
 class Profiler:
@@ -85,7 +28,6 @@ class Profiler:
         os.makedirs(self.path, exist_ok=True)
         import jax.profiler
         jax.profiler.start_trace(self.path)
-        set_ranges_enabled(True)
         self._active = True
 
     def stop(self) -> None:
@@ -93,7 +35,6 @@ class Profiler:
             return
         import jax.profiler
         jax.profiler.stop_trace()
-        set_ranges_enabled(False)
         self._active = False
 
     @contextlib.contextmanager

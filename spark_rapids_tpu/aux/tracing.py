@@ -16,12 +16,25 @@ one place —
 ``DataFrame.explain(analyze=True)`` and bench attribution render from
 here; the JSONL event log (``spark.rapids.sql.eventLog.path``) receives
 queryStart / spanMetrics / queryEnd plus every layer event.
+
+:func:`span` is THE span primitive of the query path.  One call writes the
+same span to two places: the active query's tree (a ``phase`` span: name,
+start, end, the span that caused it) and the JAX profiler's trace (a
+``TraceAnnotation`` named ``srt.<name>`` carrying ``query_id`` and
+``span_id``, on the device events' clock).  With no profiler trace running
+the annotation is an inert TraceMe, so nothing has to be switched on.  The
+span vocabulary is a contract (docs/observability.md, PERF.md):
+``plan.parse``, ``plan.analyze``, ``plan.rewrite``, ``exec.run``,
+``exec.replay``, ``xfer.h2d``, ``xfer.d2h``, ``xfer.sync``,
+``compile.build``, ``result.rows``; annotation only: ``dispatch`` and
+``exec.<node name>`` (one per batch pull).
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import heapq
 import itertools
 import threading
 import time
@@ -41,7 +54,8 @@ _LAST_SUMMARY: Optional[dict] = None
 #: dict under its own leaf lock so a scrape never touches engine locks.
 _LIVE_LOCK = threading.Lock()
 _LIVE: Dict[int, "QueryExecution"] = {}
-_RECENT: collections.deque = collections.deque(maxlen=32)
+#: 1024 summaries: a served benchmark window reads its queries back from here
+_RECENT: collections.deque = collections.deque(maxlen=1024)
 
 
 def live_queries() -> List["QueryExecution"]:
@@ -81,12 +95,13 @@ def _nondefault_conf(conf) -> dict:
 
 class Span:
     """One node of the query's span tree.  ``kind`` is ``query`` (root),
-    ``exec`` (one physical plan node) or ``partition`` (one task of an
-    exec node)."""
+    ``exec`` (one physical plan node), ``partition`` (one task of an
+    exec node) or ``phase`` (one layer boundary of the query path, opened
+    by :func:`span`; ``metrics`` holds its attributes)."""
 
     __slots__ = ("span_id", "parent_id", "name", "desc", "kind", "device",
                  "children", "start", "end", "metrics", "rows", "batches",
-                 "pidx")
+                 "padded_rows", "pidx")
 
     def __init__(self, name: str, parent_id: Optional[int] = None,
                  desc: str = "", kind: str = "exec", device: bool = False,
@@ -103,6 +118,8 @@ class Span:
         self.metrics: Dict = {}
         self.rows = 0
         self.batches = 0
+        #: sum of the buckets of the batches pulled (rows incl. padding)
+        self.padded_rows = 0
         self.pidx = pidx
 
     @property
@@ -143,9 +160,19 @@ class QueryExecution:
         self._node_spans: Dict[int, Span] = {}
         self._span_index: Dict[int, Span] = {self.root.span_id: self.root}
         self._plan = None
+        #: the exec span of the attached plan's root
+        self._plan_span: Optional[Span] = None
         self._token = None
         self._start_snapshot = None
         self._transitions_snapshot = None
+        self._dispatch_snapshot = None
+        #: counts noted where the work happens (:func:`add_count`)
+        self.counters: Dict[str, int] = {"speculation_replays": 0,
+                                         "pair_rows_padded": 0}
+        #: seconds of the planning spans adopted from ``TpuSession.sql``,
+        #: which ran before this query began: part of what the client
+        #: waited, so part of ``duration_s``
+        self._adopted_s = 0.0
         self.summary_dict: Optional[dict] = None
         self.finished = False
         #: cached predict_plan_costs rows for the attached plan (fixed
@@ -187,6 +214,8 @@ class QueryExecution:
             else None
         from spark_rapids_tpu.aux import transitions as TR
         self._transitions_snapshot = TR.snapshot()
+        from spark_rapids_tpu.exec import stage_compiler as SC
+        self._dispatch_snapshot = SC.dispatch_totals()
         start_payload = {"description": self.description}
         if self.conf_snapshot:
             start_payload["conf"] = dict(self.conf_snapshot)
@@ -203,18 +232,42 @@ class QueryExecution:
         return False
 
     # -- span tree -----------------------------------------------------------
+    def _current_span_locked(self) -> Span:
+        """The span the calling thread runs under, else the root."""
+        sid = EV.current_span_id()
+        return self._span_index.get(sid, self.root) if sid is not None \
+            else self.root
+
     def attach_plan(self, plan) -> None:
-        """Mirrors the executed physical plan as exec spans.  Re-attaching
-        (a speculation replay re-applies the overrides) rebuilds the tree
-        for the plan that actually runs; already-recorded events keep
-        their span ids and fall back to the root for attribution."""
+        """Mirrors the executed physical plan as exec spans under the
+        span the caller runs in (``exec.run``; the root outside one).
+        Re-attaching the same plan moves its spans there; another plan (a
+        speculation replay re-applies the overrides) replaces them, so
+        the tree holds the plan that actually runs: already-recorded
+        events keep their span ids and fall back to the root for
+        attribution."""
         with self._lock:
+            parent = self._current_span_locked()
+            old = self._plan_span
+            if old is not None:
+                holder = self._span_index.get(old.parent_id, self.root)
+                if old in holder.children:
+                    holder.children.remove(old)
+                if plan is self._plan:
+                    old.parent_id = parent.span_id
+                    parent.children.append(old)
+                    return
+
+                def forget(sp: Span) -> None:
+                    self._span_index.pop(sp.span_id, None)
+                    for c in sp.children:
+                        forget(c)
+
+                forget(old)
             self._plan = plan
             self._node_spans.clear()
-            self.root.children = []
-            self._span_index = {self.root.span_id: self.root}
 
-            def build(node, parent: Span) -> None:
+            def build(node, parent: Span) -> Span:
                 sp = Span(node.name, parent.span_id, desc=node.node_desc(),
                           device=getattr(node, "is_device", False))
                 parent.children.append(sp)
@@ -222,8 +275,38 @@ class QueryExecution:
                 self._node_spans[id(getattr(node, "metrics", None))] = sp
                 for c in node.children:
                     build(c, sp)
+                return sp
 
-            build(plan, self.root)
+            self._plan_span = build(plan, parent)
+
+    def open_span(self, name: str, attrs: dict) -> Span:
+        """A ``phase`` child of the span the calling thread runs under
+        (:func:`span` pushes and closes it)."""
+        with self._lock:
+            parent = self._current_span_locked()
+            sp = Span(name, parent.span_id, kind="phase")
+            sp.metrics = attrs
+            parent.children.append(sp)
+            self._span_index[sp.span_id] = sp
+            return sp
+
+    def adopt(self, planned) -> None:
+        """Takes closed ``(name, start, end)`` intervals
+        (``plan.parse``/``plan.analyze`` of the text this query runs) as
+        children of the root, with their own times.  What of them lies
+        before the query began counts in ``duration_s``."""
+        with self._lock:
+            for name, start, end in planned:
+                sp = Span(name, self.root.span_id, kind="phase")
+                sp.start, sp.end = start, end
+                self.root.children.append(sp)
+                self._span_index[sp.span_id] = sp
+                self._adopted_s += max(0.0, min(end, self.root.start)
+                                       - start)
+
+    def add_count(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self.counters[name] = self.counters.get(name, 0) + n
 
     def start_partition(self, node_key: int, pidx: int) -> Span:
         """Child span for one partition (task) of an exec node; called by
@@ -416,7 +499,8 @@ class QueryExecution:
             # span ids orphaned by a replay's attach_plan rebuild fall
             # back to the root so pressure events still count
             sp = self._span_index.get(ev.span_id) or self.root
-            if sp.kind == "partition":
+            while sp.kind in ("partition", "phase"):
+                # a transfer inside an operator's pull belongs to the node
                 sp = self._span_index.get(sp.parent_id, self.root)
             if sp.kind == "query" and ev.kind not in ("spill", "retryOOM",
                                                       "splitRetry", "oom",
@@ -519,13 +603,14 @@ class QueryExecution:
         for sp in self._exec_spans():
             row = {"span_id": sp.span_id, "parent_id": sp.parent_id,
                    "depth": depths.get(sp.span_id, 1), "node": sp.name,
-                   "desc": sp.desc[:120],
+                   "desc": sp.desc[:120], "device": sp.device,
                    "start_s": round(sp.start, 6),
                    "end_s": round(sp.end if sp.end is not None else now, 6),
                    **sp.metrics}
             parts = [{"pidx": c.pidx, "start_s": round(c.start, 6),
                       "end_s": round(c.end if c.end is not None else now, 6),
-                      "rows": c.rows, "batches": c.batches}
+                      "rows": c.rows, "batches": c.batches,
+                      "padded_rows": c.padded_rows}
                      for c in sp.children if c.kind == "partition"]
             if parts:
                 row["partitions"] = parts
@@ -534,16 +619,32 @@ class QueryExecution:
                 row.update({k: v for k, v in extra.items() if v})
             nodes.append(row)
             self.record_event("spanMetrics", row, span_id=sp.span_id)
+        for sp in self._phase_spans():
+            self.record_event("spanMetrics", {
+                "span_id": sp.span_id, "parent_id": sp.parent_id,
+                "depth": depths.get(sp.span_id, 1), "node": sp.name,
+                "kind": "phase", "start_s": round(sp.start, 6),
+                "end_s": round(sp.end if sp.end is not None else now, 6),
+                **sp.metrics}, span_id=sp.span_id)
+        with self._lock:
+            counters = dict(self.counters)
         summary = {
             "query_id": self.query_id,
             "description": self.description,
             "status": "error" if error is not None else "ok",
-            "duration_s": round(self.root.duration_s, 6),
+            # what the client waited: the action, and the planning of
+            # its text that ``sql()`` did before the action began
+            "duration_s": round(self.root.duration_s + self._adopted_s, 6),
             "events": len(self.ring) + self.ring.dropped,
             "events_dropped": self.ring.dropped,
             **delta,
+            **counters,
+            "phases": self._phase_self_times(now),
             "nodes": nodes,
         }
+        if self._dispatch_snapshot is not None:
+            from spark_rapids_tpu.exec import stage_compiler as SC
+            summary.update(SC.dispatch_delta(self._dispatch_snapshot))
         if recovery:
             summary["recovery"] = recovery
         # host-transition ledger: snapshot-delta of the gateway counters
@@ -611,17 +712,72 @@ class QueryExecution:
         except Exception:   # noqa: BLE001 - report-only, never fails a query
             return None
 
-    def _exec_spans(self) -> List[Span]:
+    def _spans_of(self, kind: str) -> List[Span]:
         out: List[Span] = []
 
         def walk(sp: Span) -> None:
-            if sp.kind == "exec":
+            if sp.kind == kind:
                 out.append(sp)
             for c in sp.children:
                 walk(c)
 
         walk(self.root)
         return out
+
+    def _exec_spans(self) -> List[Span]:
+        return self._spans_of("exec")
+
+    def _phase_spans(self) -> List[Span]:
+        return self._spans_of("phase")
+
+    def _phase_self_times(self, now: float) -> Dict[str, float]:
+        """``{span name: self seconds}`` over the phase spans: a span's
+        duration less the part that the phase spans inside it cover (the
+        choosing-metrics rule).  Every instant of the root's interval
+        goes to the innermost phase span open then (the latest opened
+        where threads overlap), or to ``(unattributed)``; adopted spans
+        add their own durations.  So the entries add up to
+        ``duration_s``."""
+        lo, hi = self.root.start, (self.root.end if self.root.end
+                                   is not None else now)
+        out: Dict[str, float] = {}
+        edges = []      # (time, 0 = close | 1 = open, index)
+        spans = []      # (depth among phase spans, start, name)
+
+        def walk(sp: Span, depth: int) -> None:
+            for c in sp.children:
+                d = depth
+                if c.kind == "phase":
+                    d = depth + 1
+                    end = c.end if c.end is not None else now
+                    if c.start < lo:    # adopted: ran before the root
+                        out[c.name] = out.get(c.name, 0.0) \
+                            + min(end, lo) - c.start
+                    if min(end, hi) > max(c.start, lo):
+                        edges.append((max(c.start, lo), 1, len(spans)))
+                        edges.append((min(end, hi), 0, len(spans)))
+                        spans.append((d, c.start, c.name))
+                walk(c, d)
+
+        with self._lock:
+            walk(self.root, 0)
+        edges.sort()
+        open_heap: List = []    # (-depth, -start, index): innermost first
+        closed = set()
+        at = lo
+        for t, opens, i in edges:
+            while open_heap and open_heap[0][2] in closed:
+                heapq.heappop(open_heap)
+            name = spans[open_heap[0][2]][2] if open_heap \
+                else "(unattributed)"
+            out[name] = out.get(name, 0.0) + (t - at)
+            at = t
+            if opens:
+                heapq.heappush(open_heap, (-spans[i][0], -spans[i][1], i))
+            else:
+                closed.add(i)
+        out["(unattributed)"] = out.get("(unattributed)", 0.0) + (hi - at)
+        return {k: round(v, 6) for k, v in out.items()}
 
     # -- rendering -----------------------------------------------------------
     def render_tree(self, show_partitions: bool = False) -> str:
@@ -667,9 +823,17 @@ class QueryExecution:
             for c in sp.children:
                 walk(c, indent + 1)
 
-        for c in self.root.children:
-            walk(c, 0)
+        if self._plan_span is not None:
+            walk(self._plan_span, 0)
         summary = self.summary_dict or {}
+        phases = summary.get("phases")
+        if phases:
+            lines.append("== Phases (self time) ==")
+            lines.append(" ".join(f"{k}={v}s" for k, v in phases.items()))
+            lines.append(" ".join(
+                f"{k}={summary[k]}" for k in
+                ("dispatches", "dispatch_s", "speculation_replays",
+                 "pair_rows_padded") if k in summary))
         lines.append("== Query Summary ==")
         lines.append(" ".join(
             f"{k}={summary[k]}" for k in
@@ -694,13 +858,93 @@ class QueryExecution:
         return "\n".join(lines)
 
 
+def annotation(name: str, q, span_id, **attrs):
+    """The profiler annotation ``srt.<name>`` with the query's id and a
+    span's: an inert TraceMe unless a profiler trace is running."""
+    # imported where it is used: the package does not import jax before
+    # the first device use
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(
+        "srt." + name,
+        query_id=q.query_id if q is not None else EV.NO_QUERY,
+        span_id=span_id if span_id is not None else EV.NO_SPAN, **attrs)
+
+
 @contextlib.contextmanager
-def query_scope(conf=None, description: str = ""):
+def _running_under(name: str, q, sp: Optional[Span], attrs: dict):
+    """Annotates ``srt.<name>`` and, where there is a span, runs the
+    thread under it."""
+    with annotation(name, q, sp.span_id if sp is not None else None,
+                    **attrs):
+        if sp is None:
+            yield
+            return
+        EV.push_span(sp.span_id)
+        try:
+            yield
+        finally:
+            EV.pop_span()
+
+
+@contextlib.contextmanager
+def span(name: str, **attrs):
+    """THE span primitive: one layer boundary of the query path, written
+    to two places.  With an active query it opens a ``phase`` child of
+    the span the thread runs under (the root outside one) and yields it;
+    it always enters the profiler annotation ``srt.<name>`` with the
+    query's id and the span's, which is inert unless a profiler trace is
+    running.  Outside any query (or after the query has finished) only
+    the annotation is written, and ``None`` is yielded."""
+    q = EV.active_query()
+    sp = q.open_span(name, attrs) if q is not None and not q.finished \
+        else None
+    try:
+        with _running_under(name, q, sp, attrs):
+            yield sp
+    finally:
+        if sp is not None:
+            sp.end = time.monotonic()
+
+
+def partition_pull(q: Optional[QueryExecution], pspan: Optional[Span],
+                   node_name: str):
+    """One pull of an operator's partition iterator: the thread runs
+    under the partition span for its length, and the profiler's trace
+    gets ``srt.exec.<node name>``."""
+    return _running_under("exec." + node_name, q, pspan, {})
+
+
+@contextlib.contextmanager
+def run_span(plan):
+    """``exec.run``: the root's ``collect_host`` / ``execute_all``.  The
+    plan's exec spans (and through them the partition spans) hang under
+    it."""
+    with span("exec.run") as sp:
+        if sp is not None:
+            EV.active_query().attach_plan(plan)
+        yield sp
+
+
+def add_count(name: str, n: int = 1) -> None:
+    """Adds to a per-query counter of the active query's summary
+    (``speculation_replays``, ``pair_rows_padded``), where the work
+    happens."""
+    q = EV.active_query()
+    if q is not None:
+        q.add_count(name, n)
+
+
+@contextlib.contextmanager
+def query_scope(conf=None, description: str = "", planned=()):
     """Action-level wrapper: opens a QueryExecution unless one is already
     active (nested actions — cache materialization, explain(analyze) —
-    join the outer query) or tracing is disabled by conf."""
+    join the outer query) or tracing is disabled by conf.  ``planned``
+    holds the closed ``plan.parse``/``plan.analyze`` intervals of the
+    text this action runs; the query that opens adopts them."""
     active = EV.active_query()
     if active is not None:
+        if planned:
+            active.adopt(planned)
         yield active
         return
     if conf is not None:
@@ -710,4 +954,6 @@ def query_scope(conf=None, description: str = ""):
             return
     qe = QueryExecution.from_conf(conf, description)
     with qe:
+        if planned:
+            qe.adopt(planned)
         yield qe

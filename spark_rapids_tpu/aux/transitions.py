@@ -43,6 +43,7 @@ from typing import Optional
 import numpy as np
 
 from spark_rapids_tpu.aux import events as EV
+from spark_rapids_tpu.aux.tracing import span
 
 #: instrumentation master switch + per-boundary event emission switch
 #: (module-internal; mutated ONLY by sync_from_conf)
@@ -171,8 +172,23 @@ def _record_sync(site: str, duration_s: float,
 
 # ---------------------------------------------------------------------------
 # blocking-sync wrappers (THE sanctioned sync call sites; the sync-site
-# lint rule bans raw block_until_ready/jax.device_get elsewhere)
+# lint rule bans raw block_until_ready/jax.device_get elsewhere).  Each
+# is one ``xfer.sync`` span over the interval the ledger sums; the two
+# transfer doors (``xfer.h2d``/``xfer.d2h``) are opened by
+# columnar/transfer.py around the intervals it hands to ``record_h2d`` /
+# ``record_d2h``.
 # ---------------------------------------------------------------------------
+
+def _sync(site: str, wait, with_bytes: bool = False):
+    """One blocking sync: an ``xfer.sync`` span over ``wait()`` and the
+    same interval in the ledger."""
+    with span("xfer.sync", site=site):
+        t0 = time.perf_counter()
+        out = wait()
+        dt = time.perf_counter() - t0
+    _record_sync(site, dt, nbytes=out.nbytes if with_bytes else None)
+    return out
+
 
 def fetch(arr, site: str) -> np.ndarray:
     """Blocking device->host fetch of one array (``np.asarray`` on a
@@ -180,10 +196,7 @@ def fetch(arr, site: str) -> np.ndarray:
     through at numpy cost — safe on either side of the boundary."""
     if not _ENABLED:
         return np.asarray(arr)
-    t0 = time.perf_counter()
-    out = np.asarray(arr)
-    _record_sync(site, time.perf_counter() - t0, nbytes=out.nbytes)
-    return out
+    return _sync(site, lambda: np.asarray(arr), with_bytes=True)
 
 
 def sync_int(x, site: str) -> int:
@@ -191,20 +204,14 @@ def sync_int(x, site: str) -> int:
     deferred-count force shape)."""
     if not _ENABLED:
         return int(x)
-    t0 = time.perf_counter()
-    out = int(x)
-    _record_sync(site, time.perf_counter() - t0)
-    return out
+    return _sync(site, lambda: int(x))
 
 
 def block_until_ready(x, site: str = "dispatch"):
     """Timed ``block_until_ready`` — the dispatch-boundary sync."""
     if not _ENABLED:
         return x.block_until_ready()
-    t0 = time.perf_counter()
-    out = x.block_until_ready()
-    _record_sync(site, time.perf_counter() - t0)
-    return out
+    return _sync(site, x.block_until_ready)
 
 
 def device_get(x, site: str = "device_get"):
@@ -212,7 +219,4 @@ def device_get(x, site: str = "device_get"):
     import jax
     if not _ENABLED:
         return jax.device_get(x)
-    t0 = time.perf_counter()
-    out = jax.device_get(x)
-    _record_sync(site, time.perf_counter() - t0)
-    return out
+    return _sync(site, lambda: jax.device_get(x))

@@ -30,6 +30,7 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.aux import transitions as TR
+from spark_rapids_tpu.aux.tracing import span
 from spark_rapids_tpu.columnar.column import (DeviceColumn, HostColumn,
                                               _jnp, assemble_host_column,
                                               bucket_rows,
@@ -221,10 +222,11 @@ def upload_host_batch(hb, bucket: Optional[int] = None):
     # the ONE H2D boundary of the upload path: packed width-grouped
     # buffers cross in a single device_put dispatch (ledger: duration is
     # dispatch wall — the copy may complete asynchronously)
-    t0_h2d = time.perf_counter()
-    dev_bufs = jax.device_put([host_bufs[w] for w in widths])
-    TR.record_h2d(sum(buf.nbytes for buf in host_bufs.values()),
-                  time.perf_counter() - t0_h2d,
+    with span("xfer.h2d", site="upload"):
+        t0_h2d = time.perf_counter()
+        dev_bufs = jax.device_put([host_bufs[w] for w in widths])
+        dt_h2d = time.perf_counter() - t0_h2d
+    TR.record_h2d(sum(buf.nbytes for buf in host_bufs.values()), dt_h2d,
                   kinds=",".join(sorted({d[0] for d, _ in descs})),
                   planes=len(all_planes))
     planes_dev, ones = fn(dev_bufs, n)
@@ -461,20 +463,23 @@ def download_host_batch(cb, spec_rows=None) -> "object":
     # the ONE D2H boundary of the download path: all planes cross as a
     # single packed buffer per round trip (ledger: duration is the true
     # blocking fetch — counted as a transition, not a sync)
-    t0_d2h = time.perf_counter()
-    buf = np.asarray(_pack_planes(planes, shrink, rc_traceable(rc)))
-    TR.record_d2h(buf.nbytes, time.perf_counter() - t0_d2h,
-                  site="download", planes=len(planes))
+    with span("xfer.d2h", site="download"):
+        t0_d2h = time.perf_counter()
+        buf = np.asarray(_pack_planes(planes, shrink, rc_traceable(rc)))
+        dt_d2h = time.perf_counter() - t0_d2h
+    TR.record_d2h(buf.nbytes, dt_d2h, site="download", planes=len(planes))
     fetched, n = _unpack_buffer(buf, planes, shrink)
     if deferred:
         rc._val = n   # the fetch resolved the count: cache it
     if n > shrink:
         # speculation miss: fetch again at the exact size (one more trip)
         shrink = min(bucket, bucket_rows(max(n, 1), minimum=8))
-        t0_d2h = time.perf_counter()
-        buf = np.asarray(_pack_planes(planes, shrink, n))
-        TR.record_d2h(buf.nbytes, time.perf_counter() - t0_d2h,
-                      site="download-miss", planes=len(planes))
+        with span("xfer.d2h", site="download-miss"):
+            t0_d2h = time.perf_counter()
+            buf = np.asarray(_pack_planes(planes, shrink, n))
+            dt_d2h = time.perf_counter() - t0_d2h
+        TR.record_d2h(buf.nbytes, dt_d2h, site="download-miss",
+                      planes=len(planes))
         fetched, _ = _unpack_buffer(buf, planes, shrink)
 
     cols = []

@@ -638,12 +638,6 @@ SHUFFLE_COMPRESSION_CODEC = conf_str(
     "LZ4/ZSTD; here the libtpucol LZ4 block codec or zlib).",
     "lz4")
 
-RANGES_ENABLED = conf_bool(
-    "spark.rapids.sql.nvtx.enabled",
-    "Annotate operator ranges into the active profiler trace "
-    "(reference: NVTX ranges, NvtxWithMetrics.scala).",
-    False)
-
 JOIN_SUBPARTITION_THRESHOLD = conf_bytes(
     "spark.rapids.sql.join.subPartitionThresholdBytes",
     "Build sides larger than this re-partition into hash buckets joined "

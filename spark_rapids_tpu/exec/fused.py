@@ -318,6 +318,7 @@ class TpuFusedAggExec(UnaryExec, _PromotedLiteralsMixin):
                tuple(None if d is None else d.fingerprint
                      for d in key_dicts))
         def build():
+            import jax
             from spark_rapids_tpu.expressions.evaluator import \
                 tcol_to_device_column
             from spark_rapids_tpu.ops.agg_ops import (_GLOBAL_OUT_BUCKET,
@@ -332,6 +333,21 @@ class TpuFusedAggExec(UnaryExec, _PromotedLiteralsMixin):
             kdicts = key_dicts
 
             def run(arrs, rc, lits, enc_args):
+                # named scopes: the engine's names for the program's
+                # phases in every XLA op's op_name (metadata only)
+                with jax.named_scope("keys"):
+                    upd_cols, sel = update_inputs(arrs, rc, lits, enc_args)
+                with jax.named_scope("update"):
+                    if nk == 0:
+                        outs = global_agg_trace(upd_cols, sel, upd_specs,
+                                                jnp)
+                        return outs, None
+                    return keyed_agg_trace(upd_cols, sel, nk, upd_specs,
+                                           bucket, jnp)
+
+            def update_inputs(arrs, rc, lits, enc_args):
+                """The chain's filters and projections, then the group
+                keys and the aggregates' inputs."""
                 cols = _arrs_to_tcols(arrs, dtypes)
                 if plan is not None:
                     cols = plan.prepare_cols(cols, enc_args, jnp)
@@ -359,11 +375,7 @@ class TpuFusedAggExec(UnaryExec, _PromotedLiteralsMixin):
                     upd_cols.append(DeviceColumn(dc.data, dc.validity,
                                                  bucket, e.data_type,
                                                  dc.lengths))
-                if nk == 0:
-                    outs = global_agg_trace(upd_cols, sel, upd_specs, jnp)
-                    return outs, None
-                return keyed_agg_trace(upd_cols, sel, nk, upd_specs,
-                                       bucket, jnp)
+                return upd_cols, sel
 
             return run
         from spark_rapids_tpu.exec.stage_compiler import get_or_build

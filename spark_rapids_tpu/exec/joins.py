@@ -28,6 +28,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.aux.tracing import add_count
 from spark_rapids_tpu.columnar.batch import (ColumnarBatch, HostColumnarBatch,
                                              batch_from_arrow,
                                              concat_host_batches)
@@ -486,6 +487,9 @@ class _TpuJoinCore(_JoinBase):
                     total = int(total)       # the per-join sizing sync
                     out_bucket = J.bucket_rows(max(total, 1))
                     verify_bucket = out_bucket
+                # the rows of the pair table the device expands and
+                # verifies, whatever the join selects
+                add_count("pair_rows_padded", verify_bucket)
                 l_idx, r_idx, keep, pair_bucket = J._expand_verify(
                     probe_aug, probe_ords, built, self.null_safe, lo,
                     offsets, total, verify_bucket)

@@ -42,6 +42,9 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from spark_rapids_tpu.aux import events as EV
+from spark_rapids_tpu.aux.tracing import annotation, span
+
 __all__ = ["get_or_build", "stats", "reset_stats", "clear",
            "set_max_programs", "set_persistent_cache_dir", "StageProgram",
            "jaxpr_signatures"]
@@ -87,10 +90,13 @@ _STATS = {
     "ledger_rows": 0,   # stageProgram audit rows emitted
     "ledger_errors": 0,  # ledger recordings that raised (audit never
                          # fails the query; nonzero = blind audit spots)
+    "dispatches": 0,    # steady-path calls of an already-built program
+    "dispatch_s": 0.0,  # host seconds inside those calls
 }
 #: last background-compile error (stats(); None = healthy)
 _ASYNC_ERROR = [None]
 _TRACES_BY_KIND: Dict[str, int] = {}
+_DISPATCHES_BY_KIND: Dict[str, int] = {}
 
 #: persistent (tier-2) cache state; dir None = disabled
 _DISK = {"dir": None, "error": None}
@@ -185,11 +191,12 @@ class StageProgram:
                 return False
 
             def work():
-                t0 = time.perf_counter()
-                traced = self._fn.trace(*args)
-                lowered = traced.lower()
-                compiled = lowered.compile()
-                dt = time.perf_counter() - t0
+                with span("compile.build", kind=self.kind, tier="aot"):
+                    t0 = time.perf_counter()
+                    traced = self._fn.trace(*args)
+                    lowered = traced.lower()
+                    compiled = lowered.compile()
+                    dt = time.perf_counter() - t0
                 self._note_compiled(dt, tier="aot")
                 with _LOCK:
                     _STATS["async_compiles"] += 1
@@ -217,6 +224,22 @@ class StageProgram:
              disk_cache=_DISK["dir"] is not None)
 
     # -- dispatch ------------------------------------------------------------
+    def _dispatch(self, fn, args):
+        """The steady path: a call of a program that is already built.
+        One ``srt.dispatch`` annotation (no Span object: a query may make
+        thousands) and two clock reads; counted once it has returned."""
+        t0 = time.perf_counter()
+        with annotation("dispatch", EV.active_query(),
+                        EV.current_span_id(), kind=self.kind):
+            out = fn(*args)
+        dt = time.perf_counter() - t0
+        with _LOCK:
+            _STATS["dispatches"] += 1
+            _STATS["dispatch_s"] += dt
+            _DISPATCHES_BY_KIND[self.kind] = \
+                _DISPATCHES_BY_KIND.get(self.kind, 0) + 1
+        return out
+
     def __call__(self, *args):
         fut = self._warm_future
         if fut is not None:
@@ -239,7 +262,7 @@ class StageProgram:
                 # and counted like any cold compile, not happen invisibly
         if self._compiled is not None:
             try:
-                return self._compiled(*args)
+                return self._dispatch(self._compiled, args)
             except (TypeError, ValueError):
                 # arg-signature drift only (an int row count where the
                 # lowering saw a device scalar): route THIS call through
@@ -271,44 +294,56 @@ class StageProgram:
                     self._dispatched = True
                     first = True
         if first:
-            t0 = time.perf_counter()
-            traced = lowered = compiled = None
-            if _ledger_active():
-                # first dispatch goes through the AOT pipeline so the
-                # audit ledger sees the jaxpr + cost analysis of the
-                # exact program being cached, with ONE trace (the same
-                # count the jit dispatch would pay) and no duplicate
-                # compile.  Any AOT-surface failure falls back to the
-                # plain jit dispatch, which is always correct.
-                try:
-                    traced = self._fn.trace(*args)
-                    lowered = traced.lower()
-                    compiled = lowered.compile()
-                except Exception:  # noqa: BLE001 — audit is best-effort
-                    traced = lowered = compiled = None
-                    with _LOCK:
-                        _STATS["ledger_errors"] += 1
-            if compiled is not None:
-                self._compiled = compiled
-                out = compiled(*args)
-                self._note_compiled(time.perf_counter() - t0, tier="jit")
-                _record_ledger(self, traced, lowered)
-                return out
-            out = self._fn(*args)
+            with span("compile.build", kind=self.kind, tier="jit"):
+                return self._first_dispatch(args)
+        return self._dispatch(self._fn, args)
+
+    def _first_dispatch(self, args):
+        """Trace + compile + first execution, timed as one compile."""
+        t0 = time.perf_counter()
+        traced = lowered = compiled = None
+        if _ledger_active():
+            # first dispatch goes through the AOT pipeline so the
+            # audit ledger sees the jaxpr + cost analysis of the
+            # exact program being cached, with ONE trace (the same
+            # count the jit dispatch would pay) and no duplicate
+            # compile.  Any AOT-surface failure falls back to the
+            # plain jit dispatch, which is always correct.
+            try:
+                traced = self._fn.trace(*args)
+                lowered = traced.lower()
+                compiled = lowered.compile()
+            except Exception:  # noqa: BLE001 — audit is best-effort
+                traced = lowered = compiled = None
+                with _LOCK:
+                    _STATS["ledger_errors"] += 1
+        if compiled is not None:
+            self._compiled = compiled
+            out = compiled(*args)
             self._note_compiled(time.perf_counter() - t0, tier="jit")
+            _record_ledger(self, traced, lowered)
             return out
-        return self._fn(*args)
+        out = self._fn(*args)
+        self._note_compiled(time.perf_counter() - t0, tier="jit")
+        return out
 
 
 def _counting(kind: str, fn: Callable) -> Callable:
     """Wraps a trace function so every ACTUAL jax trace (including
-    signature-variant retraces inside one jit wrapper) counts."""
+    signature-variant retraces inside one jit wrapper) counts.
+
+    The name it gives the function is a contract: ``run[<kind>]``,
+    whatever the build function called it, so jit names the program
+    ``jit_run_<kind>`` (the brackets fall to XLA's module naming).  That
+    is what the profiler's trace shows for it and what
+    ``benchmark/trace/reduce.py`` ``short_program`` shortens to the kind.
+    ``tests/test_spans.py`` pins it through an xplane."""
     def traced(*args, **kwargs):
         with _LOCK:
             _STATS["traces"] += 1
             _TRACES_BY_KIND[kind] = _TRACES_BY_KIND.get(kind, 0) + 1
         return fn(*args, **kwargs)
-    traced.__name__ = getattr(fn, "__name__", "run") + f"[{kind}]"
+    traced.__name__ = f"run[{kind}]"
     return traced
 
 
@@ -529,10 +564,29 @@ def stats() -> Dict:
         out["programs"] = len(_PROGRAMS)
         out["max_programs"] = _MAX_PROGRAMS
         out["traces_by_kind"] = dict(_TRACES_BY_KIND)
+        out["dispatches_by_kind"] = dict(_DISPATCHES_BY_KIND)
         out["disk_cache_dir"] = _DISK["dir"]
         out["disk_cache_error"] = _DISK["error"]
         out["async_error"] = _ASYNC_ERROR[0]
         return out
+
+
+def dispatch_totals() -> Tuple[int, float, Dict[str, int]]:
+    """The steady-path counters, for a query's start-of-query snapshot."""
+    with _LOCK:
+        return (_STATS["dispatches"], _STATS["dispatch_s"],
+                dict(_DISPATCHES_BY_KIND))
+
+
+def dispatch_delta(start) -> Dict:
+    """What the process dispatched since ``start``: the per-query part of
+    the summary (``QueryExecution.finish``)."""
+    n0, s0, by0 = start
+    n, s, by = dispatch_totals()
+    return {"dispatches": n - n0, "dispatch_s": round(s - s0, 6),
+            "dispatches_by_kind": {k: v - by0.get(k, 0)
+                                   for k, v in by.items()
+                                   if v != by0.get(k, 0)}}
 
 
 def reset_stats() -> None:
@@ -540,8 +594,9 @@ def reset_stats() -> None:
     programs stay cached."""
     with _LOCK:
         for k in _STATS:
-            _STATS[k] = 0.0 if k == "compile_s" else 0
+            _STATS[k] = 0.0 if k in ("compile_s", "dispatch_s") else 0
         _TRACES_BY_KIND.clear()
+        _DISPATCHES_BY_KIND.clear()
         _ASYNC_ERROR[0] = None
 
 

@@ -162,22 +162,29 @@ def _probe_ranges(probe_keys: List[DeviceColumn], built: BuiltSide):
     key = ("probe", tuple(_col_sig(c) for c in probe_keys),
            built.hashes_sorted.shape, tuple(built.widths))
     def build():
+        import jax
         bucket = probe_keys[0].bucket
         dtypes = [c.data_type for c in probe_keys]
         widths = built.widths
 
         def run(arrs, row_count, hs):
-            cols = [DeviceColumn(d, v, bucket, dtypes[i], ln)
-                    for i, (d, v, ln) in enumerate(arrs)]
-            rowpos = jnp.arange(bucket, dtype=np.int32)
-            inrow = rowpos < row_count
-            h = _hash_rows(cols, widths, inrow, jnp)
-            lo = jnp.searchsorted(hs, h, side="left").astype(np.int64)
-            hi = jnp.searchsorted(hs, h, side="right").astype(np.int64)
-            # sentinel probe rows (padding) must not match sentinel build pad
-            counts = jnp.where(inrow & (h != _SENTINEL), hi - lo, 0)
-            offsets = prefix_sum(counts, jnp) - counts
-            return lo, counts, offsets, jnp.sum(counts)
+            # the named scopes are the engine's names for the program's
+            # phases in every XLA op's op_name (metadata only)
+            with jax.named_scope("hash"):
+                cols = [DeviceColumn(d, v, bucket, dtypes[i], ln)
+                        for i, (d, v, ln) in enumerate(arrs)]
+                rowpos = jnp.arange(bucket, dtype=np.int32)
+                inrow = rowpos < row_count
+                h = _hash_rows(cols, widths, inrow, jnp)
+            with jax.named_scope("search"):
+                lo = jnp.searchsorted(hs, h, side="left").astype(np.int64)
+                hi = jnp.searchsorted(hs, h, side="right").astype(np.int64)
+            with jax.named_scope("offsets"):
+                # sentinel probe rows (padding) must not match sentinel
+                # build pad
+                counts = jnp.where(inrow & (h != _SENTINEL), hi - lo, 0)
+                offsets = prefix_sum(counts, jnp) - counts
+                return lo, counts, offsets, jnp.sum(counts)
 
         return run
     from spark_rapids_tpu.exec.stage_compiler import get_or_build
@@ -202,6 +209,7 @@ def _expand_verify(probe: ColumnarBatch, probe_ordinals, built: BuiltSide,
     key = ("pairs", out_bucket, tuple(_col_sig(c) for c in pkeys),
            tuple(_col_sig(c) for c in bkeys), null_safe, tuple(built.widths))
     def build():
+        import jax
         p_bucket = probe.bucket
         b_bucket = built.batch.bucket
         pdt = [c.data_type for c in pkeys]
@@ -213,29 +221,33 @@ def _expand_verify(probe: ColumnarBatch, probe_ordinals, built: BuiltSide,
                      for i, (d, v, ln) in enumerate(parrs)]
             bcols = [DeviceColumn(d, v, b_bucket, bdt[i], ln)
                      for i, (d, v, ln) in enumerate(barrs)]
-            r = jnp.arange(out_bucket, dtype=np.int64)
-            # probe row for each output pair: last offset <= r
-            p = jnp.searchsorted(offsets, r, side="right").astype(np.int64) - 1
-            p = jnp.clip(p, 0, p_bucket - 1)
-            j = r - jnp.take(offsets, p)
-            spos = jnp.take(lo, p) + j          # position in sorted build
-            spos = jnp.clip(spos, 0, b_bucket - 1)
-            b = jnp.take(perm, spos).astype(np.int64)   # original build row
-            live = r < total
-            keep = live & (p < p_count) & (b < b_count)
+            with jax.named_scope("expand"):
+                r = jnp.arange(out_bucket, dtype=np.int64)
+                # probe row for each output pair: last offset <= r
+                p = jnp.searchsorted(offsets, r,
+                                     side="right").astype(np.int64) - 1
+                p = jnp.clip(p, 0, p_bucket - 1)
+                j = r - jnp.take(offsets, p)
+                spos = jnp.take(lo, p) + j      # position in sorted build
+                spos = jnp.clip(spos, 0, b_bucket - 1)
+                # original build row
+                b = jnp.take(perm, spos).astype(np.int64)
+                live = r < total
+                keep = live & (p < p_count) & (b < b_count)
             # verify true equality on masked words (collisions + nulls)
-            for ki, (pc, bc) in enumerate(zip(pcols, bcols)):
-                pw = _key_words(pc, jnp, widths[ki])
-                bw = _key_words(bc, jnp, widths[ki])
-                eq = jnp.ones(out_bucket, dtype=bool)
-                for a, bword in zip(pw, bw):
-                    av = jnp.take(a, p, axis=0)
-                    bv = jnp.take(bword, b, axis=0)
-                    eq = eq & (av == bv)
-                if not null_safe[ki]:
-                    eq = eq & jnp.take(pc.validity, p) & \
-                        jnp.take(bc.validity, b)
-                keep = keep & eq
+            with jax.named_scope("verify"):
+                for ki, (pc, bc) in enumerate(zip(pcols, bcols)):
+                    pw = _key_words(pc, jnp, widths[ki])
+                    bw = _key_words(bc, jnp, widths[ki])
+                    eq = jnp.ones(out_bucket, dtype=bool)
+                    for a, bword in zip(pw, bw):
+                        av = jnp.take(a, p, axis=0)
+                        bv = jnp.take(bword, b, axis=0)
+                        eq = eq & (av == bv)
+                    if not null_safe[ki]:
+                        eq = eq & jnp.take(pc.validity, p) & \
+                            jnp.take(bc.validity, b)
+                    keep = keep & eq
             return p, b, keep
 
         return run

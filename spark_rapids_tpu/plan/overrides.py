@@ -516,7 +516,17 @@ class TpuOverrides:
               skip_pruning: bool = False) -> Exec:
         """``for_explain`` produces the would-be plan without the test-mode
         all-on-device assertion (introspection must not raise on fallback).
-        ``skip_pruning`` is set by callers that already pruned (count())."""
+        ``skip_pruning`` is set by callers that already pruned (count()).
+        A rewrite that is going to run is one ``plan.rewrite`` span (a
+        speculation replay rewrites again, so it has two)."""
+        if for_explain:
+            return self._apply(plan, True, skip_pruning)
+        from spark_rapids_tpu.aux.tracing import span
+        with span("plan.rewrite"):
+            return self._apply(plan, False, skip_pruning)
+
+    def _apply(self, plan: Exec, for_explain: bool,
+               skip_pruning: bool) -> Exec:
         from spark_rapids_tpu.plan.base import (set_task_oom_injection,
                                                 set_task_parallelism,
                                                 set_task_retry_policy)
@@ -710,8 +720,6 @@ class TpuOverrides:
             level = MetricLevel.parse(
                 conf.get(C.METRICS_LEVEL.key, "MODERATE"))
             instrument_plan(out, level)
-        from spark_rapids_tpu.aux import profiler as _prof
-        _prof.set_ranges_enabled(bool(conf.get(C.RANGES_ENABLED.key)))
         return out
 
     def _coalesce_after_device_sources(self, plan: Exec) -> Exec:

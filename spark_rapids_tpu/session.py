@@ -11,6 +11,7 @@ per query, GpuOverrides.scala:4564).
 from __future__ import annotations
 
 import itertools
+import time
 from typing import Dict, List, Optional, Sequence, Union
 
 from spark_rapids_tpu import types as T
@@ -129,9 +130,20 @@ class TpuSession:
         """Executes SQL text against registered temp views (the reference
         accepts arbitrary Spark SQL via Catalyst; here sql/ carries the
         parser + analyzer for the TPC-DS-class dialect)."""
+        from spark_rapids_tpu.aux.tracing import span
         from spark_rapids_tpu.sql.analyzer import Analyzer
         from spark_rapids_tpu.sql.parser import parse
-        return Analyzer(self).plan(parse(text))
+        # no query runs yet: the spans go to the profiler's trace at once,
+        # and the DataFrame keeps the intervals for the query that runs it
+        t0 = time.monotonic()
+        with span("plan.parse"):
+            tree = parse(text)
+        t1 = time.monotonic()
+        with span("plan.analyze"):
+            df = Analyzer(self).plan(tree)
+        df._planned = (("plan.parse", t0, t1),
+                       ("plan.analyze", t1, time.monotonic()))
+        return df
 
     def create_or_replace_temp_view(self, name: str, df: "DataFrame") -> None:
         self._views[name.lower()] = df
@@ -302,6 +314,12 @@ def rows_from_host_batch(batch) -> List[dict]:
     shape, shared by ``DataFrame.collect`` and the serving layer's
     ``Submission.result`` so served rows can never drift from
     DataFrame rows."""
+    from spark_rapids_tpu.aux.tracing import span
+    with span("result.rows"):
+        return _rows(batch)
+
+
+def _rows(batch) -> List[dict]:
     d = batch.to_pydict()
     names = list(d.keys())
     return [dict(zip(names, row)) for row in zip(*d.values())] \
@@ -316,23 +334,32 @@ def collect_with_speculation(conf, plan_factory) -> HostColumnarBatch:
     physical plan — called again for the replay so the factory can
     re-arm per-execution state (CTE epochs) or re-plan."""
     from spark_rapids_tpu import config as C
+    from spark_rapids_tpu.aux import tracing
     from spark_rapids_tpu.ops.speculation import (SpeculationOverflow,
                                                   no_speculation,
                                                   speculation_scope)
+
+    def collect():
+        plan = plan_factory()
+        with tracing.run_span(plan):
+            return plan.collect_host()
+
     if not conf.get(C.SPECULATIVE_SIZING_ENABLED.key):
         with no_speculation():
-            return plan_factory().collect_host()
+            return collect()
     try:
         with speculation_scope() as ctx:
-            out = plan_factory().collect_host()
+            out = collect()
             if ctx is not None:
                 ctx.check()   # one sync over every overflow flag
             return out
     except SpeculationOverflow:
         # a speculative output bucket was too small somewhere: replay
-        # the whole action with exact (sync-per-decision) sizing
-        with no_speculation():
-            return plan_factory().collect_host()
+        # the whole action with exact (sync-per-decision) sizing.  A
+        # query that ran twice says so: the span and the counter
+        tracing.add_count("speculation_replays")
+        with tracing.span("exec.replay"), no_speculation():
+            return collect()
 
 
 class DataFrame:
@@ -341,6 +368,10 @@ class DataFrame:
     def __init__(self, plan: Exec, session: TpuSession):
         self._plan = plan
         self._session = session
+        #: the closed ``plan.parse``/``plan.analyze`` intervals of the
+        #: text this DataFrame came from (``TpuSession.sql``), until the
+        #: first query that runs it adopts them
+        self._planned = ()
 
     @property
     def schema(self) -> T.StructType:
@@ -802,31 +833,44 @@ class DataFrame:
             q.attach_plan(plan)
         return plan
 
-    def collect_batch(self) -> HostColumnarBatch:
+    def _query_scope(self, description: str):
+        """The action's query; the first one adopts the text's planning
+        spans."""
         from spark_rapids_tpu.aux.tracing import query_scope
-        with query_scope(self._session.conf, "collect"):
-            return self._collect_batch_traced()
+        planned, self._planned = self._planned, ()
+        return query_scope(self._session.conf, description, planned)
 
-    def _collect_batch_traced(self) -> HostColumnarBatch:
-        return collect_with_speculation(self._session.conf,
-                                        self._executed_plan)
+    def _collect_as(self, convert):
+        """Collects and converts (``result.rows``) inside one query, so
+        the query's duration is what the client waited."""
+        from spark_rapids_tpu.aux.tracing import span
+        with self._query_scope("collect"):
+            batch = collect_with_speculation(self._session.conf,
+                                             self._executed_plan)
+            if convert is None:
+                return batch
+            with span("result.rows"):
+                return convert(batch)
+
+    def collect_batch(self) -> HostColumnarBatch:
+        return self._collect_as(None)
 
     def to_pydict(self) -> Dict[str, list]:
-        return self.collect_batch().to_pydict()
+        return self._collect_as(lambda batch: batch.to_pydict())
 
     def to_arrow(self):
         import pyarrow as pa
-        return pa.Table.from_batches([self.collect_batch().to_arrow()])
+        return self._collect_as(
+            lambda batch: pa.Table.from_batches([batch.to_arrow()]))
 
     def to_pandas(self):
         return self.to_arrow().to_pandas()
 
     def collect(self) -> List[dict]:
-        return rows_from_host_batch(self.collect_batch())
+        return self._collect_as(_rows)
 
     def count(self) -> int:
-        from spark_rapids_tpu.aux import events as EV
-        from spark_rapids_tpu.aux.tracing import query_scope
+        from spark_rapids_tpu.aux.tracing import run_span
         from spark_rapids_tpu.columnar.column import sum_counts
         from spark_rapids_tpu.plan.pruning import prune_columns
         # count needs row counts only: prune every column the plan's own
@@ -836,29 +880,31 @@ class DataFrame:
         if self._session.conf.get(C.COLUMN_PRUNING_ENABLED.key, True):
             plan = prune_columns(plan, required=set())
         overrides = TpuOverrides(self._session.conf)
-        with query_scope(self._session.conf, "count"):
+        with self._query_scope("count"):
             # already pruned above (with the tighter empty required-set);
             # don't pay a second tree walk inside apply()
             executed = overrides.apply(plan, skip_pruning=True)
-            q = EV.active_query()
-            if q is not None:
-                q.attach_plan(executed)
-            return sum_counts([b.row_count for b in executed.execute_all()])
+            with run_span(executed):
+                return sum_counts([b.row_count
+                                   for b in executed.execute_all()])
 
     def write_parquet(self, path: str) -> None:
-        from spark_rapids_tpu.aux.tracing import query_scope
+        from spark_rapids_tpu.aux.tracing import run_span
         from spark_rapids_tpu.io.parquet import write_parquet
-        with query_scope(self._session.conf, "write_parquet"):
-            write_parquet(self._executed_plan().execute_all(), path,
-                          self.schema)
+        with self._query_scope("write_parquet"):
+            plan = self._executed_plan()
+            with run_span(plan):
+                write_parquet(plan.execute_all(), path, self.schema)
 
     def write_hive_text(self, path: str, serde=None) -> None:
         """Hive text table write (reference: GpuHiveTextFileFormat)."""
-        from spark_rapids_tpu.aux.tracing import query_scope
+        from spark_rapids_tpu.aux.tracing import run_span
         from spark_rapids_tpu.hive.table import write_hive_text
-        with query_scope(self._session.conf, "write_hive_text"):
-            write_hive_text(self._executed_plan().execute_all(), path,
-                            self.schema, serde=serde)
+        with self._query_scope("write_hive_text"):
+            plan = self._executed_plan()
+            with run_span(plan):
+                write_hive_text(plan.execute_all(), path, self.schema,
+                                serde=serde)
 
     @property
     def write(self):

@@ -61,9 +61,12 @@ class ReadDiagnostics:
 
 
 class SpanNode:
-    """One exec span reconstructed from a ``spanMetrics`` row."""
+    """One span reconstructed from a ``spanMetrics`` row: an exec span
+    (one plan node), or a ``phase`` span of the query path
+    (``kind == "phase"``: ``plan.rewrite``, ``exec.run``, ``xfer.d2h``...,
+    its attributes under ``metrics``)."""
 
-    __slots__ = ("span_id", "parent_id", "depth", "name", "desc",
+    __slots__ = ("span_id", "parent_id", "depth", "name", "desc", "kind",
                  "metrics", "children", "partitions", "start_s", "end_s")
 
     def __init__(self, row: Dict):
@@ -72,12 +75,13 @@ class SpanNode:
         self.depth = row.get("depth", 1)
         self.name = row.get("node", "?")
         self.desc = row.get("desc", self.name)
+        self.kind = row.get("kind", "exec")
         self.start_s = row.get("start_s")
         self.end_s = row.get("end_s")
         self.partitions = row.get("partitions", [])
         self.children: List["SpanNode"] = []
-        meta = {"span_id", "parent_id", "depth", "node", "desc",
-                "start_s", "end_s", "partitions"}
+        meta = {"span_id", "parent_id", "depth", "node", "desc", "kind",
+                "device", "start_s", "end_s", "partitions"}
         self.metrics = {k: v for k, v in row.items() if k not in meta}
 
     @property
@@ -112,6 +116,8 @@ class QueryProfile:
         self.events: List[Event] = []
         self.spans: Dict[int, SpanNode] = {}
         self.roots: List[SpanNode] = []
+        #: the query path's phase spans, in the order they opened
+        self.phases: List[SpanNode] = []
         self.samples: List[Event] = []
         self.complete = False
 
@@ -313,10 +319,13 @@ def profiles_from_events(events: List[Event], diag: ReadDiagnostics
             row = dict(ev.payload)
             row.setdefault("span_id", ev.span_id)
             sp = SpanNode(row)
-            if sp.span_id >= 0:
+            if sp.kind == "phase":
+                qp.phases.append(sp)
+            elif sp.span_id >= 0:
                 qp.spans[sp.span_id] = sp
     for qp in out:
         qp._link_spans()
+        qp.phases.sort(key=lambda sp: (sp.start_s or 0.0, sp.span_id))
         if qp.start_ts is not None and qp.end_ts is not None:
             qp.samples = [s for s in samples_by_run.get(qp.run, [])
                           if qp.start_ts <= s.ts <= qp.end_ts]

@@ -10,6 +10,8 @@ attached to the run:
   description), timestamps relative to the query run's earliest event;
 - the exec-span tree renders as nested complete ("X") slices on a
   ``plan`` thread (span nesting reconstructs operator containment);
+- the query path's phase spans (``plan.rewrite``, ``exec.run``,
+  ``xfer.d2h``...; ``aux.tracing.span``) render on a ``phases`` thread;
 - per-partition task timelines render on one thread per partition
   index — the gantt ``tools profile`` draws in ASCII, zoomable;
 - duration-carrying events land on per-resource threads:
@@ -43,6 +45,7 @@ _TID_TRANSITIONS = 2
 _TID_COMPILE = 3
 _TID_SPILL = 4
 _TID_ICI = 5
+_TID_PHASES = 6
 _TID_PARTITION_BASE = 100
 
 #: event kind -> (thread id, slice-name prefix) for duration events
@@ -159,6 +162,7 @@ def build_trace(profiles: List[QueryProfile],
         events.append(_meta(pid, "", _TID_COMPILE, "compile"))
         events.append(_meta(pid, "", _TID_SPILL, "spill"))
         events.append(_meta(pid, "", _TID_ICI, "ici"))
+        events.append(_meta(pid, "", _TID_PHASES, "phases"))
         pidxs = sorted({int(part["pidx"])
                         for sp in qp.exec_spans()
                         for part in sp.partitions
@@ -168,6 +172,15 @@ def build_trace(profiles: List[QueryProfile],
                                 f"partition {pidx}"))
         for root in qp.roots:
             _span_slices(root, pid, base, events)
+        for sp in qp.phases:
+            if sp.start_s is not None and sp.end_s is not None:
+                events.append({"ph": "X", "pid": pid, "tid": _TID_PHASES,
+                               # a text is planned before its query
+                               # begins: the zero is the query's start
+                               "ts": _us(max(0.0, sp.start_s - base)),
+                               "dur": _us(max(0.0, sp.end_s - sp.start_s)),
+                               "name": sp.name, "cat": "phase",
+                               "args": dict(sp.metrics)})
         _query_events(qp, pid, base, events)
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 

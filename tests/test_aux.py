@@ -78,18 +78,7 @@ def test_dump_batch_and_dump_on_error(tmp_path):
         next(it)
 
 
-def test_profiler_ranges_and_trace(tmp_path):
-    PROF.reset_range_stats()
-    PROF.set_ranges_enabled(True)
-    try:
-        with PROF.op_range("unit-op"):
-            pass
-        with PROF.op_range("unit-op"):
-            pass
-        stats = PROF.range_stats()
-        assert stats["unit-op"]["count"] == 2
-    finally:
-        PROF.set_ranges_enabled(False)
+def test_profiler_trace(tmp_path):
     prof = PROF.Profiler(str(tmp_path / "trace"))
     try:
         with prof.scoped():

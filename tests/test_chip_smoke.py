@@ -88,21 +88,36 @@ def test_serving_phase(tpcds):
 
 
 def test_counters_phase(sessions):
-    out = S.phase_counters({"built": False}, S.CacheCounters(), "unset")
+    """The phase judges what the smoke added to the process-wide counters,
+    so what another test file left in this worker is not its fault, and
+    what the smoke's own phases add is."""
+    from spark_rapids_tpu.aux import faults
+    faults.note_recovery("collective_fallbacks")    # another test's
+    baseline = S.hiding_counters()
+    out = S.phase_counters({"built": False}, S.CacheCounters(), "unset",
+                           baseline)
     assert out["async_failures"] == 0 and out["ledger_errors"] == 0
-    assert out["collective_fallbacks"] == 0
+    assert out["collective_fallbacks"] == 0 and out["recoveries"] == {}
     assert out["transitions"]["h2d_count"] >= 0
+    faults.note_recovery("collective_fallbacks")    # the smoke's own
+    with pytest.raises(S.SmokeFailure, match="fell back to the host"):
+        S.phase_counters({"built": False}, S.CacheCounters(), "unset",
+                         baseline)
 
 
 def test_mesh_phase_on_virtual_devices(tmp_path):
     """The ``--chips 4`` phase on four of the virtual CPU devices: the
     exchange takes the in-mesh path and the shards sit on four devices."""
     from spark_rapids_tpu.parallel.mesh import set_active_mesh
+    from spark_rapids_tpu.aux import faults
     tpu, cpu = S.make_sessions(
         S.mesh_conf(4, str(tmp_path / "events.jsonl")))
     try:
         S.register_tpcds((tpu, cpu), 1, str(tmp_path), num_partitions=4,
                          storage="memory")
+        # a fallback that another test of this worker left behind is not
+        # the phase's: it checks what it adds itself
+        faults.note_recovery("collective_fallbacks")
         out = S.phase_mesh(tpu, cpu, 4)
     finally:
         set_active_mesh(None)

@@ -12,7 +12,6 @@ import pytest
 from spark_rapids_tpu import config as C
 from spark_rapids_tpu import functions as F
 from spark_rapids_tpu.aux import events as EV
-from spark_rapids_tpu.aux import profiler as PROF
 from spark_rapids_tpu.aux import tracing as TR
 from spark_rapids_tpu.aux.metrics import MetricLevel, collect_metrics
 from spark_rapids_tpu.columnar import batch_from_pydict
@@ -234,31 +233,6 @@ def test_metrics_level_validated_at_set_conf():
     assert MetricLevel.parse(" debug ") is MetricLevel.DEBUG
     # valid values still round-trip through set_conf
     s.set_conf("spark.rapids.sql.metrics.level", "ESSENTIAL")
-
-
-def test_op_ranges_cover_exec_names():
-    """Satellite: profiler op ranges wire through the exec
-    execute_partition wrappers, so traces carry operator names."""
-    PROF.reset_range_stats()
-    s = tpu_session({"spark.rapids.sql.test.enabled": "false",
-                     "spark.rapids.sql.nvtx.enabled": "true"})
-    try:
-        (s.create_dataframe({"a": np.arange(500, dtype=np.int64)})
-         .select(Alias(col("a") * lit(2), "b")).collect())
-        stats = PROF.range_stats()
-        assert any(name.endswith("Exec") for name in stats), \
-            f"expected exec-named ranges, got {sorted(stats)}"
-    finally:
-        PROF.set_ranges_enabled(False)
-        PROF.reset_range_stats()
-
-
-def test_ranges_disabled_is_default_and_unrecorded():
-    PROF.reset_range_stats()
-    s = tpu_session({"spark.rapids.sql.test.enabled": "false"})
-    (s.create_dataframe({"a": np.arange(100, dtype=np.int64)})
-     .select(col("a")).collect())
-    assert PROF.range_stats() == {}
 
 
 def test_render_prometheus_parses():
