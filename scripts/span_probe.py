@@ -13,7 +13,8 @@ the harness would delete, and writes ``chiprun_out/span_probe.json``:
   (an event's duration less what the events inside it on its thread
   cover) and the inclusive time;
 - the device's time by program kind and by the scope each operation's
-  ``op_name`` begins with (``jax.named_scope``).
+  ``op_name`` begins with (``jax.named_scope``), and the thirty
+  ``op_name``s that took most of it.
 
 Not part of the benchmark: it measures nothing the driver compares."""
 
@@ -63,7 +64,8 @@ def self_times(events):
 
 
 def device_ops_by_scope(path: str):
-    """Device time by ``(program kind, head of the op_name)``.  The
+    """Device time by ``(program kind, head of the op_name)``, and of the
+    thirty longest ``op_name``s.  The
     op_name (``tf_op``) is a stat of the event's metadata, which
     ``ProfileData`` does not hand out: read from the xplane protobuf with
     the message classes that the installed tensorflow ships (loaded alone,
@@ -71,7 +73,7 @@ def device_ops_by_scope(path: str):
     import importlib.util
     found = importlib.util.find_spec("tensorflow")
     if found is None:
-        return "no xplane_pb2 here (tensorflow is not installed)"
+        return "no xplane_pb2 here (tensorflow is not installed)", []
     pb2 = os.path.join(list(found.submodule_search_locations)[0], "tsl",
                        "profiler", "protobuf", "xplane_pb2.py")
     spec = importlib.util.spec_from_file_location("xplane_pb2", pb2)
@@ -81,6 +83,12 @@ def device_ops_by_scope(path: str):
     with open(path, "rb") as f:
         space.ParseFromString(f.read())
     out: dict = {}
+    by_op: dict = {}
+
+    def scope_of(op_name):
+        m = OP_NAME.match(op_name)
+        return m.groups() if m else ("(other)", "")
+
     for plane in space.planes:
         if not plane.name.startswith("/device:TPU:"):
             continue
@@ -97,17 +105,20 @@ def device_ops_by_scope(path: str):
             events = []
             for e in line.events:
                 op_name = op_names.get(e.metadata_id, "")
-                m = OP_NAME.match(op_name)
-                key = m.groups() if m else ("(other)", "")
-                got = out.setdefault(key, {"ops": 0, "seconds": 0.0,
-                                           "example": op_name[:120]})
+                got = out.setdefault(scope_of(op_name),
+                                     {"ops": 0, "seconds": 0.0,
+                                      "example": op_name[:120]})
                 got["ops"] += 1
-                events.append((key, e.offset_ps, e.duration_ps))
+                events.append((op_name, e.offset_ps, e.duration_ps))
             # self time: a loop's body is counted, the loop only for the rest
-            for key, ps in self_times(events).items():
-                out[key]["seconds"] += ps / 1e12
-    return [{"kind": k[0], "scope": k[1], **v} for k, v in
-            sorted(out.items(), key=lambda kv: -kv[1]["seconds"])]
+            for op_name, ps in self_times(events).items():
+                out[scope_of(op_name)]["seconds"] += ps / 1e12
+                by_op[op_name] = by_op.get(op_name, 0.0) + ps / 1e12
+    scopes = [{"kind": k[0], "scope": k[1], **v} for k, v in
+              sorted(out.items(), key=lambda kv: -kv[1]["seconds"])]
+    ops = [{"op_name": k, "seconds": v} for k, v in
+           sorted(by_op.items(), key=lambda kv: -kv[1])[:30]]
+    return scopes, ops
 
 
 def inspect(path: str) -> dict:
@@ -150,10 +161,12 @@ def inspect(path: str) -> dict:
     for got in srt.values():
         got["query_ids"] = sorted(got["query_ids"], key=str)
     threads.sort(key=lambda t: -t["events"])
+    scopes, ops = device_ops_by_scope(path)
     return {"collect_spans": len(collects),
             "srt_events_inside_bench_collect": srt,
             "host_threads_inside_bench_collect": threads[:8],
-            "device_ops_by_scope": device_ops_by_scope(path)}
+            "device_ops_by_scope": scopes,
+            "device_ops_by_op_name": ops}
 
 
 def main(argv=None) -> int:

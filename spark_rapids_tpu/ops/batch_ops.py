@@ -104,6 +104,24 @@ def prefix_sum(x, jnp):
     return (rows + before[:, None]).reshape(n)
 
 
+def expand_positions(offsets, out_bucket: int, jnp):
+    """For every ``r`` in ``[0, out_bucket)`` the count of ``offsets[i] <=
+    r``, less one, as int32: the source row of output position ``r`` when
+    row ``i`` expands to the positions from ``offsets[i]`` up to
+    ``offsets[i + 1]``.  ``offsets`` is non-decreasing and starts at 0, so
+    this is ``searchsorted(offsets, arange(out_bucket), "right") - 1``
+    without the search: the queries are an iota, and a histogram of the
+    offsets, summed, counts the same thing with no loop and no gather (a
+    per-row binary search was 48% of a TPC-DS q3's device time, PERF.md
+    section 6, PR 29).  Offsets at or past ``out_bucket`` count for no
+    position; they may exceed 32 bits and are sent out of range before
+    they are narrowed."""
+    idx = jnp.minimum(offsets, out_bucket).astype(np.int32)
+    hist = jnp.zeros(out_bucket, dtype=np.int32).at[idx].add(
+        1, mode="drop", indices_are_sorted=True)
+    return prefix_sum(hist, jnp) - 1
+
+
 def compaction_perm(keep, jnp):
     """int32 permutation that moves kept rows to the front, stable."""
     from spark_rapids_tpu.ops.sort_ops import lex_sort_perm

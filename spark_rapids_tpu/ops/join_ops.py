@@ -10,8 +10,12 @@ binary search, so an equi-join becomes:
 2. sort the BUILD side by hash (``sort_ops.lex_sort_perm``);
 3. ``searchsorted`` each PROBE hash into the sorted build hashes -> a
    candidate range [lo, hi) per probe row (static shapes throughout);
-4. expand candidate pairs into a padded pair table (the only host syncs are
-   the candidate total and the final row count);
+4. expand candidate pairs into a padded pair table: output position ``r``
+   belongs to the last probe row whose offset is ``<= r``, found for all
+   positions at once by a histogram of the offsets and a prefix sum
+   (``batch_ops.expand_positions``; the positions are an iota, so no
+   search is needed), then three gathers give the build row (the only
+   host syncs are the candidate total and the final row count);
 5. VERIFY true key equality per pair (hash collisions and null semantics are
    resolved here, on masked sortable words), and
 6. finalize per join type: compact kept pairs, append null-extended
@@ -36,7 +40,8 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn, bucket_rows
-from spark_rapids_tpu.ops.batch_ops import compaction_perm, prefix_sum
+from spark_rapids_tpu.ops.batch_ops import (compaction_perm,
+                                            expand_positions, prefix_sum)
 
 
 def _jx():
@@ -215,6 +220,12 @@ def _expand_verify(probe: ColumnarBatch, probe_ordinals, built: BuiltSide,
         pdt = [c.data_type for c in pkeys]
         bdt = [c.data_type for c in bkeys]
         widths = built.widths
+        if max(out_bucket, b_bucket) > 1 << 30:
+            # lo + j must fit 32 bits (``lex_sort_perm`` holds the build
+            # side and the compaction of the pairs to the same limit)
+            raise ValueError(f"join.pair: a pair table of {out_bucket} rows "
+                             f"over a build side of {b_bucket} does not fit "
+                             "32-bit positions")
 
         def run(parrs, barrs, lo, offsets, total, perm, p_count, b_count):
             pcols = [DeviceColumn(d, v, p_bucket, pdt[i], ln)
@@ -222,16 +233,23 @@ def _expand_verify(probe: ColumnarBatch, probe_ordinals, built: BuiltSide,
             bcols = [DeviceColumn(d, v, b_bucket, bdt[i], ln)
                      for i, (d, v, ln) in enumerate(barrs)]
             with jax.named_scope("expand"):
-                r = jnp.arange(out_bucket, dtype=np.int64)
+                # positions are 32-bit inside the program (every one is
+                # bounded by a bucket, and a 64-bit gather costs the TPU
+                # two); only what is returned is widened
+                r = jnp.arange(out_bucket, dtype=np.int32)
                 # probe row for each output pair: last offset <= r
-                p = jnp.searchsorted(offsets, r,
-                                     side="right").astype(np.int64) - 1
+                p = expand_positions(offsets, out_bucket, jnp)
                 p = jnp.clip(p, 0, p_bucket - 1)
-                j = r - jnp.take(offsets, p)
-                spos = jnp.take(lo, p) + j      # position in sorted build
+                # offsets[p] <= r < out_bucket for every r: clamping the
+                # 64-bit offsets (a candidate total may pass 2^31) changes
+                # no offset that is read
+                j = r - jnp.take(
+                    jnp.minimum(offsets, out_bucket).astype(np.int32), p)
+                # position in sorted build
+                spos = jnp.take(lo.astype(np.int32), p) + j
                 spos = jnp.clip(spos, 0, b_bucket - 1)
                 # original build row
-                b = jnp.take(perm, spos).astype(np.int64)
+                b = jnp.take(perm, spos)
                 live = r < total
                 keep = live & (p < p_count) & (b < b_count)
             # verify true equality on masked words (collisions + nulls)
@@ -248,7 +266,7 @@ def _expand_verify(probe: ColumnarBatch, probe_ordinals, built: BuiltSide,
                         eq = eq & jnp.take(pc.validity, p) & \
                             jnp.take(bc.validity, b)
                     keep = keep & eq
-            return p, b, keep
+            return p.astype(np.int64), b.astype(np.int64), keep
 
         return run
     from spark_rapids_tpu.exec.stage_compiler import get_or_build

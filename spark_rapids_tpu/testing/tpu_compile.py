@@ -127,24 +127,35 @@ def compile_for_chip(prog, specs, topo):
     return lowered.compile()  # lint: ok=aot-site (described chip)
 
 
+def _equations(jaxpr, scopes=()):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it, each
+    with the names of the ``jax.named_scope``s around it (a nested jaxpr's
+    own name stack starts empty: the caller's scopes are carried in)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        names = scopes + tuple(
+            n for n in str(eqn.source_info.name_stack).split("/") if n)
+        yield eqn, names
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _equations(sub.jaxpr, names)
+                elif isinstance(sub, Jaxpr):
+                    yield from _equations(sub, names)
+
+
 def sort_operand_counts(prog, specs) -> list:
     """Operand count of every ``sort`` in the program's jaxpr (nested
     jaxprs included): a count, not a clock."""
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-    counts: list = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "sort":
-                counts.append(len(eqn.invars))
-            for val in eqn.params.values():
-                for sub in (val if isinstance(val, (tuple, list))
-                            else (val,)):
-                    if isinstance(sub, ClosedJaxpr):
-                        walk(sub.jaxpr)
-                    elif isinstance(sub, Jaxpr):
-                        walk(sub)
-
     traced = prog._fn.trace(*specs)  # lint: ok=aot-site (jaxpr only)
-    walk(traced.jaxpr.jaxpr)
-    return counts
+    return [len(eqn.invars) for eqn, _names in _equations(traced.jaxpr.jaxpr)
+            if eqn.primitive.name == "sort"]
+
+
+def primitives_under_scope(fn, specs, scope: str) -> set:
+    """Names of the primitives that the jitted ``fn`` traces inside the
+    ``jax.named_scope`` called ``scope``, nested jaxprs included."""
+    traced = fn.trace(*specs)  # lint: ok=aot-site (jaxpr only)
+    return {eqn.primitive.name
+            for eqn, names in _equations(traced.jaxpr.jaxpr)
+            if scope in names}

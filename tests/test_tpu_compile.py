@@ -116,7 +116,7 @@ def test_two_key_group_by(tpu_branch):
     assert max(TC.sort_operand_counts(prog, specs)) <= 2
 
 
-def test_join_build_and_probe(tpu_branch):
+def test_join_build_probe_and_pairs(tpu_branch):
     import jax.numpy as jnp
     from spark_rapids_tpu.ops import join_ops as J
     build = _batch(LARGE, T.LONG, T.DOUBLE)
@@ -127,6 +127,10 @@ def test_join_build_and_probe(tpu_branch):
     built = J.BuiltSide(build, (0,), jnp.zeros(LARGE, dtype=np.uint64),
                         jnp.zeros(LARGE, dtype=np.int32), [1])
     _compiles(tpu_branch, J._probe_ranges, [probe.columns[0]], built)
+    # the pair table under speculative sizing: twice the probe bucket
+    ranges = jnp.zeros(1 << 19, dtype=np.int64)
+    _compiles(tpu_branch, J._expand_verify, probe, (0,), built, (False,),
+              ranges, ranges, jnp.int64(0), LARGE)
 
 
 def test_window_over_partition(tpu_branch):
