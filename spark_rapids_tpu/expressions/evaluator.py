@@ -158,7 +158,10 @@ def _signature(exprs, batch: ColumnarBatch) -> Tuple:
 
 
 def eval_exprs_tpu(exprs: Sequence[Expression], batch: ColumnarBatch,
-                   names: Optional[List[str]] = None) -> ColumnarBatch:
+                   names: Optional[List[str]] = None,
+                   kind: str = "expr.project") -> ColumnarBatch:
+    """``kind`` names the program for the stage compiler's counters and
+    for the device trace (``jit_run_<kind>``)."""
     from spark_rapids_tpu.columnar.column import _jnp
     from spark_rapids_tpu.columnar.encoding import (batch_has_encoded,
                                                     materialize_batch)
@@ -194,7 +197,7 @@ def eval_exprs_tpu(exprs: Sequence[Expression], batch: ColumnarBatch,
             return outs
         return run
 
-    fn = get_or_build("expr.project", key, build)
+    fn = get_or_build(kind, key, build)
 
     arrs = [(c.data, c.validity, c.lengths, c.elem_valid)
             for c in batch.columns]

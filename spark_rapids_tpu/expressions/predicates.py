@@ -101,7 +101,8 @@ class BinaryComparison(BinaryExpr):
                                               np.asarray(b.data), np)[()]),
                                T.BOOLEAN)
         if ctx.backend == "tpu" and (a.is_string or b.is_string):
-            a, b = _densify_string(a, ctx, xp), _densify_string(b, ctx, xp)
+            a, b = (_densify_string(a, ctx, xp, like=b),
+                    _densify_string(b, ctx, xp, like=a))
             cmp = self._device_string_cmp(a, b, xp)
             out = self._cmp(cmp, xp.zeros_like(cmp), xp)
             return TCol(out, valid, T.BOOLEAN)
@@ -152,18 +153,43 @@ def _decimal_side_to_double(c: TCol, ctx, xp) -> TCol:
     return DM.decimal_to_double(c, ctx, xp)
 
 
-def _densify_string(c: TCol, ctx: EvalContext, xp):
+def string_literal_planes(value, width: int = None):
+    """A scalar string (or binary) as the device holds one row of a string
+    column: its bytes zero-padded to ``width`` (the bucket of its own
+    length where none is given) and its length in bytes."""
+    raw = np.frombuffer(value.encode() if isinstance(value, str) else value,
+                        dtype=np.uint8)
+    if width is None:
+        from spark_rapids_tpu.columnar.column import bucket_strlen
+        width = bucket_strlen(max(1, len(raw)))
+    chars = np.zeros(width, dtype=np.uint8)
+    chars[:len(raw)] = raw
+    return chars, np.asarray(len(raw), dtype=np.int32)
+
+
+def _densify_string(c: TCol, ctx: EvalContext, xp, like: TCol = None):
+    """A scalar string as a per-row one: its bytes broadcast over the rows
+    inside the program, so the program holds the bytes once (or takes
+    them as an argument: a promoted literal, plan/stages.py) and never a
+    ``(row_count, width)`` constant.  Beside a per-row string ``like`` the
+    bytes take that column's width: a longer scalar keeps its true length
+    and the bytes the column can hold, which orders and tells it apart
+    from every value of the column as the whole would."""
     if not c.is_scalar:
         return c
-    s = c.data or ""
-    raw = np.frombuffer(s.encode() if isinstance(s, str) else s, dtype=np.uint8)
-    from spark_rapids_tpu.columnar.column import bucket_strlen
-    w = bucket_strlen(max(1, len(raw)))
-    chars = np.zeros((ctx.row_count, w), dtype=np.uint8)
-    chars[:, :len(raw)] = raw
-    lens = np.full(ctx.row_count, len(raw), dtype=np.int32)
-    return TCol(xp.asarray(chars), valid_array(c, ctx), c.dtype,
-                lengths=xp.asarray(lens))
+    if c.lengths is not None:       # promoted: traced bytes and length
+        chars, length = c.data, c.lengths
+    else:
+        chars, length = string_literal_planes(c.data or "")
+    if like is not None and like.is_string and not like.is_scalar:
+        w = like.data.shape[1]
+        chars = chars[:w] if chars.shape[0] >= w else \
+            xp.pad(chars, (0, w - chars.shape[0]))
+    rows = ctx.row_count
+    return TCol(xp.broadcast_to(xp.asarray(chars)[None, :],
+                                (rows, chars.shape[0])),
+                valid_array(c, ctx), c.dtype,
+                lengths=xp.full(rows, length, dtype=np.int32))
 
 
 def _numeric_align(ad, bd, xp):

@@ -20,7 +20,12 @@ literals sitting directly under comparison / +,-,* arithmetic whose
 sibling operand has the SAME data type are promoted (same-dtype operands
 make the strong-typed runtime scalar bit-identical to the weak-typed
 baked constant; mixed-dtype promotions could shift XLA's promotion rules
-and break the bit-identical-vs-CPU contract).
+and break the bit-identical-vs-CPU contract).  A string literal is
+promoted under a comparison against a per-row string: it travels as
+``PROMOTED_STRING_WIDTH`` zero-padded bytes plus its length, so the
+program depends on the column's width alone and ``cd_gender = 'M'`` and
+``cd_gender = 'F'`` (or a template's seven education values) share one
+executable.
 """
 
 from __future__ import annotations
@@ -55,7 +60,18 @@ class PromotedLiteral(Literal):
         vals = getattr(ctx, "literal_args", None)
         if vals is None:
             return self._as_tcol()
+        if isinstance(self._dtype, T.StringType):
+            # a scalar whose bytes and length are traced values: only a
+            # comparison's ``_densify_string`` reads it (``eligible``)
+            chars, length = vals[self.slot]
+            return TCol(chars, True, self._dtype, lengths=length,
+                        is_scalar=True)
         return TCol(vals[self.slot], True, self._dtype, is_scalar=True)
+
+
+#: bytes a promoted string literal travels in, whatever its own length
+#: (a longer literal stays a constant of its program)
+PROMOTED_STRING_WIDTH = 64
 
 
 def physical_literal(value, dtype):
@@ -63,18 +79,24 @@ def physical_literal(value, dtype):
     numpy scalar in the column's physical representation (date -> days,
     timestamp -> micros) — exactly what ``materialize`` bakes for the
     constant form (one shared conversion), so the compiled math is
-    identical."""
+    identical.  A string is its UTF-8 bytes, zero-padded to
+    ``PROMOTED_STRING_WIDTH``, and its length in bytes."""
     import numpy as np
+    if isinstance(dtype, T.StringType):
+        from spark_rapids_tpu.expressions.predicates import \
+            string_literal_planes
+        return string_literal_planes(value, PROMOTED_STRING_WIDTH)
     from spark_rapids_tpu.expressions.base import to_physical_scalar
     return np.asarray(to_physical_scalar(value), dtype=dtype.np_dtype)
 
 
 def _promotable_parents():
+    """(comparisons, arithmetic): a string promotes under the first only."""
     from spark_rapids_tpu.expressions import arithmetic as A
     from spark_rapids_tpu.expressions import predicates as P
-    return (P.EqualTo, P.NotEqual, P.LessThan, P.LessThanOrEqual,
-            P.GreaterThan, P.GreaterThanOrEqual, P.EqualNullSafe,
-            A.Add, A.Subtract, A.Multiply)
+    return ((P.EqualTo, P.NotEqual, P.LessThan, P.LessThanOrEqual,
+             P.GreaterThan, P.GreaterThanOrEqual, P.EqualNullSafe),
+            (A.Add, A.Subtract, A.Multiply))
 
 
 _PROMOTABLE_TYPES = (T.ByteType, T.ShortType, T.IntegerType, T.LongType,
@@ -86,7 +108,7 @@ def promote_stage_literals(ops) -> Tuple[list, List[PromotedLiteral]]:
     ``PromotedLiteral`` slots.  Returns (new ops, promoted literals in
     slot order).  Idempotent over already-promoted chains (re-fusion
     renumbers the slots from the carried values)."""
-    parents = _promotable_parents()
+    comparisons, arithmetic = _promotable_parents()
     promoted: List[PromotedLiteral] = []
 
     def has_input(e: Expression) -> bool:
@@ -98,11 +120,17 @@ def promote_stage_literals(ops) -> Tuple[list, List[PromotedLiteral]]:
             return True     # column ref / bound ref / lambda variable
         return any(has_input(c) for c in e.children)
 
-    def eligible(lit: Expression, sibling: Expression) -> bool:
+    def eligible(lit: Expression, sibling: Expression,
+                 parent: Expression) -> bool:
         if type(lit) not in (Literal, PromotedLiteral) or lit.value is None:
             return False
         dt = lit.data_type
-        if not isinstance(dt, _PROMOTABLE_TYPES) or \
+        if isinstance(dt, T.StringType):
+            if not isinstance(parent, comparisons) or \
+                    not isinstance(lit.value, str) or \
+                    len(lit.value.encode()) > PROMOTED_STRING_WIDTH:
+                return False
+        elif not isinstance(dt, _PROMOTABLE_TYPES) or \
                 getattr(dt, "np_dtype", None) is None:
             return False
         if not has_input(sibling):
@@ -117,9 +145,9 @@ def promote_stage_literals(ops) -> Tuple[list, List[PromotedLiteral]]:
 
     def walk(e: Expression) -> Expression:
         kids = [walk(c) for c in e.children]
-        if isinstance(e, parents) and len(kids) == 2:
+        if isinstance(e, comparisons + arithmetic) and len(kids) == 2:
             for i in (0, 1):
-                if eligible(kids[i], kids[1 - i]):
+                if eligible(kids[i], kids[1 - i], e):
                     pl = PromotedLiteral(kids[i].value, kids[i].data_type,
                                          len(promoted))
                     promoted.append(pl)

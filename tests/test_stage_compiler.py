@@ -159,7 +159,7 @@ def test_promotion_unit_placeholders_and_slots():
            ("project", [Add(v, Literal(1.5, T.DOUBLE)),
                         # dtype mismatch (INT col vs LONG literal): kept
                         GreaterThan(w, Literal(7, T.LONG)),
-                        # strings never promote
+                        # a bare string column has no literal to promote
                         BoundReference(2, T.STRING, True, "s")])]
     new_ops, promoted = promote_stage_literals(ops)
     assert len(promoted) == 2
@@ -170,6 +170,120 @@ def test_promotion_unit_placeholders_and_slots():
     assert isinstance(promoted[0], PromotedLiteral)
     # original tree untouched (plans are shared)
     assert "5" in ops[0][1].sql()
+
+
+def test_promotion_unit_strings():
+    """A string literal beside a per-row string is promoted under every
+    comparison; beside a literal, under arithmetic's parents or longer
+    than the width it travels in, it is not."""
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.expressions.base import BoundReference, Literal
+    from spark_rapids_tpu.expressions import predicates as P
+    from spark_rapids_tpu.plan.stages import (PROMOTED_STRING_WIDTH,
+                                              PromotedLiteral,
+                                              physical_literal,
+                                              promote_stage_literals)
+    s = BoundReference(0, T.STRING, True, "s")
+    comparisons = (P.EqualTo, P.NotEqual, P.LessThan, P.LessThanOrEqual,
+                   P.GreaterThan, P.GreaterThanOrEqual, P.EqualNullSafe)
+    ops = [("filter", cls(s, Literal("M", T.STRING)))
+           for cls in comparisons]
+    ops.append(("filter", P.EqualTo(Literal("Primary", T.STRING), s)))
+    new_ops, promoted = promote_stage_literals(ops)
+    assert [p.value for p in promoted] == ["M"] * 7 + ["Primary"]
+    assert all(isinstance(p, PromotedLiteral) for p in promoted)
+    assert new_ops[0][1].sql() == "(s = $lit0:string)"
+    assert new_ops[7][1].sql() == "($lit7:string = s)"
+    # the value, not the slot, is what the CPU oracle and explain read
+    assert promoted[7].eval_cpu(None).data == "Primary"
+    # one shape for every value: the program's key cannot follow it
+    for value in ("M", "Advanced Degree", "é"):
+        chars, length = physical_literal(value, T.STRING)
+        assert chars.shape == (PROMOTED_STRING_WIDTH,)
+        assert chars.dtype == np.uint8 and length.dtype == np.int32
+        assert bytes(chars[:int(length)]).decode() == value
+    kept = [("filter", P.EqualTo(Literal("a", T.STRING),
+                                 Literal("b", T.STRING))),
+            ("filter", P.EqualTo(s, Literal(None, T.STRING))),
+            ("filter", P.EqualTo(s, Literal(
+                "x" * (PROMOTED_STRING_WIDTH + 1), T.STRING)))]
+    _, promoted = promote_stage_literals(kept)
+    assert promoted == []
+
+
+def _string_data():
+    rng = np.random.default_rng(5)
+    values = ["M", "F", "Advanced Degree", "Advanced", "Primary", "",
+              "Advanced Degrees", None]
+    return {"s": [values[i] for i in rng.integers(0, len(values), 3000)],
+            "x": rng.integers(0, 9, 3000).astype(np.int64)}
+
+
+def _string_filter(df, op, value):
+    cond = {"=": lambda c, v: c == v, "<": lambda c, v: c < v,
+            ">=": lambda c, v: c >= v,
+            "<>": lambda c, v: c != v}[op](col("s"), lit(value))
+    return df.filter(cond).agg(F.sum("x").alias("sx"),
+                               F.count("x").alias("cx"))
+
+
+@pytest.mark.parametrize("op", ["=", "<", ">=", "<>"])
+def test_promoted_string_literals_share_one_program(op):
+    """'M', then 'F', then 'Advanced Degree' (the column's width), then a
+    literal wider than the column: no trace after the first, and every
+    answer is the CPU engine's, nulls and the empty string included."""
+    data = _string_data()
+    s = tpu_session()
+    df = s.create_dataframe(data, num_partitions=1)
+    cdf = cpu_session().create_dataframe(data, num_partitions=1)
+    first = _string_filter(df, op, "M").collect()
+    assert "lits[$lit0='M']" in _string_filter(df, op, "M").explain()
+    SC.reset_stats()
+    got = {"M": first}
+    for value in ("F", "Advanced Degree", "Advanced Degree and more", ""):
+        got[value] = _string_filter(df, op, value).collect()
+    st = SC.stats()
+    assert st["traces"] == 0, \
+        f"a new string literal retraced: {st['traces_by_kind']}"
+    for value, rows in got.items():
+        want = _string_filter(cdf, op, value).collect()
+        assert [tuple(r.values()) for r in rows] == \
+            [tuple(r.values()) for r in want], (op, value)
+    assert got["M"] != got["F"]            # the values bind
+
+
+def test_promoted_string_null_safe_equal_and_literal_first():
+    data = _string_data()
+
+    def fn(value):
+        def run(session):
+            from spark_rapids_tpu.expressions.predicates import (
+                EqualNullSafe, LessThan)
+            df = session.create_dataframe(data, num_partitions=1)
+            return (df.filter(EqualNullSafe(col("s"), lit(value)))
+                      .filter(LessThan(lit("A"), col("s")))
+                      .agg(F.count("x").alias("cx")))
+        return run
+    assert_tpu_and_cpu_are_equal_collect(fn("M"))
+    assert_tpu_and_cpu_are_equal_collect(fn("Advanced Degree"))
+
+
+@pytest.mark.parametrize("op", ["=", "<"])
+def test_a_string_literal_over_the_promoted_width_stays_baked(op):
+    """A literal longer than ``PROMOTED_STRING_WIDTH`` is the one kind a
+    comparison still bakes into its program: explain shows no slot for it,
+    and the answer is the CPU engine's."""
+    from spark_rapids_tpu.plan.stages import PROMOTED_STRING_WIDTH
+    data = _string_data()
+    wide = "Advanced Degree" + "s" * PROMOTED_STRING_WIDTH
+    df = tpu_session().create_dataframe(data, num_partitions=1)
+    assert "$lit0" not in _string_filter(df, op, wide).explain()
+    got = _string_filter(df, op, wide).collect()
+    want = _string_filter(cpu_session().create_dataframe(
+        data, num_partitions=1), op, wide).collect()
+    assert [tuple(r.values()) for r in got] == \
+        [tuple(r.values()) for r in want]
+    assert (got[0]["cx"] == 0) == (op == "=")   # equals none, follows some
 
 
 def test_promoted_literals_share_one_program_across_values():

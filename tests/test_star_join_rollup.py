@@ -1,0 +1,119 @@
+"""The star-join class of the TPC-DS store channel (the benchmark's q7 and
+q27: four joins with a demographic dimension filtered by three string
+parameters; q27 groups by ROLLUP) through ``TpuSession.sql``, on the
+benchmark generator's tables at a twentieth of SF1, against the benchmark's
+plain numpy references: exact rows, doubles to 1e-9.
+
+What the cell ``store_star_join`` needs of the engine is pinned here at a
+small size: the roll-up's fan-out runs at the size of the join chain's live
+rows and not at three padded copies of its output, the fan-out is counted,
+and a new GEN/MS/ES triple traces no program.
+"""
+
+import pytest
+
+from spark_rapids_tpu.aux import tracing
+from spark_rapids_tpu.exec import stage_compiler as SC
+from spark_rapids_tpu.exec.expand import EXPAND_MIN_BUCKET
+
+SEED = 2147493319
+#: store_sales 144,020 rows: the joins put out a 262,144-row bucket, well
+#: above ``EXPAND_MIN_BUCKET``, of which a q27 keeps some 200 rows
+SCALE_DOWN = 20
+PARAMS = {
+    "q7": [{"GEN": "M", "MS": "S", "ES": "College", "YEAR": 2000},
+           {"GEN": "F", "MS": "W", "ES": "Advanced Degree", "YEAR": 1999}],
+    "q27": [{"GEN": "F", "MS": "D", "ES": "Primary", "YEAR": 2001,
+             "STATE": "TN"},
+            {"GEN": "M", "MS": "U", "ES": "2 yr Degree", "YEAR": 1998,
+             "STATE": "TN"}],
+}
+
+
+@pytest.fixture(scope="module")
+def star():
+    """The cell as the benchmark builds it, a session over its tables, and
+    each text answered with both of its parameter draws (the first draw
+    builds the programs)."""
+    from benchmark import run as bench
+    from benchmark.literals import Query
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.session import TpuSession
+    # the cell's own texts are these templates with the strings fixed
+    # (``q7_qual``, ``q27_qual``): the tables are the same
+    cell = bench.Cell("store_star_join", SCALE_DOWN)
+    gen, tables = bench.make_tables(cell, SEED)
+    session = TpuSession(TpuConf(dict(cell.config["session_conf"])))
+    for name, table in tables.items():
+        session.create_or_replace_temp_view(
+            name, session.create_dataframe(
+                table, num_partitions=int(cell.config["partitions"])))
+    runs = []
+    for q, draws in PARAMS.items():
+        for nth, params in enumerate(draws):
+            traces = SC.stats()["traces"]
+            rows = session.sql(Query(q).fill(params)).collect()
+            runs.append({"q": q, "nth": nth, "params": params, "rows": rows,
+                         "summary": tracing.last_query_summary(),
+                         "traces": SC.stats()["traces"] - traces})
+    yield cell, gen, runs
+    session.stop()
+
+
+@pytest.mark.parametrize("q,nth", [("q7", 0), ("q7", 1), ("q27", 0),
+                                   ("q27", 1)])
+def test_answers_match_the_plain_reference(star, q, nth):
+    from benchmark import run as bench
+    from benchmark.compare import compare
+    cell, gen, runs = star
+    run = next(r for r in runs if r["q"] == q and r["nth"] == nth)
+    answer = bench.load_by_name("reference", q).run(gen, run["params"])
+    got = compare(run["rows"], answer)
+    assert got["groups"] > 0, "the draw keeps no row: nothing was compared"
+    assert got["rows_wrong"] == 0, got
+    assert got["max_rel_err"] <= 1e-9, got
+    if q == "q27":
+        # the roll-up's three levels are all among the first hundred rows:
+        # the grand total (nulls first), an item's total, an item by state
+        levels = {(r["i_item_id"] is None, r["s_state"] is None,
+                   r["g_state"]) for r in run["rows"]}
+        assert levels == {(True, True, 1), (False, True, 1),
+                          (False, False, 0)}
+
+
+def test_the_fan_out_runs_at_the_live_rows_size(star):
+    """q27's fan-out hands the aggregation three buckets of the join
+    chain's live rows: together no more than the last join's own output
+    bucket, where three padded copies of it were handed before."""
+    _, _, runs = star
+    for run in (r for r in runs if r["q"] == "q27"):
+        s = run["summary"]
+        last_join = next(n for n in s["nodes"] if "HashJoin" in n["node"])
+        join_bucket = sum(p["padded_rows"] for p in last_join["partitions"])
+        assert join_bucket > EXPAND_MIN_BUCKET
+        assert s["expand_rows_padded"] == 3 * EXPAND_MIN_BUCKET
+        assert 0 < s["expand_rows_padded"] <= join_bucket
+        expand = next(n for n in s["nodes"] if "Expand" in n["node"])
+        assert sum(p["padded_rows"] for p in expand["partitions"]) \
+            == s["expand_rows_padded"]
+        # the count the fan-out forces, and the collect's
+        assert s["transitions"]["sync_count"] == 2
+
+
+def test_a_query_without_grouping_sets_counts_no_fan_out(star):
+    _, _, runs = star
+    for run in (r for r in runs if r["q"] == "q7"):
+        assert run["summary"]["expand_rows_padded"] == 0
+        assert run["summary"]["transitions"]["sync_count"] == 1
+
+
+def test_new_string_parameters_trace_nothing(star):
+    """The second draw of each text differs in all three string
+    parameters and in the year, and builds no program."""
+    _, _, runs = star
+    assert [r["traces"] for r in runs if r["nth"] == 1] == [0, 0]
+    assert all(r["traces"] > 0 for r in runs if r["nth"] == 0)
+
+
+def test_the_fan_out_programs_have_a_kind_of_their_own(star):
+    assert SC.stats()["traces_by_kind"].get("expand.project", 0) >= 3
