@@ -169,7 +169,8 @@ class QueryExecution:
         #: counts noted where the work happens (:func:`add_count`)
         self.counters: Dict[str, int] = {"speculation_replays": 0,
                                          "pair_rows_padded": 0,
-                                         "expand_rows_padded": 0}
+                                         "expand_rows_padded": 0,
+                                         "probe_gather_rounds": 0}
         #: seconds of the planning spans adopted from ``TpuSession.sql``,
         #: which ran before this query began: part of what the client
         #: waited, so part of ``duration_s``
@@ -834,7 +835,8 @@ class QueryExecution:
             lines.append(" ".join(
                 f"{k}={summary[k]}" for k in
                 ("dispatches", "dispatch_s", "speculation_replays",
-                 "pair_rows_padded", "expand_rows_padded")
+                 "pair_rows_padded", "expand_rows_padded",
+                 "probe_gather_rounds")
                 if k in summary))
         lines.append("== Query Summary ==")
         lines.append(" ".join(
@@ -930,7 +932,8 @@ def run_span(plan):
 def add_count(name: str, n: int = 1) -> None:
     """Adds to a per-query counter of the active query's summary
     (``speculation_replays``, ``pair_rows_padded``,
-    ``expand_rows_padded``), where the work happens."""
+    ``expand_rows_padded``, ``probe_gather_rounds``), where the work
+    happens."""
     q = EV.active_query()
     if q is not None:
         q.add_count(name, n)

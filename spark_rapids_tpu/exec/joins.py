@@ -464,17 +464,24 @@ class _TpuJoinCore(_JoinBase):
                 if built is None:
                     built = J.build_side(build_aug, build_ords, pk)
                     built_by_widths[wkey] = built
-                lo, counts, offsets, total = J._probe_ranges(
-                    [probe_aug.columns[i] for i in probe_ords], built)
+                lo, counts, offsets, total = J._probe_ranges(pk, built)
+                # the gathers a probe row made to find its range
+                add_count("probe_gather_rounds", J.PROBE_GATHER_ROUNDS)
                 spec = speculation.active()
                 if spec is not None:
                     # optimistic OUTPUT table = probe bucket (exact for
                     # the FK->PK joins that dominate star schemas: <=1
                     # build match per probe row), but candidates are
                     # expanded + verified over a HEADROOM window first:
-                    # hash-collision / null-key candidates that
-                    # verification rejects must not flag overflow (they
-                    # used to trigger a silent full-query exact replay).
+                    # the candidates verification rejects must not flag
+                    # overflow (they used to trigger a silent full-query
+                    # exact replay).  Those are null keys and the other
+                    # live build rows of a probe row's table slot: the
+                    # latter raise ``total`` by at most probe rows /
+                    # J._TABLE_LOAD on average (additive: a slot holds
+                    # live build rows / slots <= 1 / _TABLE_LOAD rows
+                    # whatever the join selects), an eighth of the probe
+                    # bucket where the window leaves a whole one.
                     # Overflow is decided on the POST-VERIFY pair count
                     # against the probe bucket (below, after compact);
                     # only a candidate total beyond even the headroom

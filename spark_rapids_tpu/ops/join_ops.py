@@ -2,14 +2,19 @@
 
 Reference: GpuHashJoin (execution/GpuHashJoin.scala) lowers joins to cuDF
 hash-table gather maps; JoinGatherer.scala applies them.  TPU-first
-redesign — XLA has no device hash tables, but is excellent at sort +
-binary search, so an equi-join becomes:
+redesign — XLA has no device hash tables, but sorts and prefix-sums well,
+so an equi-join becomes:
 
 1. hash every row's key columns into one uint64 word (padding/invalid rows
    get a sentinel hash);
-2. sort the BUILD side by hash (``sort_ops.lex_sort_perm``);
-3. ``searchsorted`` each PROBE hash into the sorted build hashes -> a
-   candidate range [lo, hi) per probe row (static shapes throughout);
+2. sort the BUILD side by hash (``sort_ops.lex_sort_perm``) and, in the
+   same program, count its live rows by the hash's top ``k`` bits and
+   prefix-sum the counts into a bucket-start table (``_bucket_starts``);
+3. look each PROBE hash's top ``k`` bits up in that table -> a candidate
+   range [lo, lo + count) of sorted build positions per probe row, two
+   32-bit gathers and no search (static shapes throughout).  The range
+   holds every build row of equal hash and, on average, under
+   ``1 / _TABLE_LOAD`` rows of another hash, which step 5 drops;
 4. expand candidate pairs into a padded pair table: output position ``r``
    belongs to the last probe row whose offset is ``<= r``, found for all
    positions at once by a histogram of the offsets and a prefix sum
@@ -115,31 +120,81 @@ def _col_sig(c: DeviceColumn) -> Tuple:
             c.elem_valid is not None)
 
 
+#: Slots of the bucket-start table for each row of the build bucket (a
+#: power of two).  A probe row's candidates are its equal-hash build rows
+#: plus the other live rows of its slot, ``live build rows / slots <= 1 /
+#: _TABLE_LOAD`` on average: an additive ``probe rows / _TABLE_LOAD`` on
+#: the candidate total at most, whatever the join selects.  8 by a chip
+#: reading (v5e, 4.2M-row probe bucket; PERF.md section 6, PR 32): the
+#: probe program takes 69.7 ms against any table of 2^17 to 2^23 slots,
+#: 76.5 ms against 2^24 (64 MiB: the 1.9M-row build side at 8) and 145.6
+#: ms against 2^25 (the same side at 16), and false candidates cost no
+#: time inside the fixed pair window (11% of the probe rows at 8, 23% at
+#: 4, 6% at 16).  So 8 is the most margin that is still free.
+_TABLE_LOAD = 8
+#: No table has more than ``2^_TABLE_MAX_BITS`` slots (512 MiB of int32),
+#: which is ``_TABLE_LOAD`` slots a row up to a build bucket of 2^24 rows.
+#: Above that a slot holds ``bucket / 2^27`` rows of another hash on
+#: average (1/4 at 2^25 rows, 8 at the 2^30 rows ``join.pair`` admits):
+#: more pairs for ``verify`` to drop, and the same rows kept.
+_TABLE_MAX_BITS = 27
+#: Gathers a probe row makes to find its candidate range (the counter
+#: ``probe_gather_rounds``): ``starts[b]`` and ``starts[b + 1]``.
+PROBE_GATHER_ROUNDS = 2
+
+
+def _table_bits(bucket: int) -> int:
+    """``k``: the table over a ``bucket``-row build side has ``2^k`` slots.
+    A function of the build bucket alone, so of the program's shapes."""
+    return min((_TABLE_LOAD * bucket).bit_length() - 1, _TABLE_MAX_BITS)
+
+
+def _slot_of(h, k: int):
+    """The hash's top ``k`` bits as int32 (0 for ``k`` = 0: the shift is
+    split because a shift by the whole width is undefined)."""
+    return ((h >> np.uint64(1)) >> np.uint64(63 - k)).astype(np.int32)
+
+
+def _bucket_starts(slot_sorted, live_sorted, k: int, jnp):
+    """int32[2^k + 1]: ``starts[b]`` is the first sorted build position
+    whose slot is ``>= b``, counting live rows only, so ``starts[b + 1] -
+    starts[b]`` rows of slot ``b`` begin at ``starts[b]`` and no padding
+    row is in any range.  A histogram of the slots (ascending: the rows
+    are sorted by hash, padding last) and its prefix sum, as
+    ``batch_ops.expand_positions`` counts offsets."""
+    slots = 1 << k
+    hist = jnp.zeros(slots, dtype=np.int32).at[
+        jnp.where(live_sorted, slot_sorted, slots)].add(
+            1, mode="drop", indices_are_sorted=True)
+    return jnp.concatenate([jnp.zeros(1, dtype=np.int32),
+                            prefix_sum(hist, jnp)])
+
+
 @dataclasses.dataclass
 class BuiltSide:
     """The build (hash) side, sorted by key hash — reusable across many
     probe batches (reference: the build-side hash table in GpuHashJoin)."""
     batch: ColumnarBatch          # original build batch
     key_ordinals: Tuple[int, ...]
-    hashes_sorted: object         # uint64[bucket] ascending
+    starts: object                # int32[2^k + 1]: _bucket_starts
     perm: object                  # int32[bucket]: sorted pos -> original row
     widths: List[int]             # string word widths agreed with probe side
 
 
-
-
 def build_side(batch: ColumnarBatch, key_ordinals: Sequence[int],
                probe_key_cols: Sequence[DeviceColumn]) -> BuiltSide:
-    """Sorts the build side by key hash (one jitted program)."""
+    """Sorts the build side by key hash and tabulates where each slot of
+    the hash's top bits starts (one jitted program)."""
     from spark_rapids_tpu.ops.sort_ops import lex_sort_perm
     jnp = _jx()
     key_ordinals = tuple(key_ordinals)
     kcols = [batch.columns[i] for i in key_ordinals]
     widths = [max(_n_value_words(b), _n_value_words(p))
               for b, p in zip(kcols, probe_key_cols)]
-    key = ("build", tuple(_col_sig(c) for c in kcols), tuple(widths))
+    bucket = kcols[0].bucket if kcols else batch.bucket
+    k = _table_bits(bucket)
+    key = ("build", tuple(_col_sig(c) for c in kcols), tuple(widths), k)
     def build():
-        bucket = kcols[0].bucket if kcols else batch.bucket
         dtypes = [c.data_type for c in kcols]
 
         def run(arrs, row_count):
@@ -148,31 +203,38 @@ def build_side(batch: ColumnarBatch, key_ordinals: Sequence[int],
             rowpos = jnp.arange(bucket, dtype=np.int32)
             inrow = rowpos < row_count
             h = _hash_rows(cols, widths, inrow, jnp)
+            # stable: a live row whose hash is the sentinel's sorts before
+            # every padding row
             perm = lex_sort_perm([h], bucket, jnp)
-            return jnp.take(h, perm, axis=0), perm
+            starts = _bucket_starts(jnp.take(_slot_of(h, k), perm),
+                                    perm < row_count, k, jnp)
+            return starts, perm
 
         return run
     from spark_rapids_tpu.exec.stage_compiler import get_or_build
     fn = get_or_build("join.build", key, build)
     from spark_rapids_tpu.columnar.column import rc_traceable
     arrs = [(c.data, c.validity, c.lengths) for c in kcols]
-    hs, perm = fn(arrs, rc_traceable(batch.row_count))
-    return BuiltSide(batch, key_ordinals, hs, perm, widths)
+    starts, perm = fn(arrs, rc_traceable(batch.row_count))
+    return BuiltSide(batch, key_ordinals, starts, perm, widths)
 
 
 def _probe_ranges(probe_keys: List[DeviceColumn], built: BuiltSide):
-    """Per-probe-row candidate range in the sorted build hashes.
+    """Per-probe-row candidate range in the sorted build side: the rows of
+    the probe hash's slot, read from ``built.starts`` with
+    ``PROBE_GATHER_ROUNDS`` 32-bit gathers and no search.
     Returns (lo, counts, offsets, total) — total is the one host sync."""
     jnp = _jx()
     key = ("probe", tuple(_col_sig(c) for c in probe_keys),
-           built.hashes_sorted.shape, tuple(built.widths))
+           built.starts.shape, tuple(built.widths))
     def build():
         import jax
         bucket = probe_keys[0].bucket
         dtypes = [c.data_type for c in probe_keys]
         widths = built.widths
+        k = (int(built.starts.shape[0]) - 1).bit_length() - 1
 
-        def run(arrs, row_count, hs):
+        def run(arrs, row_count, starts):
             # the named scopes are the engine's names for the program's
             # phases in every XLA op's op_name (metadata only)
             with jax.named_scope("hash"):
@@ -181,13 +243,14 @@ def _probe_ranges(probe_keys: List[DeviceColumn], built: BuiltSide):
                 rowpos = jnp.arange(bucket, dtype=np.int32)
                 inrow = rowpos < row_count
                 h = _hash_rows(cols, widths, inrow, jnp)
-            with jax.named_scope("search"):
-                lo = jnp.searchsorted(hs, h, side="left").astype(np.int64)
-                hi = jnp.searchsorted(hs, h, side="right").astype(np.int64)
+            with jax.named_scope("lookup"):
+                b = _slot_of(h, k)
+                lo = jnp.take(starts, b)
+                hi = jnp.take(starts, b + 1)
             with jax.named_scope("offsets"):
-                # sentinel probe rows (padding) must not match sentinel
-                # build pad
-                counts = jnp.where(inrow & (h != _SENTINEL), hi - lo, 0)
+                # the table counts live build rows only, so a range never
+                # holds a padding row; a candidate total may pass 2^31
+                counts = jnp.where(inrow, hi - lo, 0).astype(np.int64)
                 offsets = prefix_sum(counts, jnp) - counts
                 return lo, counts, offsets, jnp.sum(counts)
 
@@ -197,7 +260,7 @@ def _probe_ranges(probe_keys: List[DeviceColumn], built: BuiltSide):
     arrs = [(c.data, c.validity, c.lengths) for c in probe_keys]
     from spark_rapids_tpu.columnar.column import rc_traceable
     lo, counts, offsets, total = fn(arrs, rc_traceable(probe_keys[0].row_count),
-                                    built.hashes_sorted)
+                                    built.starts)
     return lo, counts, offsets, total   # total: 0-d device (caller decides)
 
 

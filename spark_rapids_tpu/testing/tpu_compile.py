@@ -152,10 +152,17 @@ def sort_operand_counts(prog, specs) -> list:
             if eqn.primitive.name == "sort"]
 
 
-def primitives_under_scope(fn, specs, scope: str) -> set:
-    """Names of the primitives that the jitted ``fn`` traces inside the
-    ``jax.named_scope`` called ``scope``, nested jaxprs included."""
+def primitive_counts_under_scope(fn, specs, scope: str):
+    """``Counter`` of the primitives that the jitted ``fn`` traces inside
+    the ``jax.named_scope`` called ``scope``, nested jaxprs included."""
+    import collections
     traced = fn.trace(*specs)  # lint: ok=aot-site (jaxpr only)
-    return {eqn.primitive.name
-            for eqn, names in _equations(traced.jaxpr.jaxpr)
-            if scope in names}
+    return collections.Counter(
+        eqn.primitive.name
+        for eqn, names in _equations(traced.jaxpr.jaxpr) if scope in names)
+
+
+def primitives_under_scope(fn, specs, scope: str) -> set:
+    """Names of the primitives under ``scope``
+    (:func:`primitive_counts_under_scope`)."""
+    return set(primitive_counts_under_scope(fn, specs, scope))

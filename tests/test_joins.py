@@ -224,6 +224,10 @@ def test_expand_positions_matches_the_search(case):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
+#: a jaxpr's loops; both lower to an HLO ``while``
+LOOPS = {"while", "scan"}
+
+
 def _pair_program_args(n):
     """``_expand_verify``'s arguments for one LONG key over ``n``-row
     buckets on both sides, no candidates."""
@@ -237,8 +241,10 @@ def _pair_program_args(n):
             jnp.zeros(n, dtype=np.int64), jnp.ones(n, dtype=bool), n,
             T.LONG)], n, ["k"])
 
-    built = J.BuiltSide(batch(), (0,), jnp.zeros(n, dtype=np.uint64),
-                        jnp.zeros(n, dtype=np.int32), [1])
+    built = J.BuiltSide(
+        batch(), (0,),
+        jnp.zeros((1 << J._table_bits(n)) + 1, dtype=np.int32),
+        jnp.zeros(n, dtype=np.int32), [1])
     zeros = jnp.zeros(n, dtype=np.int64)
     return batch(), (0,), built, (False,), zeros, zeros, jnp.int64(0)
 
@@ -252,8 +258,6 @@ def test_join_pair_expands_without_a_search_loop():
     import jax.numpy as jnp
     from spark_rapids_tpu.ops import join_ops as J
     from spark_rapids_tpu.testing import tpu_compile as TC
-    #: a jaxpr's loops; both lower to an HLO ``while``
-    LOOPS = {"while", "scan"}
     n = 1024
     prog, specs = TC.ProgramRecorder().capture(
         J._expand_verify, *_pair_program_args(n), 2 * n)
@@ -276,3 +280,145 @@ def test_join_pair_refuses_positions_past_32_bits():
     from spark_rapids_tpu.ops import join_ops as J
     with pytest.raises(ValueError, match="32-bit positions"):
         J._expand_verify(*_pair_program_args(1024), 1 << 31)
+
+
+def test_join_probe_looks_up_without_a_search_loop():
+    """The probe reads its candidate range from the build side's
+    bucket-start table: ``PROBE_GATHER_ROUNDS`` gathers a probe row under
+    the ``join.probe`` program's ``lookup`` scope and no loop, where two
+    ``jnp.searchsorted`` made 32 to 44 rounds of a 64-bit gather (6.70 s
+    of a TPC-DS q3's 17.4 s; PERF.md section 6, PR 32)."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import join_ops as J
+    from spark_rapids_tpu.testing import tpu_compile as TC
+    n = 1024
+    probe, _ords, built = _pair_program_args(n)[:3]
+    prog, specs = TC.ProgramRecorder().capture(
+        J._probe_ranges, [probe.columns[0]], built)
+    assert prog.kind == "join.probe"
+    lookup = TC.primitive_counts_under_scope(prog._fn, specs, "lookup")
+    assert lookup["gather"] == J.PROBE_GATHER_ROUNDS, lookup
+    assert not LOOPS & set(lookup), lookup
+    # no search anywhere else in the program either
+    assert "while" not in str(prog._fn.trace(*specs).jaxpr)
+
+    @jax.jit
+    def searched(hs, h):
+        with jax.named_scope("lookup"):
+            return jnp.searchsorted(hs, h, side="left")
+
+    # the guard sees the loop it guards against
+    u64 = jax.ShapeDtypeStruct((n,), np.uint64)
+    assert LOOPS & TC.primitives_under_scope(searched, (u64, u64), "lookup")
+
+
+def _long_column(values, bucket):
+    """A LONG key column of ``bucket`` rows; ``None`` is a null key."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    data = np.zeros(bucket, dtype=np.int64)
+    valid = np.zeros(bucket, dtype=bool)
+    for i, v in enumerate(values):
+        if v is not None:
+            data[i], valid[i] = v, True
+    return DeviceColumn(jnp.asarray(data), jnp.asarray(valid), len(values),
+                        T.LONG)
+
+
+_RNG = np.random.default_rng(11)
+#: (build keys, build bucket, probe keys, probe bucket)
+PROBE_CASES = {
+    "empty-build": ([], 1024, list(range(300)), 1024),
+    "one-row-build": ([7], 1024, [7, 8, 7, None, 9], 1024),
+    "all-null-keys": ([None] * 40, 1024, [None] * 25 + [1, 2, 3], 1024),
+    "heavy-duplicates": ([5] * 600 + [6] * 300 + list(range(100, 200)),
+                         1024,
+                         list(_RNG.integers(0, 210, 900)), 1024),
+    "probe-padding": (list(range(1000)), 1024,
+                      list(_RNG.integers(0, 2000, 37)), 2048),
+    "build-padding": (list(_RNG.integers(0, 5000, 1025)), 2048,
+                      list(_RNG.integers(0, 5000, 2000)), 2048),
+    "build-fills-its-bucket": (list(range(1024)), 1024,
+                               list(_RNG.integers(0, 1024, 1024)), 1024),
+}
+
+
+@pytest.mark.parametrize("case", list(PROBE_CASES))
+def test_probe_ranges_contain_the_searched_ranges(case):
+    """Every probe row's ``[lo, lo + count)`` holds the exact range of
+    equal hashes that ``np.searchsorted`` finds among the live build
+    rows, no range reaches a padding row, and the candidate total passes
+    the exact one by what the table's load allows."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch
+    from spark_rapids_tpu.ops import join_ops as J
+    bkeys, b_bucket, pkeys, p_bucket = PROBE_CASES[case]
+    bcol, pcol = _long_column(bkeys, b_bucket), _long_column(pkeys, p_bucket)
+    built = J.build_side(ColumnarBatch([bcol], len(bkeys), ["k"]), (0,),
+                         [pcol])
+    lo, counts, offsets, total = (np.asarray(a) for a in
+                                  J._probe_ranges([pcol], built))
+    assert lo.dtype == np.int32 and counts.dtype == offsets.dtype == np.int64
+
+    def hashes(c, n):
+        return np.asarray(J._hash_rows(
+            [c], [1], jnp.arange(c.bucket) < n, jnp))
+    hb, hp = hashes(bcol, len(bkeys)), hashes(pcol, len(pkeys))
+    perm = np.asarray(built.perm)
+    hs = hb[perm][:len(bkeys)]              # live rows first, by hash
+    np.testing.assert_array_equal(hs, np.sort(hb[:len(bkeys)]))
+    assert sorted(perm[:len(bkeys)]) == list(range(len(bkeys)))
+    starts = np.asarray(built.starts)
+    assert starts[0] == 0 and starts[-1] == len(bkeys)
+    assert (np.diff(starts) >= 0).all()
+    live = slice(0, len(pkeys))
+    want_lo = np.searchsorted(hs, hp[live], "left")
+    want_hi = np.searchsorted(hs, hp[live], "right")
+    assert (lo[live] <= want_lo).all()
+    assert (lo[live] + counts[live] >= want_hi).all()
+    assert (lo + counts <= len(bkeys)).all()        # never a padding row
+    assert (counts[len(pkeys):] == 0).all()
+    np.testing.assert_array_equal(offsets, np.cumsum(counts) - counts)
+    exact = int((want_hi - want_lo).sum())
+    largest_run = max(np.unique(hs, return_counts=True)[1], default=0)
+    assert exact <= total == counts.sum()
+    # a probe row meets the other live rows of its slot: live build rows /
+    # slots of them on average, which the load holds under 1 / _TABLE_LOAD
+    mean_false = len(pkeys) * len(bkeys) / (len(starts) - 1)
+    assert mean_false <= len(pkeys) / J._TABLE_LOAD
+    assert total - exact <= \
+        mean_false + 4 * mean_false ** 0.5 + 2 * largest_run + 8
+
+
+@pytest.mark.parametrize("bits", [0, 1], ids=["one-slot", "two-slots"])
+@pytest.mark.parametrize("how", JOIN_TYPES)
+def test_join_types_when_nearly_every_candidate_is_false(how, bits,
+                                                         monkeypatch):
+    """With a table of one or two slots a probe row's candidates are all,
+    or half, of the live build rows: ``verify`` alone decides what joins,
+    for every join type, the non-equi condition and null keys."""
+    from spark_rapids_tpu.ops import join_ops as J
+    monkeypatch.setattr(J, "_table_bits", lambda bucket: bits)
+    assert_tpu_and_cpu_are_equal_collect(
+        lambda s: s.create_dataframe(_left_data(), num_partitions=2)
+        .join(s.create_dataframe(_right_data(), num_partitions=2), on="k",
+              how=how),
+        ignore_order=True)
+    if how in ("inner", "left", "full"):
+        assert_tpu_and_cpu_are_equal_collect(
+            lambda s: s.create_dataframe(_left_data())
+            .join(s.create_dataframe(_right_data()), on="k", how=how,
+                  condition=F.col("lv") * 10 < F.col("rv")),
+            ignore_order=True)
+    assert tracing.last_query_summary()["speculation_replays"] == 0
+
+
+def test_table_bits_follow_the_build_bucket_alone():
+    from spark_rapids_tpu.ops import join_ops as J
+    assert J._TABLE_LOAD in (4, 8, 16)
+    for bucket in (1024, 1 << 15, 1 << 21, 1 << 24):
+        assert 1 << J._table_bits(bucket) == J._TABLE_LOAD * bucket
+    # capped: a table is 512 MiB at most, whatever the build side
+    assert J._table_bits(1 << 25) == J._table_bits(1 << 30) \
+        == J._TABLE_MAX_BITS == 24 + J._TABLE_LOAD.bit_length() - 1

@@ -218,6 +218,7 @@ def test_dispatches_and_padded_rows_of_a_two_batch_plan():
     assert sum(p["rows"] for p in scan["partitions"]) == 200
     assert sum(p["padded_rows"] for p in scan["partitions"]) == 2 * bucket
     assert summary["pair_rows_padded"] == 0     # no join
+    assert summary["probe_gather_rounds"] == 0
 
 
 def test_the_hash_join_notes_its_padded_pair_table():
@@ -229,6 +230,20 @@ def test_the_hash_join_notes_its_padded_pair_table():
     assert summary["pair_rows_padded"] > 0
     assert summary["pair_rows_padded"] % SPECULATIVE_PAIR_HEADROOM == 0
     assert summary["pair_rows_padded"] >= 3000
+
+
+def test_the_hash_join_notes_the_gathers_of_its_probes():
+    """``probe_gather_rounds``: the per-probe-row gathers the query's
+    ``join.probe`` programs were built with, two a probed batch (the
+    bucket-start table's ``starts[b]`` and ``starts[b + 1]``) where a
+    binary search made two a round."""
+    from spark_rapids_tpu.ops.join_ops import PROBE_GATHER_ROUNDS
+    s = _star_session()
+    s.sql(JOIN_AGG).collect()
+    summary = TR.last_query_summary()
+    probes = summary["dispatches_by_kind"]["join.probe"]
+    assert probes >= 1 and PROBE_GATHER_ROUNDS == 2
+    assert summary["probe_gather_rounds"] == PROBE_GATHER_ROUNDS * probes
 
 
 def test_spans_outside_a_query_only_annotate():
@@ -274,6 +289,7 @@ def test_explain_analyze_renders_the_plan_and_the_phases():
     plan_part = text.split("== Phases (self time) ==")[0]
     assert "Join" in plan_part and "exec.run" not in plan_part
     assert "exec.run=" in text and "dispatches=" in text
+    assert "pair_rows_padded=" in text and "probe_gather_rounds=" in text
 
 
 @pytest.fixture(scope="module")
@@ -348,7 +364,7 @@ def test_the_xplane_names_programs_by_kind(xplane):
     assert kinds <= set(summary["dispatches_by_kind"])
 
 
-SCOPES = {"join.probe": ("hash", "search", "offsets"),
+SCOPES = {"join.probe": ("hash", "lookup", "offsets"),
           "join.pair": ("expand", "verify"),
           "fused.agg_update": ("keys", "update")}
 

@@ -124,8 +124,10 @@ def test_join_build_probe_and_pairs(tpu_branch):
     prog, specs = _compiles(tpu_branch, J.build_side, build, (0,),
                             [probe.columns[0]])
     assert max(TC.sort_operand_counts(prog, specs)) <= 2
-    built = J.BuiltSide(build, (0,), jnp.zeros(LARGE, dtype=np.uint64),
-                        jnp.zeros(LARGE, dtype=np.int32), [1])
+    built = J.BuiltSide(
+        build, (0,),
+        jnp.zeros((1 << J._table_bits(LARGE)) + 1, dtype=np.int32),
+        jnp.zeros(LARGE, dtype=np.int32), [1])
     _compiles(tpu_branch, J._probe_ranges, [probe.columns[0]], built)
     # the pair table under speculative sizing: twice the probe bucket
     ranges = jnp.zeros(1 << 19, dtype=np.int64)
