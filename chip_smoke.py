@@ -151,7 +151,7 @@ def _timed_collect(df):
 
 
 # ---------------------------------------------------------------------------
-# phase: resident table (bench.py's pipeline)
+# phase: resident table
 # ---------------------------------------------------------------------------
 
 def build_resident_data(n_rows: int, seed: int = 7) -> dict:

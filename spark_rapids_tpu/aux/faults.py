@@ -33,8 +33,8 @@ every query so each action sees a fresh fault budget.
 
 The module also keeps process-wide *recovery counters* (fetch retries,
 failovers, task retries, breaker trips, map re-runs, worker expiries):
-every recovery emit site notes its transition here so ``bench.py`` can
-report what recovery cost across a run without scraping event logs.
+every recovery emit site notes its transition here so a run can report
+what recovery cost without scraping event logs.
 """
 
 from __future__ import annotations
@@ -140,8 +140,8 @@ def reset_fault_stats() -> None:
 
 #: THE recovery vocabulary: event kind -> ledger/summary key.  Emit sites
 #: pair each event with note_recovery(key); tracing's per-query summary
-#: and bench.py's chaos payload both derive from this map, so adding a
-#: recovery kind here propagates to every surface.
+#: and tools/profile's recovery bucket both derive from this map, so
+#: adding a recovery kind here propagates to every surface.
 RECOVERY_KINDS: Dict[str, str] = {
     "fetchRetry": "fetch_retries",
     "fetchFailover": "fetch_failovers",

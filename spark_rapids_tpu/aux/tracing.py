@@ -71,8 +71,8 @@ def recent_summaries() -> List[dict]:
 
 
 def last_query_summary() -> Optional[dict]:
-    """Summary dict of the most recently finished query in this process
-    (bench.py embeds this so BENCH_*.json is attributable)."""
+    """Summary dict of the most recently finished query in this
+    process."""
     with _LAST_LOCK:
         return _LAST_SUMMARY
 

@@ -1073,10 +1073,9 @@ SERVING_AUTOTUNE_ENABLED = conf_bool(
 
 HISTORY_PATH = conf_str(
     "spark.rapids.history.path",
-    "Path of the persistent cross-run history warehouse (SQLite). When "
-    "set, bench.py auto-ingests each run's payload and event log after "
-    "the benchmark completes, so `tools history regress|calibrate` "
-    "accumulate a baseline without manual ingestion. Empty disables. "
+    "Path of the persistent cross-run history warehouse (SQLite): the "
+    "database the `tools history` sub-commands open when no --db is "
+    "given. Empty: the CLI requires --db. "
     "Reference: the spark-rapids-tools Qualification/Profiling store "
     "over Spark event logs.",
     "")

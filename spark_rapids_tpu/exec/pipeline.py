@@ -43,8 +43,8 @@ the queue.  The spool is memory-safe and failure-safe, not just fast:
 Stall-time and queue-depth metrics flow to the event bus
 (``pipelineSpool`` events) and into the node's OpMetrics so
 ``explain(analyze=True)`` shows measured overlap per boundary; a
-process-wide ledger (``pipeline_stats``) feeds bench.py's ``pipeline``
-payload.
+process-wide ledger (``pipeline_stats``) sums them over every spool the
+process closed.
 """
 
 from __future__ import annotations
@@ -78,7 +78,7 @@ class _SpoolError:
 
 
 # ---------------------------------------------------------------------------
-# process-wide ledger (bench.py's `pipeline` payload)
+# process-wide ledger
 # ---------------------------------------------------------------------------
 
 _STATS_LOCK = threading.Lock()

@@ -1,5 +1,5 @@
 """Row-level result comparison shared by the pytest differential asserts
-and bench.py's TPC-DS oracle (reference:
+and chip_smoke.py's checks (reference:
 integration_tests/src/main/python/asserts.py:579 — the oracle deep-
 compares collected rows, never just row counts)."""
 

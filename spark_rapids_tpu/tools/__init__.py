@@ -12,8 +12,6 @@ logs PR 1's sink writes:
                  stalls / spill / recovery) plus operator ranking;
 - ``autotune`` — rule-based conf recommendations, each citing the
                  evidence events that triggered it;
-- ``compare``  — BENCH_r*.json diffing across PRs (shared regression
-                 core with ``history regress`` in ``regression``);
 - ``lint``     — static AST analysis of the engine's own source against
                  its declared invariants (docs/lint.md);
 - ``history``  — persistent SQLite warehouse across runs: ingest event
@@ -22,14 +20,13 @@ logs PR 1's sink writes:
                  profile ``plan/cost.py`` predicts from (docs/history.md).
 
 CLI: ``python -m spark_rapids_tpu.tools
-<profile|autotune|compare|trace|audit|lint|history>``
+<profile|autotune|trace|audit|lint|history>``
 (stdlib-only; runs without jax or a device).
 """
 
 from spark_rapids_tpu.tools.autotune import (Recommendation, autotune,
                                              render_recommendations,
                                              to_conf_dict)
-from spark_rapids_tpu.tools.compare import compare, render_compare
 from spark_rapids_tpu.tools.history import (HistoryWarehouse, calibrate,
                                             regress)
 from spark_rapids_tpu.tools.profile import (Attribution, attribute,
@@ -40,8 +37,8 @@ from spark_rapids_tpu.tools.reader import (QueryProfile, ReadDiagnostics,
 
 __all__ = [
     "Attribution", "HistoryWarehouse", "QueryProfile", "ReadDiagnostics",
-    "Recommendation", "attribute", "autotune", "calibrate", "compare",
+    "Recommendation", "attribute", "autotune", "calibrate",
     "load_profiles", "profiles_to_json", "read_events", "regress",
-    "render_compare", "render_recommendations", "render_report",
+    "render_recommendations", "render_report",
     "to_conf_dict",
 ]

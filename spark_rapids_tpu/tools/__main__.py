@@ -6,7 +6,6 @@ Commands:
   + operator ranking from a JSONL event log (rotated/.gz sets handled).
 - ``autotune <event-log>``: rule-based conf recommendations with cited
   evidence; ``--json`` prints the ready-to-apply conf dict.
-- ``compare <bench.json ...>``: diff BENCH payloads across runs/PRs.
 - ``trace <event-log>``: render the log as Chrome-trace/Perfetto JSON
   (load in chrome://tracing or ui.perfetto.dev); ``--check`` fails on
   transitions unattributed to any query.
@@ -17,7 +16,7 @@ Commands:
   recompile storms, dtype widening, roofline cross-check; exits
   non-zero on any unsuppressed error finding.
 - ``history ingest|report|regress|calibrate``: the persistent SQLite
-  warehouse (docs/history.md) — ingest event logs and BENCH payloads,
+  warehouse (docs/history.md) — ingest event logs and benchmark payloads,
   judge the latest run against the accumulated baseline (nonzero exit
   on regression), and fit the machine profile ``plan/cost.py`` uses to
   annotate plans with predicted cost.
@@ -67,10 +66,6 @@ def _build_parser() -> argparse.ArgumentParser:
                     help="exit non-zero if any hostTransition/deviceSync "
                          "event is unattributed to a query")
 
-    cmp_p = sub.add_parser("compare", help="diff BENCH_r*.json payloads")
-    cmp_p.add_argument("files", nargs="+")
-    cmp_p.add_argument("--json", action="store_true")
-
     aud = sub.add_parser("audit",
                          help="compiled-program audit over the "
                               "stageProgram ledger")
@@ -101,7 +96,7 @@ def _build_parser() -> argparse.ArgumentParser:
                           help="persistent cross-run metrics warehouse")
     hsub = hist.add_subparsers(dest="action", required=True)
     h_ing = hsub.add_parser("ingest",
-                            help="ingest event logs / BENCH payloads "
+                            help="ingest event logs / benchmark payloads "
                                  "(files or directories, sniffed)")
     h_ing.add_argument("paths", nargs="+")
     h_ing.add_argument("--db", default=None,
@@ -206,13 +201,6 @@ def main(argv=None) -> int:
             if args.check:
                 return 1
         return 0
-    if args.cmd == "compare":
-        from spark_rapids_tpu.tools.compare import compare, render_compare
-        if args.json:
-            print(json.dumps(compare(args.files), indent=2))
-        else:
-            sys.stdout.write(render_compare(args.files))
-        return 0
     if args.cmd == "audit":
         from spark_rapids_tpu.tools.audit import (render_audit, run_audit,
                                                   write_audit_baseline)
@@ -280,8 +268,7 @@ def _run_history(args) -> int:
                                                 calibrate, regress,
                                                 render_profile,
                                                 render_regress)
-    # --db falls back to the registered warehouse conf: the same key a
-    # session/bench run sets to auto-ingest its own logs
+    # --db falls back to the registered warehouse conf
     if not args.db:
         args.db = C.default_conf().get(C.HISTORY_PATH.key)
     if not args.db:

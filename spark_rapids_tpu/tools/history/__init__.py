@@ -4,15 +4,15 @@ history ...``).
 Every run's telemetry used to die with its log file; this package is
 the durable substrate under the offline toolkit — a SQLite warehouse
 (``spark.rapids.history.path``) that ingests event logs (schemas v1–v4)
-and BENCH/MULTICHIP payloads into normalized tables, and three
+and benchmark payloads into normalized tables, and three
 consumers over the accumulated history:
 
 - ``report``: what the warehouse holds (runs, queries, spans, ledger
   rows) — the inventory view;
 - ``regress``: the trajectory sentinel — the latest run vs the history
   baseline per query/metric with noise-aware thresholds (min-runs,
-  median-absolute-deviation bands; shared core with ``tools compare``
-  in tools/regression.py), nonzero exit on regression;
+  median-absolute-deviation bands, from tools/regression.py), nonzero
+  exit on regression;
 - ``calibrate``: joins the audit ledger's flops/bytes to measured
   per-stage-kind exclusive time and fits a machine profile (achieved
   byte/s and FLOP/s per stage kind, per-dispatch fixed overhead,

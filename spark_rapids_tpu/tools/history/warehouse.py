@@ -4,9 +4,9 @@ One ``ingest`` call per source makes one ``runs`` row; everything else
 hangs off ``run_id``.  Sources are sniffed, not flagged: a JSONL event
 log (any supported schema version, rotated/gzip sets included) lands as
 queries/spans/programs/transitions/spills/ici/compiles/confs/serving
-rows; a BENCH/MULTICHIP payload (bench.py's one-line JSON, or the
-committed driver-wrapper docs) lands as metric rows keyed by the same
-dotted paths ``tools compare`` diffs.  Failed bench runs (placeholder
+rows; a benchmark payload (a run's one-line JSON, or a driver-wrapper
+doc) lands as metric rows keyed by the dotted paths of
+``PAYLOAD_METRICS``.  Failed bench runs (placeholder
 zeros, see tools/regression.run_failure) are recorded as runs with
 ``status='failed'`` and NO metric rows — their placeholders must never
 enter a baseline.
@@ -100,6 +100,78 @@ CREATE TABLE IF NOT EXISTS bench_metrics(
 """
 
 _ROTATED = re.compile(r"^(?P<base>.+)\.(\d+)$")
+
+#: (label, dotted path into the payload, higher-is-better or None)
+PAYLOAD_METRICS: List[Tuple[str, str, Optional[bool]]] = [
+    ("rows/s", "value", True),
+    ("vs CPU baseline", "vs_baseline", True),
+    ("TPU wall s", "tpu_s", False),
+    ("CPU wall s", "cpu_s", False),
+    ("HBM fraction", "hbm_frac", True),
+    ("bytes/s", "bytes_per_sec", True),
+    ("pipeline overlap", "pipeline.overlap_ratio", True),
+    ("producer stall s", "pipeline.producer_stall_s", False),
+    ("consumer stall s", "pipeline.consumer_stall_s", False),
+    ("peak spool depth", "pipeline.peak_depth", None),
+    ("TPC-DS geomean", "tpcds.geomean_speedup", True),
+    ("TPC-DS queries", "tpcds.queries_counted", True),
+    ("faults injected", "chaos.faults_injected", None),
+    ("task retries", "chaos.task_retries", False),
+    ("fetch retries", "chaos.fetch_retries", False),
+    ("query tasks", "query_metrics.tasks", None),
+    ("query spill bytes", "query_metrics.spill_bytes", False),
+    ("programs built", "event_log.audit.programs", None),
+    ("audit errors", "event_log.audit.errors", False),
+]
+
+
+def _dig(payload: Dict, dotted: str):
+    cur = payload
+    for part in dotted.split("."):
+        if not isinstance(cur, dict) or part not in cur:
+            return None
+        cur = cur[part]
+    return cur
+
+
+def _load_payload(path: str) -> Dict:
+    """One payload file, whichever capture shape it arrived in:
+
+    - a driver wrapper (a pretty-printed doc whose ``parsed`` field
+      holds the payload, with the raw stream tail under ``tail``),
+    - a run's own stdout (one JSON line, possibly preceded by stderr
+      snapshots in merged-stream captures — the LAST parseable line
+      wins, matching the 'final stdout line is the payload' contract).
+    """
+    with open(path) as f:
+        text = f.read()
+    try:
+        doc = json.loads(text)
+    except ValueError:
+        doc = None
+    if isinstance(doc, dict):
+        parsed = doc.get("parsed")
+        if isinstance(parsed, dict):
+            return parsed
+        if "tail" in doc and isinstance(doc["tail"], str):
+            # no parsed payload: fall through to line-scanning the tail
+            text = doc["tail"]
+        else:
+            return doc
+    last = None
+    for line in text.splitlines():
+        line = line.strip()
+        if not line:
+            continue
+        try:
+            d = json.loads(line)
+        except ValueError:
+            continue
+        if isinstance(d, dict):
+            last = d
+    if last is None:
+        raise ValueError(f"{path!r} contains no JSON payload line")
+    return last
 
 
 class HistoryWarehouse:
@@ -337,16 +409,15 @@ class HistoryWarehouse:
 
     def ingest_payload(self, source, label: str = "",
                        force: bool = False) -> Dict:
-        """One BENCH/MULTICHIP payload (path or already-loaded dict)
+        """One benchmark payload (path or already-loaded dict)
         -> one run of metric rows.  A failed run (placeholder zeros) is
         recorded with ``status='failed'`` and no metric rows.  Path
         sources dedupe by content digest like event logs; an
-        already-loaded dict (bench.py's in-process auto-ingest) always
-        inserts — there is no stable source identity to match."""
-        from spark_rapids_tpu.tools.compare import METRICS, _dig, load_bench
+        already-loaded dict always inserts — there is no stable source
+        identity to match."""
         from spark_rapids_tpu.tools.regression import run_failure
         if isinstance(source, str):
-            payload = load_bench(source)
+            payload = _load_payload(source)
             src = os.path.abspath(source)
             digest = _content_digest(source)
         else:
@@ -373,7 +444,7 @@ class HistoryWarehouse:
             run_id = cur.lastrowid
         metrics = 0
         if why is None:
-            for mlabel, dotted, higher in METRICS:
+            for mlabel, dotted, higher in PAYLOAD_METRICS:
                 v = _dig(payload, dotted)
                 if not isinstance(v, (int, float)) \
                         or isinstance(v, bool):

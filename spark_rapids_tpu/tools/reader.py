@@ -167,8 +167,8 @@ _GZIP_MAGIC = b"\x1f\x8b"
 
 def log_file_set(path: str) -> List[str]:
     """``path``'s rotated siblings (oldest first) then ``path`` itself.
-    Public: bench.py clears exactly this set before a run so stale
-    rotations never leak into a fresh log's profile."""
+    Public: exactly the set to clear before a log path is reused, so
+    stale rotations never leak into a fresh log's profile."""
     base = os.path.basename(path)
     d = os.path.dirname(os.path.abspath(path))
     rx = re.compile(re.escape(base) + r"\.(\d+)$")

@@ -1,12 +1,10 @@
 """The shared regression-detection core.
 
-Two consumers, one vocabulary: ``tools compare`` diffs a handful of
-BENCH payloads (first vs last), ``tools history regress`` judges the
-latest ingested run against the accumulated baseline.  Both must agree
-on what a failed run looks like (placeholder-zero payloads are skipped,
-never treated as a −100% regression) and on what counts as "the wrong
-way by enough" — so the thresholds and the failed-run detector live
-here, not in either caller.
+``tools history regress`` judges the latest ingested run against the
+accumulated baseline; the warehouse's ingest records a failed run
+without its numbers.  Both must agree on what a failed run looks like
+(placeholder-zero payloads are skipped, never treated as a −100%
+regression), so the thresholds and the failed-run detector live here.
 
 Noise model: with ≥ ``min_runs`` baseline samples the band around the
 baseline median is ``max(rel_threshold·|median|, band_k·1.4826·MAD)``
@@ -21,7 +19,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence
 
-#: the classic compare rule: >5% the wrong way is a regression
+#: the relative floor of the band: >5% the wrong way is a regression
 REL_THRESHOLD = 0.05
 
 #: baseline samples required before a verdict is trusted at all
@@ -33,16 +31,14 @@ DEFAULT_BAND_K = 3.0
 
 def run_failure(payload: Dict) -> Optional[str]:
     """A payload from a run that FAILED rather than measured: its
-    numbers are placeholders (value 0, vs_baseline 0.0 from the bench
+    numbers are placeholders (value 0, vs_baseline 0.0 from a run's
     failsafe), and comparing against them would report a −100%/÷0
-    'regression' where the honest verdict is 'run failed'
-    (BENCH_r05: ``budget_exceeded`` with value 0)."""
+    'regression' where the honest verdict is 'run failed'."""
     if not isinstance(payload, dict):
         return None
     # a run that produced a real primary value is a (possibly partial)
-    # measurement even if a later phase tripped the budget alarm
-    # (BENCH_r04 carries budget_exceeded WITH a real value); only a
-    # placeholder-zero payload is a failed run
+    # measurement even if a later phase tripped the budget alarm; only
+    # a placeholder-zero payload is a failed run
     if payload.get("value"):
         return None
     if payload.get("budget_exceeded"):
@@ -69,20 +65,6 @@ def mad(xs: Sequence[float]) -> float:
         return 0.0
     m = median(xs)
     return median([abs(x - m) for x in xs])
-
-
-def delta_regression(first: float, last: float,
-                     higher_better: Optional[bool],
-                     rel_threshold: float = REL_THRESHOLD
-                     ) -> Optional[bool]:
-    """The two-point rule ``tools compare`` applies: last vs first,
-    >``rel_threshold`` the wrong way.  None when no verdict applies
-    (zero baseline or direction-less metric)."""
-    if higher_better is None or not first:
-        return None
-    delta = (last - first) / abs(first)
-    return delta < -rel_threshold if higher_better \
-        else delta > rel_threshold
 
 
 def detect(history: Sequence[float], latest: float,

@@ -26,7 +26,8 @@ The module is stdlib-only (reader + json), like the rest of the tools
 package.  ``unattributed`` counts hostTransition/deviceSync events that
 fired OUTSIDE any traced query (query_id == -1): every transfer the
 gateway sees during a traced run should belong to a query, and
-``scripts/check.sh`` fails its round-trip step when one does not.
+``tests/test_transitions.py::test_trace_cli_roundtrip_and_check`` holds a
+traced query's round trip to zero of them.
 """
 
 from __future__ import annotations

@@ -27,7 +27,6 @@ import sys
 import weakref
 
 import numpy as np
-import pytest
 
 from spark_rapids_tpu import functions as F
 from spark_rapids_tpu.aux import events as EV
@@ -39,8 +38,6 @@ from spark_rapids_tpu.tools.audit import (LedgerRow, cluster_rows,
                                           run_audit, write_audit_baseline)
 
 from tests.asserts import tpu_session
-
-pytestmark = pytest.mark.smoke
 
 RNG = np.random.default_rng(12)
 # w is int32 so `col("w") > lit(threshold)` is a same-dtype comparison —

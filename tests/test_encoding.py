@@ -77,7 +77,6 @@ def _assert_trimodal(df_fn, extra=None, ignore_order=True):
 # operator matrix, bit-identical on/off/cpu
 # ---------------------------------------------------------------------------
 
-@pytest.mark.smoke
 def test_scan_filter_agg_sort_trimodal(enc_parquet):
     s0 = ENC.encoding_stats()
 
@@ -95,7 +94,6 @@ def test_scan_filter_agg_sort_trimodal(enc_parquet):
     assert s1["decode_avoided_bytes"] > s0["decode_avoided_bytes"]
 
 
-@pytest.mark.smoke
 def test_filter_shapes_trimodal(enc_parquet):
     from spark_rapids_tpu.expressions import predicates as P
 
@@ -115,7 +113,6 @@ def test_filter_shapes_trimodal(enc_parquet):
         _assert_trimodal(fn)
 
 
-@pytest.mark.smoke
 def test_null_accepting_predicates_keep_null_rows(enc_parquet):
     """Review regression (code-space translation dropped null rows): a
     conjunct that is TRUE on null input — IS NULL, coalesce-defaulted
@@ -244,7 +241,6 @@ def _encoded_device_batch(values, codes_with_nulls):
     return upload_host_batch(hb)
 
 
-@pytest.mark.smoke
 def test_upload_keeps_codes_and_download_ships_codes():
     dev = _encoded_device_batch(["x", "y", "z"], [0, 1, 2, 0, None, 1])
     c = dev.columns[0]
@@ -260,7 +256,6 @@ def test_upload_keeps_codes_and_download_ships_codes():
     assert s1["encoded_bytes_out"] > s0["encoded_bytes_out"]
 
 
-@pytest.mark.smoke
 def test_fused_filter_keeps_output_encoded_and_compiles_once():
     """THE late-materialization contract: a code-space filter's output
     still carries codes (only survivors could ever decode), and two
@@ -435,7 +430,6 @@ def _compressible_host_batch(rows=20_000):
     ], rows, ["a", "b"])
 
 
-@pytest.mark.smoke
 def test_compressed_spill_roundtrip_under_pressure(tmp_path):
     """Forced host-pool pressure pushes batches to disk through the
     spill codec: round trip is exact and at least 2x the logical bytes
@@ -666,7 +660,6 @@ def _tpcds_trimodal(qname):
     _assert_trimodal(fn, extra={"spark.rapids.sql.test.enabled": "false"})
 
 
-@pytest.mark.smoke
 def test_tpcds_q3_encoded_trimodal():
     _tpcds_trimodal("q3")
 

@@ -1,8 +1,8 @@
 """History warehouse tests: mixed-schema directory ingest with pinned
 per-version row counts, the trajectory sentinel (injected slowdown vs
 healthy repeat), bench-payload ingest, machine-profile calibration, the
-``== Cost ==`` explain section + queryEnd cross-check, and the shared
-regression core between ``tools compare`` and ``history regress``
+``== Cost ==`` explain section + queryEnd cross-check, and the
+noise-aware regression core behind ``history regress``
 (docs/history.md)."""
 
 import json
@@ -20,10 +20,9 @@ from spark_rapids_tpu.tools.history import (HistoryWarehouse, calibrate,
                                             regress)
 from spark_rapids_tpu.tools.history.calibrate import (
     MACHINE_PROFILE_SCHEMA, family_for_node)
+from spark_rapids_tpu.tools.regression import detect
 
 from tests.asserts import tpu_session
-
-pytestmark = pytest.mark.smoke
 
 _DATA = {"k": np.arange(4000, dtype=np.int64) % 7,
          "v": np.linspace(0.0, 1.0, 4000)}
@@ -174,24 +173,25 @@ def test_bench_payload_ingest_failed_runs_never_baseline(tmp_path):
         keys = [v["key"] for d in out["domains"]
                 for v in d["verdicts"] if v.get("regression")]
         assert any("rows/s" in k for k in keys)
+        # a payload FILE: the last parseable JSON line is the payload, and
+        # a budget alarm beside a real primary value is a measurement
+        p = tmp_path / "capture.json"
+        p.write_text("stderr noise\n" + json.dumps({"value": 1}) + "\n"
+                     + json.dumps({"value": 2, "budget_exceeded": True})
+                     + "\n")
+        r = wh.ingest(str(p))[0]
+        assert r["kind"] == "bench" and r["status"] == "ok"
+        assert wh.query("SELECT value FROM bench_metrics WHERE run_id = ?"
+                        " AND path = 'value'", (r["run_id"],)) == [(2.0,)]
 
 
-def test_compare_and_regress_share_one_core():
-    # satellite 1: compare.py routes its verdicts through the shared
-    # core — same failed-run detector, same two-point rule object
-    import importlib
-    # the package re-exports compare() the function; fetch the MODULES
-    CMP = importlib.import_module("spark_rapids_tpu.tools.compare")
-    REG = importlib.import_module("spark_rapids_tpu.tools.regression")
-    assert CMP.run_failure is REG.run_failure
-    assert CMP.delta_regression is REG.delta_regression
-    assert CMP.REL_THRESHOLD == REG.REL_THRESHOLD
+def test_regress_band_widens_with_the_baseline_noise():
     # MAD band: a noisy baseline widens its own band instead of flagging
     noisy = [1.0, 1.4, 0.7, 1.2, 0.8]
-    v = REG.detect(noisy, 1.45, higher_better=False)
+    v = detect(noisy, 1.45, higher_better=False)
     assert not v["regression"]
     tight = [1.0, 1.01, 0.99, 1.0, 1.0]
-    v = REG.detect(tight, 1.45, higher_better=False)
+    v = detect(tight, 1.45, higher_better=False)
     assert v["regression"]
 
 

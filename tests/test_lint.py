@@ -11,8 +11,6 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
 from spark_rapids_tpu.tools.lint import (load_facts, render_text,
                                          run_lint, write_baseline)
 from spark_rapids_tpu.tools.lint.rules import (ConfRegistryRule,
@@ -22,8 +20,6 @@ from spark_rapids_tpu.tools.lint.rules import (ConfRegistryRule,
                                                RetryFrameRule,
                                                SpillableCloseRule,
                                                TracedPurityRule)
-
-pytestmark = pytest.mark.smoke
 
 
 def _lint_snippet(tmp_path, source, rules, name="snippet.py"):

@@ -376,7 +376,7 @@ def test_literal_vs_literal_comparison_not_promoted():
 
 @pytest.mark.parametrize("qname", [
     "q3",
-    # q3 stays in the smoke tier (cheap, covers filter+join+agg fusion);
+    # q3 stays in tier-1 (cheap, covers filter+join+agg fusion);
     # the wider sweep is slow-only — fusion is default-on, so every
     # tier-1 TPC-DS vs-CPU test already executes through the compiler
     pytest.param("q1", marks=pytest.mark.slow),
@@ -388,7 +388,7 @@ def test_tpcds_fused_vs_per_operator_bit_identical(qname):
     """The stage compiler must be invisible to results: the same TPC-DS
     query through fused stages and through per-operator dispatch returns
     identical row sets (each side is separately compared against the CPU
-    engine by test_tpcds.py; this pins the fusion pass itself)."""
+    engine by tests/tpcds_differential.py; this pins the fusion pass itself)."""
     from spark_rapids_tpu.testing.rowcompare import rows_equal
     from spark_rapids_tpu.testing.tpcds import register_tables
     from spark_rapids_tpu.testing.tpcds_queries import QUERIES

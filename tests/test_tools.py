@@ -21,7 +21,6 @@ from spark_rapids_tpu.tools import __main__ as CLI
 from spark_rapids_tpu.tools.autotune import (autotune, autotune_query,
                                              render_recommendations,
                                              to_conf_dict)
-from spark_rapids_tpu.tools.compare import compare, render_compare
 from spark_rapids_tpu.tools.profile import attribute, render_report
 from spark_rapids_tpu.tools.reader import load_profiles, read_events
 
@@ -479,87 +478,6 @@ def test_autotune_cli_json(tmp_path, capsys):
 # bench compare
 # ---------------------------------------------------------------------------
 
-def _bench_payload(value, overlap, geomean):
-    return {"metric": "filter_project_hash_agg_rows_per_sec",
-            "value": value, "unit": "rows/s", "vs_baseline": 2.0,
-            "tpu_s": 1.0, "cpu_s": 2.0,
-            "pipeline": {"overlap_ratio": overlap,
-                         "consumer_stall_s": 0.5, "peak_depth": 2},
-            "tpcds": {"geomean_speedup": geomean, "queries_counted": 10},
-            "chaos": {"faults_injected": 0, "task_retries": 0}}
-
-
-def test_compare_payloads_and_regression_flag(tmp_path):
-    a = tmp_path / "BENCH_r01.json"
-    b = tmp_path / "BENCH_r02.json"
-    a.write_text(json.dumps(_bench_payload(1000, 0.8, 3.0)) + "\n")
-    b.write_text(json.dumps(_bench_payload(500, 0.2, 3.2)) + "\n")
-    out = compare([str(a), str(b)])
-    assert out["files"] == ["BENCH_r01.json", "BENCH_r02.json"]
-    rows = {r["metric"]: r for r in out["rows"]}
-    assert rows["rows/s"]["values"] == [1000, 500]
-    assert rows["rows/s"]["delta_pct"] == -50.0
-    assert rows["rows/s"]["regression"] is True
-    assert rows["TPC-DS geomean"]["regression"] is False
-    text = render_compare([str(a), str(b)])
-    assert "regressions" in text and "rows/s" in text
-
-
-def test_compare_cli(tmp_path, capsys):
-    a = tmp_path / "a.json"
-    a.write_text(json.dumps(_bench_payload(10, 0.5, 1.0)) + "\n")
-    assert CLI.main(["compare", str(a)]) == 0
-    assert "BENCH comparison" in capsys.readouterr().out
-
-
-def test_compare_takes_last_json_line(tmp_path):
-    p = tmp_path / "multi.json"
-    p.write_text("garbage\n"
-                 + json.dumps({"value": 1}) + "\n"
-                 + json.dumps({"value": 2}) + "\n")
-    out = compare([str(p)])
-    rows = {r["metric"]: r for r in out["rows"]}
-    assert rows["rows/s"]["values"] == [2]
-
-
-def test_compare_skips_and_flags_failed_payload(tmp_path):
-    """The BENCH_r05 shape: a budget-exceeded run records value 0 — a
-    healthy-vs-failed comparison must say 'run failed', never a
-    −100%/÷0 regression (in either direction)."""
-    good = tmp_path / "BENCH_r04.json"
-    bad = tmp_path / "BENCH_r05.json"
-    good.write_text(json.dumps(_bench_payload(1000, 0.8, 3.0)) + "\n")
-    bad.write_text(json.dumps({
-        "metric": "filter_project_hash_agg_rows_per_sec", "value": 0,
-        "unit": "rows/s", "vs_baseline": 0.0,
-        "error": "primary phase exceeded BENCH_BUDGET_S",
-        "budget_exceeded": True}) + "\n")
-    out = compare([str(good), str(bad)])
-    assert "BENCH_r05.json" in out["failed"]
-    assert "BENCH_BUDGET_S" in out["failed"]["BENCH_r05.json"]
-    rows = {r["metric"]: r for r in out["rows"]}
-    # the failed run's placeholder zeros never enter a row or a delta
-    assert rows["rows/s"]["values"] == [1000, None]
-    assert rows["rows/s"]["delta_pct"] == 0.0
-    assert not any(r.get("regression") for r in out["rows"])
-    text = render_compare([str(good), str(bad)])
-    assert "run failed" in text and "regressions" not in text
-    # reversed order: the failed run must not become the delta base
-    out2 = compare([str(bad), str(good)])
-    assert "BENCH_r05.json" in out2["failed"]
-    assert not any(r.get("regression") for r in out2["rows"])
-    # a budget-exceeded payload that still carries a REAL primary value
-    # (the committed BENCH_r04 shape) is a measurement, not a failure
-    partial = tmp_path / "partial.json"
-    pl = _bench_payload(900, 0.7, 3.1)
-    pl["budget_exceeded"] = True
-    partial.write_text(json.dumps(pl) + "\n")
-    out3 = compare([str(good), str(partial)])
-    assert not out3["failed"]
-    rows3 = {r["metric"]: r for r in out3["rows"]}
-    assert rows3["rows/s"]["values"] == [1000, 900]
-
-
 # ---------------------------------------------------------------------------
 # live resource sampler
 # ---------------------------------------------------------------------------
@@ -730,27 +648,6 @@ def test_prometheus_format_types_escaping_monotonicity():
             continue
         assert samples2.get(name, 0.0) >= samples1.get(name, 0.0), \
             f"counter {name} went backwards"
-
-
-# ---------------------------------------------------------------------------
-# bench smoke contract
-# ---------------------------------------------------------------------------
-
-def test_bench_event_log_payload_smoke(tmp_path):
-    """bench.py's _event_log_payload must parse a real log and report
-    profile_ok (the BENCH smoke assertion)."""
-    log = tmp_path / "bench_ev.jsonl"
-    _run_logged_query(log)
-    import bench
-    payload = bench._event_log_payload(str(log))
-    assert payload["profile_ok"] is True, payload
-    assert payload["queries"] == 1
-    assert payload["events"] > 0
-    # the per-query transition ledger rides the payload (schema v4)
-    (led,) = payload["transitions"].values()
-    assert led["d2h_count"] >= 1 and led["d2h_bytes"] > 0
-    bad = bench._event_log_payload(str(tmp_path / "missing.jsonl"))
-    assert bad["profile_ok"] is False and "error" in bad
 
 
 # ---------------------------------------------------------------------------

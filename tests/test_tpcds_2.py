@@ -1,0 +1,8 @@
+import pytest
+
+from tests.tpcds_differential import check, shard
+
+
+@pytest.mark.parametrize("qname", shard(2))
+def test_tpcds_query_differential(qname):
+    check(qname)

@@ -176,7 +176,7 @@ def test_split_retry_event_fires_once(tmp_path):
 def test_injected_retry_attributed_to_query(tmp_path):
     """Acceptance shape: a forced RetryOOM during a query shows up both
     as events in the JSONL log and as a nonzero retry_count in the query
-    summary (the one bench.py embeds)."""
+    summary."""
     from spark_rapids_tpu.exec import aggregate as AG
     log = tmp_path / "ev.jsonl"
     s = tpu_session({
