@@ -170,7 +170,8 @@ class QueryExecution:
         self.counters: Dict[str, int] = {"speculation_replays": 0,
                                          "pair_rows_padded": 0,
                                          "expand_rows_padded": 0,
-                                         "probe_gather_rounds": 0}
+                                         "probe_gather_rounds": 0,
+                                         "sized_joins": 0}
         #: seconds of the planning spans adopted from ``TpuSession.sql``,
         #: which ran before this query began: part of what the client
         #: waited, so part of ``duration_s``
@@ -836,7 +837,7 @@ class QueryExecution:
                 f"{k}={summary[k]}" for k in
                 ("dispatches", "dispatch_s", "speculation_replays",
                  "pair_rows_padded", "expand_rows_padded",
-                 "probe_gather_rounds")
+                 "probe_gather_rounds", "sized_joins")
                 if k in summary))
         lines.append("== Query Summary ==")
         lines.append(" ".join(
@@ -932,8 +933,8 @@ def run_span(plan):
 def add_count(name: str, n: int = 1) -> None:
     """Adds to a per-query counter of the active query's summary
     (``speculation_replays``, ``pair_rows_padded``,
-    ``expand_rows_padded``, ``probe_gather_rounds``), where the work
-    happens."""
+    ``expand_rows_padded``, ``probe_gather_rounds``, ``sized_joins``),
+    where the work happens."""
     q = EV.active_query()
     if q is not None:
         q.add_count(name, n)

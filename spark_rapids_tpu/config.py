@@ -333,9 +333,12 @@ JOIN_BUILD_SWAP_MAX_BYTES = conf_bytes(
 
 SPECULATIVE_SIZING_ENABLED = conf_bool(
     "spark.rapids.sql.join.speculativeSizing.enabled",
-    "Size join pair tables optimistically by the probe bucket and check "
-    "overflow flags at the collect sync (replay exact on overflow) "
-    "instead of paying a device round trip per join.",
+    "Size the pair table of a hash join whose probe batch is small (a "
+    "bucket of 32,768 rows or fewer) optimistically by the probe bucket "
+    "and check overflow flags at the collect sync (replay exact on "
+    "overflow) instead of paying a device round trip per join.  A larger "
+    "probe batch always fetches its candidate total (one scalar) and is "
+    "sized by it.  false = every join fetches its exact size.",
     True)
 
 SHUFFLE_DEVICE_SHRINK_THRESHOLD = conf_bytes(

@@ -18,7 +18,6 @@ from spark_rapids_tpu.exec.sort import (SortSpec, device_sort_batch,
 from spark_rapids_tpu.expressions.base import Expression
 from spark_rapids_tpu.expressions.evaluator import (eval_exprs_cpu,
                                                     eval_exprs_tpu)
-from spark_rapids_tpu.ops.batch_ops import shrink_batch
 from spark_rapids_tpu.plan.base import Exec, UnaryExec, closing_source
 
 
@@ -77,26 +76,16 @@ class CpuExpandExec(UnaryExec):
         return f"Expand[{len(self.projections)} projections]"
 
 
-#: an input batch whose bucket is above this is cut to the bucket of its
-#: live rows before the fan-out, and never below it: smaller inputs pay no
-#: sync for their count, and what a selective join chain keeps of a large
-#: one lands in one shape whatever its parameters keep
-EXPAND_MIN_BUCKET = 1 << 15
-
-
 class TpuExpandExec(CpuExpandExec):
     """Device Expand: each projection list is one fused XLA program
     (``expand.project``) over the same resident input batch — the fan-out
     costs no extra host transfers.
 
     The fan-out multiplies what it is handed, padding included, and the
-    aggregation above it pays for every padded row again.  A join chain
-    hands its output in the bucket of its probe side whatever it selects
-    (``exec/joins.py``), so a large input is first cut to the bucket of
-    its live rows (``shrink_batch``: the rows are at the front, the planes
-    are sliced, nothing is gathered).  That forces the deferred count:
-    one sync a large input batch, after which the fan-out and everything
-    above it run at the live rows' size."""
+    aggregation above it pays for every padded row again: it counts what
+    it hands on (``expand_rows_padded``) and cuts nothing itself.  A join
+    chain over a large probe side hands on the bucket of what it holds
+    (``exec/joins.py``, ``JOIN_SIZED_MIN_BUCKET``)."""
 
     is_device = True
 
@@ -107,8 +96,6 @@ class TpuExpandExec(CpuExpandExec):
         coerced = [self._coerced(p) for p in self.projections]
         with closing_source(self.child.execute_partition(pidx)) as it:
             for b in it:
-                if b.bucket > EXPAND_MIN_BUCKET:
-                    b = shrink_batch(b, minimum=EXPAND_MIN_BUCKET)
                 for proj in coerced:
                     add_count("expand_rows_padded", b.bucket)
                     yield eval_exprs_tpu(proj, b, self.names,

@@ -17,6 +17,20 @@ Structure per partition (TPU path):
                 (correct per-partition because the shuffle hash-partitions
                 both sides by the same keys).
 
+Sizing of a hash join's pair table and of the batch it hands on, by what
+the join can see in its input (``_TpuJoinCore._join_device``):
+  probe bucket > JOIN_SIZED_MIN_BUCKET: by the probe's candidate total,
+                fetched (one counted scalar sync a probe batch, site
+                ``join-size``): the padding of a large probe side costs
+                the device seconds, the fetch milliseconds, and every
+                operator above runs at the size of what the join kept
+  probe bucket <= the floor: speculatively (ops/speculation.py), probe
+                bucket x SPECULATIVE_PAIR_HEADROOM and no sync: there the
+                padding is cheap and the round trip is not
+  replay / speculativeSizing.enabled=false: exactly, a fetch every join
+Outer joins append the unmatched probe rows and semi/anti joins answer
+per probe row, so those still hand on a probe-sized batch.
+
 Sort-merge join: not built — the reference itself prefers converting SMJ to
 shuffled hash join (GpuSortMergeJoinMeta.scala); we always plan hash joins.
 """
@@ -28,6 +42,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.aux import transitions as TR
 from spark_rapids_tpu.aux.tracing import add_count
 from spark_rapids_tpu.columnar.batch import (ColumnarBatch, HostColumnarBatch,
                                              batch_from_arrow,
@@ -51,6 +66,18 @@ BUILD_SWAP_MAX_BYTES = 256 << 20
 #: candidates that verification rejects never flag overflow; the output
 #: table stays at the probe bucket (post-verify pairs truncate back)
 SPECULATIVE_PAIR_HEADROOM = 2
+
+#: a probe batch whose bucket is above this is sized by what it holds:
+#: the join fetches the probe's candidate total (one scalar, sync site
+#: ``join-size``) and its pair table and the batch it hands on take the
+#: bucket of that total, never one under this floor.  A probe batch at
+#: or under it pays no sync and keeps the speculative sizing.  The floor
+#: is what keeps the shapes still: what a selective join keeps of a
+#: large probe lands in one bucket whatever its parameters keep (a
+#: ladder that followed the rows down would compile anew when a literal
+#: moves a count across an edge), and a program at this size costs under
+#: a hundredth of one at a fact table's bucket
+JOIN_SIZED_MIN_BUCKET = 1 << 15
 
 
 from spark_rapids_tpu.columnar.column import known_empty as _known_empty
@@ -468,7 +495,8 @@ class _TpuJoinCore(_JoinBase):
                 # the gathers a probe row made to find its range
                 add_count("probe_gather_rounds", J.PROBE_GATHER_ROUNDS)
                 spec = speculation.active()
-                if spec is not None:
+                sized = probe_aug.bucket > JOIN_SIZED_MIN_BUCKET
+                if spec is not None and not sized:
                     # optimistic OUTPUT table = probe bucket (exact for
                     # the FK->PK joins that dominate star schemas: <=1
                     # build match per probe row), but candidates are
@@ -491,8 +519,16 @@ class _TpuJoinCore(_JoinBase):
                     verify_bucket = out_bucket * SPECULATIVE_PAIR_HEADROOM
                     spec.add(total > verify_bucket)
                 else:
-                    total = int(total)       # the per-join sizing sync
+                    # the per-join sizing sync: the pair table and the
+                    # batch handed on hold every candidate, so nothing
+                    # can overflow and nothing is truncated below.  A
+                    # large probe's table never goes under the floor;
+                    # the replay of a small one is exact
+                    total = TR.sync_int(total, site="join-size")
+                    add_count("sized_joins", 1)
                     out_bucket = J.bucket_rows(max(total, 1))
+                    if sized:
+                        out_bucket = max(out_bucket, JOIN_SIZED_MIN_BUCKET)
                     verify_bucket = out_bucket
                 # the rows of the pair table the device expands and
                 # verifies, whatever the join selects
@@ -522,12 +558,13 @@ class _TpuJoinCore(_JoinBase):
                                            out_bucket=probe.bucket)
                 continue
             l, r, n = J.compact_pairs(l_idx, r_idx, keep)
-            if use_hash and spec is not None and pair_bucket > out_bucket:
-                # the post-verify overflow check: only REAL pairs (after
-                # key verification AND the non-equi condition) must fit
-                # the optimistic output bucket; the verified headroom
-                # window then truncates back so output batches keep the
-                # probe-bucket footprint
+            if use_hash and pair_bucket > out_bucket:
+                # speculative sizing only (a table sized by its fetched
+                # total IS its output bucket).  The post-verify overflow
+                # check: only REAL pairs (after key verification AND the
+                # non-equi condition) must fit the optimistic output
+                # bucket; the verified headroom window then truncates
+                # back so output batches keep the probe-bucket footprint
                 from spark_rapids_tpu.columnar.column import (
                     DeferredCount as _DC, rc_traceable as _rt)
                 from spark_rapids_tpu.columnar.column import _jnp as _j

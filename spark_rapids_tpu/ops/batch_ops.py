@@ -177,14 +177,14 @@ def compact_batch(batch: ColumnarBatch, keep) -> ColumnarBatch:
     return ColumnarBatch(cols, row_count, batch.names)
 
 
-def shrink_batch(batch: ColumnarBatch, minimum: int = 1024) -> ColumnarBatch:
+def shrink_batch(batch: ColumnarBatch) -> ColumnarBatch:
     """Re-buckets a batch whose logical rows are far fewer than its bucket
     (e.g. aggregate output, post-filter shuffle input) by slicing every
     plane to the next power of two >= row_count.  Forces the deferred count
     (one sync) — call only at materialization boundaries (shuffle write,
     spill) where the count is needed anyway."""
     n = int(batch.row_count)
-    target = bucket_rows(max(n, 1), minimum=minimum)
+    target = bucket_rows(max(n, 1))
     if not batch.columns or target >= batch.bucket:
         return batch
     from spark_rapids_tpu.columnar.encoding import (materialize_rle_batch,

@@ -9,6 +9,15 @@ record a 0-d device overflow flag, and defer the truth test to the one
 sync the query already pays at collect.  If any flag fired, the action
 replays with speculation disabled (exact, sync-per-join sizing).
 
+Who still speculates: a hash join whose probe batch has a bucket at or
+under ``exec/joins.JOIN_SIZED_MIN_BUCKET`` (32,768 rows).  There the guess
+costs little (the padding of a small bucket is cheap on the device) and
+the round trip would be most of the join.  Above the floor the balance is
+the other way round — on the chip a scalar fetch costs milliseconds and a
+fact table's padding, carried through every operator above the join,
+seconds (PERF.md section 6, PR 34) — so a large probe fetches its
+candidate total, registers no flag, and can never force a replay.
+
 Reference analog: the retry-OOM framework (RmmRapidsRetryIterator.scala)
 re-executes work when a resource guess was wrong; here the guessed
 resource is an output shape instead of memory.

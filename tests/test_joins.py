@@ -8,6 +8,7 @@ import pytest
 from spark_rapids_tpu import functions as F
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.aux import tracing
+from spark_rapids_tpu.exec.joins import JOIN_SIZED_MIN_BUCKET as SIZED_FLOOR
 
 from tests.asserts import assert_tpu_and_cpu_are_equal_collect
 
@@ -155,6 +156,145 @@ def test_join_duplicate_key_explosion(sizing, copies, replays):
         .join(s.create_dataframe(right), on="k", how="inner"),
         ignore_order=True, conf=sizing)
     assert tracing.last_query_summary()["speculation_replays"] == replays
+
+
+#: a hash join sizes its pair table and the batch it hands on by what its
+#: probe batch's bucket says (``exec/joins.py``): above
+#: ``JOIN_SIZED_MIN_BUCKET`` by the probe's candidate total, fetched; at or
+#: under it speculatively, with no fetch.  Each case: probe rows, matches a
+#: probe row finds (a fraction: the share of rows that find one), and what
+#: the query's summary and the gateway must then read: ``sized_joins``,
+#: ``pair_rows_padded``, the join's output bucket, syncs by site
+SIZED_CASES = {
+    # 65,536-row probe bucket, a hundredth of the rows join: the floor
+    "over-the-floor-selective": (50_000, 0.01, dict(
+        sized=1, pair_rows=SIZED_FLOOR, out_bucket=SIZED_FLOOR,
+        syncs={"join-size": 1})),
+    # 200,000 pairs from a 65,536-row probe bucket: past bucket x headroom,
+    # which a speculative join answers by running the whole query twice
+    "over-the-floor-fan-out": (50_000, 4, dict(
+        sized=1, pair_rows=1 << 18, out_bucket=1 << 18,
+        syncs={"join-size": 1})),
+    # a probe bucket AT the floor: no fetch, bucket x headroom, as ever
+    "at-the-floor": (30_000, 0.01, dict(
+        sized=0, pair_rows=2 * SIZED_FLOOR, out_bucket=SIZED_FLOOR,
+        syncs={"speculation-overflow": 1})),
+    "small": (3_000, 0.01, dict(
+        sized=0, pair_rows=2 * 4096, out_bucket=4096,
+        syncs={"speculation-overflow": 1})),
+}
+
+
+@pytest.fixture
+def syncs_by_site(monkeypatch):
+    """The blocking syncs the gateway records, by site."""
+    from spark_rapids_tpu.aux import transitions as TR
+    seen = {}
+    record = TR._record_sync
+
+    def counting(site, *args, **kwargs):
+        seen[site] = seen.get(site, 0) + 1
+        return record(site, *args, **kwargs)
+
+    monkeypatch.setattr(TR, "_record_sync", counting)
+    return seen
+
+
+def _fact_and_dim(n, matches):
+    """``n`` fact rows over 1,000 keys; the dimension holds ``matches``
+    rows a key, or, for a fraction, one row for that share of the keys."""
+    rng = np.random.default_rng(7)
+    fact = {"k": rng.integers(0, 1000, n), "lv": rng.normal(size=n)}
+    keys = np.arange(1000) if matches >= 1 else \
+        np.arange(int(1000 * matches)) * int(1 / matches)
+    dim = {"k": np.repeat(keys, max(int(matches), 1))}
+    dim["rv"] = np.arange(len(dim["k"]), dtype=np.float64)
+    return fact, dim
+
+
+def _join_buckets(summary):
+    """The padded rows each of the summary's hash joins handed on, the
+    plan's last join first."""
+    return [sum(p["padded_rows"] for p in n["partitions"])
+            for n in summary["nodes"] if "HashJoin" in n["node"]]
+
+
+@pytest.mark.parametrize("case", list(SIZED_CASES))
+def test_join_sized_by_what_its_probe_bucket_says(case, syncs_by_site):
+    assert SIZED_FLOOR == 1 << 15
+    n, matches, want = SIZED_CASES[case]
+    fact, dim = _fact_and_dim(n, matches)
+    assert_tpu_and_cpu_are_equal_collect(
+        lambda s: s.create_dataframe(fact)
+        .join(s.create_dataframe(dim), on="k", how="inner"),
+        ignore_order=True)
+    summary = tracing.last_query_summary()
+    assert summary["speculation_replays"] == 0
+    assert summary["sized_joins"] == want["sized"]
+    assert summary["pair_rows_padded"] == want["pair_rows"]
+    assert _join_buckets(summary) == [want["out_bucket"]]
+    # the sized join pays its one fetch and registers no overflow flag,
+    # so the collect has none to check; a speculative one pays that check
+    assert syncs_by_site == want["syncs"]
+    assert summary["transitions"]["sync_count"] == 1
+
+
+@pytest.mark.parametrize("first_keeps, handed_on, sized, pair_rows, syncs", [
+    # the first join keeps 500 of 50,000 rows and hands on the floor's
+    # bucket: the second probes at 32,768 rows, speculatively
+    (0.01, SIZED_FLOOR, 1, SIZED_FLOOR + 2 * SIZED_FLOOR,
+     {"join-size": 1, "speculation-overflow": 1}),
+    # the first keeps 40,000 and hands on 65,536 rows: the second is over
+    # the floor too, and keeps a tenth of them
+    (0.8, 1 << 16, 2, (1 << 16) + SIZED_FLOOR, {"join-size": 2}),
+], ids=["second-at-the-floor", "second-over-the-floor"])
+def test_chained_joins_probe_at_the_bucket_the_first_hands_on(
+        first_keeps, handed_on, sized, pair_rows, syncs, syncs_by_site):
+    fact, dim = _fact_and_dim(50_000, first_keeps)
+    rng = np.random.default_rng(11)
+    fact["k2"] = rng.integers(0, 50, len(fact["k"]))
+    dim2 = {"k2": np.arange(5), "rv2": np.arange(5) * 1.5}
+    assert_tpu_and_cpu_are_equal_collect(
+        lambda s: s.create_dataframe(fact)
+        .join(s.create_dataframe(dim), on="k", how="inner")
+        .join(s.create_dataframe(dim2), on="k2", how="inner"),
+        ignore_order=True)
+    summary = tracing.last_query_summary()
+    assert summary["speculation_replays"] == 0
+    assert summary["sized_joins"] == sized
+    assert summary["pair_rows_padded"] == pair_rows
+    assert _join_buckets(summary) == [SIZED_FLOOR, handed_on]
+    assert syncs_by_site == syncs
+
+
+def test_sized_join_second_literal_traces_nothing():
+    """A sized join's shapes follow its candidate total's bucket and
+    nothing finer: a literal that keeps another count on the same side of
+    every bucket edge (516 and 493 rows, both under the floor) builds no
+    program."""
+    from spark_rapids_tpu.exec import stage_compiler as SC
+    from tests.asserts import tpu_session
+    fact, dim = _fact_and_dim(50_000, 1)
+    dim["a"] = dim["k"] % 100
+    s = tpu_session()
+    try:
+        s.create_or_replace_temp_view("fact", s.create_dataframe(fact))
+        s.create_or_replace_temp_view("dim", s.create_dataframe(dim))
+        text = ("select dim.k, sum(lv) s, count(*) c from fact, dim "
+                "where fact.k = dim.k and dim.a = {} group by dim.k")
+        counts, traces = [], []
+        for literal in (3, 7):
+            before = SC.stats()["traces"]
+            rows = s.sql(text.format(literal)).collect()
+            traces.append(SC.stats()["traces"] - before)
+            counts.append(sum(r["c"] for r in rows))
+            summary = tracing.last_query_summary()
+            assert summary["sized_joins"] == 1
+            assert summary["pair_rows_padded"] == SIZED_FLOOR
+        assert counts == [516, 493]
+        assert traces[0] > 0 and traces[1] == 0
+    finally:
+        s.stop()
 
 
 def test_join_then_aggregate():

@@ -290,6 +290,7 @@ def test_explain_analyze_renders_the_plan_and_the_phases():
     assert "Join" in plan_part and "exec.run" not in plan_part
     assert "exec.run=" in text and "dispatches=" in text
     assert "pair_rows_padded=" in text and "probe_gather_rounds=" in text
+    assert "sized_joins=" in text
 
 
 @pytest.fixture(scope="module")

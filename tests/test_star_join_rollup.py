@@ -14,11 +14,12 @@ import pytest
 
 from spark_rapids_tpu.aux import tracing
 from spark_rapids_tpu.exec import stage_compiler as SC
-from spark_rapids_tpu.exec.expand import EXPAND_MIN_BUCKET
+from spark_rapids_tpu.exec.joins import JOIN_SIZED_MIN_BUCKET
 
 SEED = 2147493319
-#: store_sales 144,020 rows: the joins put out a 262,144-row bucket, well
-#: above ``EXPAND_MIN_BUCKET``, of which a q27 keeps some 200 rows
+#: store_sales 144,020 rows: the first join probes with a 262,144-row
+#: bucket, well above ``JOIN_SIZED_MIN_BUCKET``, of which a q27 keeps some
+#: 200 rows
 SCALE_DOWN = 20
 PARAMS = {
     "q7": [{"GEN": "M", "MS": "S", "ES": "College", "YEAR": 2000},
@@ -83,20 +84,40 @@ def test_answers_match_the_plain_reference(star, q, nth):
 
 def test_the_fan_out_runs_at_the_live_rows_size(star):
     """q27's fan-out hands the aggregation three buckets of the join
-    chain's live rows: together no more than the last join's own output
-    bucket, where three padded copies of it were handed before."""
+    chain's live rows, where three padded copies of the fact table's
+    bucket were handed before: the first join, whose probe is the fact
+    table's 262,144-row bucket, is sized by its candidate total and hands
+    on the floor's bucket, and everything above it runs at that."""
     _, _, runs = star
     for run in (r for r in runs if r["q"] == "q27"):
         s = run["summary"]
-        last_join = next(n for n in s["nodes"] if "HashJoin" in n["node"])
-        join_bucket = sum(p["padded_rows"] for p in last_join["partitions"])
-        assert join_bucket > EXPAND_MIN_BUCKET
-        assert s["expand_rows_padded"] == 3 * EXPAND_MIN_BUCKET
-        assert 0 < s["expand_rows_padded"] <= join_bucket
+        joins = [n for n in s["nodes"] if "HashJoin" in n["node"]]
+        assert len(joins) == 4
+        for join in joins:
+            assert sum(p["padded_rows"] for p in join["partitions"]) \
+                == JOIN_SIZED_MIN_BUCKET
+        scan = max(sum(p["padded_rows"] for p in n["partitions"])
+                   for n in s["nodes"] if "Scan" in n["node"])
+        assert scan > JOIN_SIZED_MIN_BUCKET
+        assert s["expand_rows_padded"] == 3 * JOIN_SIZED_MIN_BUCKET
+        assert 0 < s["expand_rows_padded"] <= scan
         expand = next(n for n in s["nodes"] if "Expand" in n["node"])
         assert sum(p["padded_rows"] for p in expand["partitions"]) \
             == s["expand_rows_padded"]
-        # the count the fan-out forces, and the collect's
+
+
+@pytest.mark.parametrize("q", ["q7", "q27"])
+def test_one_sized_join_a_query_and_its_syncs(star, q):
+    """At a twentieth of SF1 the first join keeps some 2,000 rows: it is
+    the one probe over the floor (one fetch, site ``join-size``), the
+    other three probe at the floor's bucket and speculate (their flags
+    cost the collect one check), and the fan-out forces no count."""
+    _, _, runs = star
+    for run in (r for r in runs if r["q"] == q):
+        s = run["summary"]
+        assert s["sized_joins"] == 1
+        assert s["pair_rows_padded"] == (1 + 3 * 2) * JOIN_SIZED_MIN_BUCKET
+        assert s["speculation_replays"] == 0
         assert s["transitions"]["sync_count"] == 2
 
 
@@ -104,7 +125,6 @@ def test_a_query_without_grouping_sets_counts_no_fan_out(star):
     _, _, runs = star
     for run in (r for r in runs if r["q"] == "q7"):
         assert run["summary"]["expand_rows_padded"] == 0
-        assert run["summary"]["transitions"]["sync_count"] == 1
 
 
 def test_new_string_parameters_trace_nothing(star):
