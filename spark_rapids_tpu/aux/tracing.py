@@ -26,8 +26,18 @@ the annotation is an inert TraceMe, so nothing has to be switched on.  The
 span vocabulary is a contract (docs/observability.md, PERF.md):
 ``plan.parse``, ``plan.analyze``, ``plan.rewrite``, ``exec.run``,
 ``exec.replay``, ``xfer.h2d``, ``xfer.d2h``, ``xfer.sync``,
-``compile.build``, ``result.rows``; annotation only: ``dispatch`` and
-``exec.<node name>`` (one per batch pull).
+``compile.build``, ``result.rows``, ``device.permit`` (a wait for the
+device semaphore), a served query's ``serve.queue``, ``serve.admit`` and
+``serve.lookup``; annotation only: ``dispatch`` and ``exec.<node name>``
+(one per batch pull).
+
+Attribution rule: what a query's summary counts (``dispatches``, the
+transition ledger, the task metrics, ``compile_s``) is added to the
+active query where the work happens (``EV.active_query()``, which the
+task pools carry into their threads), never taken as a difference of
+process totals.  So over any set of queries, alone or overlapping, the
+summaries' counts add up to the process's delta, and a text counts the
+same in a crowd as alone.
 """
 
 from __future__ import annotations
@@ -128,6 +138,18 @@ class Span:
             - self.start
 
 
+#: the task-metric part of a summary: its tasks' ``TaskMetrics``, summed
+#: (``max_device_bytes``: the largest)
+_TASKS_ZERO = {"tasks": 0, "retry_count": 0, "split_retry_count": 0,
+               "oom_count": 0, "spill_count": 0, "spill_bytes": 0,
+               "semaphore_wait_s": 0.0, "alloc_wait_s": 0.0,
+               "max_device_bytes": 0}
+
+#: a query's transition ledger: its own crossings of the gateway
+_LEDGER_ZERO = {"h2d_count": 0, "h2d_bytes": 0, "h2d_s": 0.0,
+                "d2h_count": 0, "d2h_bytes": 0, "d2h_s": 0.0,
+                "sync_count": 0, "sync_s": 0.0}
+
 #: event kinds folded into per-node attribution at finish
 _ATTR_ZERO = {"spill_count": 0, "spill_bytes": 0, "retry_count": 0,
               "split_retry_count": 0, "oom_count": 0,
@@ -163,19 +185,24 @@ class QueryExecution:
         #: the exec span of the attached plan's root
         self._plan_span: Optional[Span] = None
         self._token = None
-        self._start_snapshot = None
-        self._transitions_snapshot = None
-        self._dispatch_snapshot = None
         #: counts noted where the work happens (:func:`add_count`)
         self.counters: Dict[str, int] = {"speculation_replays": 0,
                                          "pair_rows_padded": 0,
                                          "expand_rows_padded": 0,
                                          "probe_gather_rounds": 0,
                                          "sized_joins": 0}
-        #: seconds of the planning spans adopted from ``TpuSession.sql``,
-        #: which ran before this query began: part of what the client
-        #: waited, so part of ``duration_s``
-        self._adopted_s = 0.0
+        #: what this query's own threads did, added by the layer that did
+        #: it: steady dispatches (``exec/stage_compiler.py``), the
+        #: gateway's ledger (``aux/transitions.py``), its finished tasks'
+        #: metrics (``memory/metrics.py``) and its compiles
+        self._dispatches_by_kind: Dict[str, int] = {}
+        self._dispatch_s = 0.0
+        self._compile_s = 0.0
+        self._ledger = dict(_LEDGER_ZERO)
+        self._tasks = dict(_TASKS_ZERO)
+        #: facts of the query that the layer above knows (:func:`note`):
+        #: a served query's ``resolved`` and ``plan_cache``
+        self.notes: Dict = {}
         self.summary_dict: Optional[dict] = None
         self.finished = False
         #: cached predict_plan_costs rows for the attached plan (fixed
@@ -211,14 +238,6 @@ class QueryExecution:
     # -- lifecycle -----------------------------------------------------------
     def __enter__(self) -> "QueryExecution":
         self._token = EV._activate(self)
-        from spark_rapids_tpu.memory.device_manager import get_runtime
-        rt = get_runtime()
-        self._start_snapshot = rt.metrics.snapshot() if rt is not None \
-            else None
-        from spark_rapids_tpu.aux import transitions as TR
-        self._transitions_snapshot = TR.snapshot()
-        from spark_rapids_tpu.exec import stage_compiler as SC
-        self._dispatch_snapshot = SC.dispatch_totals()
         start_payload = {"description": self.description}
         if self.conf_snapshot:
             start_payload["conf"] = dict(self.conf_snapshot)
@@ -294,22 +313,56 @@ class QueryExecution:
             return sp
 
     def adopt(self, planned) -> None:
-        """Takes closed ``(name, start, end)`` intervals
-        (``plan.parse``/``plan.analyze`` of the text this query runs) as
-        children of the root, with their own times.  What of them lies
-        before the query began counts in ``duration_s``."""
+        """Takes closed ``(name, start, end)`` intervals (:func:`timed_span`:
+        ``plan.parse``/``plan.analyze`` of the text this query runs, a
+        served query's ``serve.*``) as children of the root, with their
+        own times; one may lie inside another.  What they cover of the
+        time before the query began counts in ``duration_s``."""
         with self._lock:
             for name, start, end in planned:
                 sp = Span(name, self.root.span_id, kind="phase")
                 sp.start, sp.end = start, end
                 self.root.children.append(sp)
                 self._span_index[sp.span_id] = sp
-                self._adopted_s += max(0.0, min(end, self.root.start)
-                                       - start)
 
     def add_count(self, name: str, n: int = 1) -> None:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
+
+    def note_dispatch(self, kind: str, seconds: float) -> None:
+        """One steady call of a built stage program, by this query."""
+        with self._lock:
+            self._dispatch_s += seconds
+            self._dispatches_by_kind[kind] = \
+                self._dispatches_by_kind.get(kind, 0) + 1
+
+    def note_compiled(self, seconds: float) -> None:
+        with self._lock:
+            self._compile_s += seconds
+
+    def note_transition(self, door: str, seconds: float,
+                        nbytes: int = 0) -> None:
+        """One crossing of the gateway by this query: ``door`` is
+        ``h2d``, ``d2h`` or ``sync``."""
+        with self._lock:
+            led = self._ledger
+            led[door + "_count"] += 1
+            led[door + "_s"] += seconds
+            if nbytes:
+                led[door + "_bytes"] += nbytes
+
+    def note_task(self, m) -> None:
+        """A finished task's ``TaskMetrics`` (``memory/metrics.py``)."""
+        with self._lock:
+            t = self._tasks
+            t["tasks"] += 1
+            for key in ("retry_count", "split_retry_count", "oom_count",
+                        "spill_count", "spill_bytes"):
+                t[key] += getattr(m, key)
+            t["semaphore_wait_s"] += m.semaphore_wait_seconds
+            t["alloc_wait_s"] += m.alloc_wait_seconds
+            t["max_device_bytes"] = max(t["max_device_bytes"],
+                                        m.max_device_bytes)
 
     def start_partition(self, node_key: int, pidx: int) -> Span:
         """Child span for one partition (task) of an exec node; called by
@@ -553,34 +606,6 @@ class QueryExecution:
                                        else m.value)
                               for m in ms.values()}
         attr = self._attribute_events()
-        # per-query TaskMetrics delta from the process registry
-        delta = {}
-        from spark_rapids_tpu.memory.device_manager import get_runtime
-        rt = get_runtime()
-        if rt is not None and self._start_snapshot is not None:
-            total, finished = rt.metrics.snapshot()
-            t0, f0 = self._start_snapshot
-            delta = {
-                "tasks": finished - f0,
-                "retry_count": total.retry_count - t0.retry_count,
-                "split_retry_count":
-                    total.split_retry_count - t0.split_retry_count,
-                "oom_count": total.oom_count - t0.oom_count,
-                "spill_count": total.spill_count - t0.spill_count,
-                "spill_bytes": total.spill_bytes - t0.spill_bytes,
-                "semaphore_wait_s": round(
-                    total.semaphore_wait_seconds
-                    - t0.semaphore_wait_seconds, 6),
-                # cooperative-arbitration parks (memory/arbiter.py)
-                "alloc_wait_s": round(
-                    total.alloc_wait_seconds - t0.alloc_wait_seconds, 6),
-                # max cannot be snapshot-subtracted like the counters;
-                # take THIS query's peak from its tasks' taskEnd events
-                "max_device_bytes": max(
-                    (int(ev.payload.get("max_device_bytes", 0))
-                     for ev in self.ring.events()
-                     if ev.kind == "taskEnd"), default=0),
-            }
         # recovery ledger: what resilience cost THIS query (chaos/fault
         # recovery transitions emitted by the shuffle/task layers; the
         # kind->key vocabulary lives in aux/faults.py)
@@ -629,34 +654,43 @@ class QueryExecution:
                 "kind": "phase", "start_s": round(sp.start, 6),
                 "end_s": round(sp.end if sp.end is not None else now, 6),
                 **sp.metrics}, span_id=sp.span_id)
+        phases, early_s = self._phase_self_times(now)
         with self._lock:
             counters = dict(self.counters)
+            tasks = {k: round(v, 6) if isinstance(v, float) else v
+                     for k, v in self._tasks.items()}
+            by_kind = dict(self._dispatches_by_kind)
+            dispatch_s, compile_s = self._dispatch_s, self._compile_s
+            ledger = {k: round(v, 6) if isinstance(v, float) else v
+                      for k, v in self._ledger.items()}
+            notes = dict(self.notes)
         summary = {
             "query_id": self.query_id,
             "description": self.description,
             "status": "error" if error is not None else "ok",
-            # what the client waited: the action, and the planning of
-            # its text that ``sql()`` did before the action began
-            "duration_s": round(self.root.duration_s + self._adopted_s, 6),
+            # what the client waited: the action, and what the spans it
+            # adopted cover of the time before it began (the planning of
+            # its text in ``sql()``, a served query's queue and admission)
+            "duration_s": round(self.root.duration_s + early_s, 6),
             "events": len(self.ring) + self.ring.dropped,
             "events_dropped": self.ring.dropped,
-            **delta,
+            **notes,
+            **tasks,
             **counters,
-            "phases": self._phase_self_times(now),
+            "phases": phases,
             "nodes": nodes,
+            "dispatches": sum(by_kind.values()),
+            "dispatch_s": round(dispatch_s, 6),
+            "dispatches_by_kind": by_kind,
+            "compile_s": round(compile_s, 6),
         }
-        if self._dispatch_snapshot is not None:
-            from spark_rapids_tpu.exec import stage_compiler as SC
-            summary.update(SC.dispatch_delta(self._dispatch_snapshot))
         if recovery:
             summary["recovery"] = recovery
-        # host-transition ledger: snapshot-delta of the gateway counters
-        # (aux/transitions.py) — robust to ring drops, like TaskMetrics
-        if self._transitions_snapshot is not None:
-            from spark_rapids_tpu.aux import transitions as TR
-            ledger = TR.snapshot().delta(self._transitions_snapshot)
-            if TR.enabled():
-                summary["transitions"] = ledger
+        # host-transition ledger: this query's own crossings of the
+        # gateway (aux/transitions.py)
+        from spark_rapids_tpu.aux import transitions as TR
+        if TR.enabled():
+            summary["transitions"] = ledger
         # calibrated cost-model cross-check (report-only; docs/history.md):
         # predicted wall time from the tools/history machine profile vs
         # this query's measured duration, emitted before sinks close so
@@ -733,54 +767,59 @@ class QueryExecution:
     def _phase_spans(self) -> List[Span]:
         return self._spans_of("phase")
 
-    def _phase_self_times(self, now: float) -> Dict[str, float]:
-        """``{span name: self seconds}`` over the phase spans: a span's
-        duration less the part that the phase spans inside it cover (the
-        choosing-metrics rule).  Every instant of the root's interval
-        goes to the innermost phase span open then (the latest opened
-        where threads overlap), or to ``(unattributed)``; adopted spans
-        add their own durations.  So the entries add up to
-        ``duration_s``."""
+    def _phase_self_times(self, now: float):
+        """``({span name: self seconds}, early seconds)`` over the phase
+        spans: a span's duration less the part that the phase spans
+        inside it cover (the choosing-metrics rule).  Every instant goes
+        to the innermost phase span open then (the latest opened where
+        threads overlap, or where one adopted span lies inside another);
+        an instant of the root's interval with none open goes to
+        ``(unattributed)``, one before the root began to nothing.  The
+        early seconds are what the adopted spans cover of the time before
+        the root began, so the entries add up to the root's duration plus
+        those: ``duration_s``."""
         lo, hi = self.root.start, (self.root.end if self.root.end
                                    is not None else now)
-        out: Dict[str, float] = {}
-        edges = []      # (time, 0 = close | 1 = open, index)
         spans = []      # (depth among phase spans, start, name)
+        edges = []      # (time, 0 = close | 1 = open, index)
+        out: Dict[str, float] = {"(unattributed)": 0.0}
 
         def walk(sp: Span, depth: int) -> None:
             for c in sp.children:
                 d = depth
                 if c.kind == "phase":
                     d = depth + 1
-                    end = c.end if c.end is not None else now
-                    if c.start < lo:    # adopted: ran before the root
-                        out[c.name] = out.get(c.name, 0.0) \
-                            + min(end, lo) - c.start
-                    if min(end, hi) > max(c.start, lo):
-                        edges.append((max(c.start, lo), 1, len(spans)))
-                        edges.append((min(end, hi), 0, len(spans)))
+                    out.setdefault(c.name, 0.0)     # a span of no length
+                    end = min(c.end if c.end is not None else now, hi)
+                    if end > c.start:
+                        edges.append((c.start, 1, len(spans)))
+                        edges.append((end, 0, len(spans)))
                         spans.append((d, c.start, c.name))
                 walk(c, d)
 
         with self._lock:
             walk(self.root, 0)
         edges.sort()
+        early = 0.0
         open_heap: List = []    # (-depth, -start, index): innermost first
         closed = set()
         at = lo
         for t, opens, i in edges:
             while open_heap and open_heap[0][2] in closed:
                 heapq.heappop(open_heap)
-            name = spans[open_heap[0][2]][2] if open_heap \
-                else "(unattributed)"
-            out[name] = out.get(name, 0.0) + (t - at)
+            if open_heap:
+                name = spans[open_heap[0][2]][2]
+                out[name] = out.get(name, 0.0) + (t - at)
+                early += max(0.0, min(t, lo) - at)
+            elif t > lo:
+                out["(unattributed)"] += t - max(at, lo)
             at = t
             if opens:
                 heapq.heappush(open_heap, (-spans[i][0], -spans[i][1], i))
             else:
                 closed.add(i)
-        out["(unattributed)"] = out.get("(unattributed)", 0.0) + (hi - at)
-        return {k: round(v, 6) for k, v in out.items()}
+        out["(unattributed)"] += hi - max(at, lo)
+        return {k: round(v, 6) for k, v in out.items()}, early
 
     # -- rendering -----------------------------------------------------------
     def render_tree(self, show_partitions: bool = False) -> str:
@@ -911,6 +950,28 @@ def span(name: str, **attrs):
             sp.end = time.monotonic()
 
 
+@contextlib.contextmanager
+def timed_span(name: str, into: list, start: Optional[float] = None,
+               **attrs):
+    """:func:`span`, for work that is done before its query opens (a
+    text's planning in ``TpuSession.sql``, a served query's queue,
+    admission and lookup): the closed interval ``(name, start, end)`` is
+    appended to ``into``, for the query to adopt (``query_scope``'s
+    ``planned``) and for the caller's own sums, so both read one clock.
+    ``start`` backdates the interval to a wait that no thread ran inside
+    (``serve.queue``: the annotation then marks its end)."""
+    t0 = time.monotonic() if start is None else start
+    sp = None
+    try:
+        with span(name, **attrs) as sp:
+            if sp is not None:      # inside a query: the tree's own clock
+                sp.start = t0
+            yield sp
+    finally:
+        into.append((name, t0, sp.end if sp is not None
+                     else time.monotonic()))
+
+
 def partition_pull(q: Optional[QueryExecution], pspan: Optional[Span],
                    node_name: str):
     """One pull of an operator's partition iterator: the thread runs
@@ -940,13 +1001,23 @@ def add_count(name: str, n: int = 1) -> None:
         q.add_count(name, n)
 
 
+def note(**fields) -> None:
+    """Sets fields of the active query's summary that only the layer
+    above the query knows: a served query's ``resolved`` and
+    ``plan_cache`` (``serving/``)."""
+    q = EV.active_query()
+    if q is not None:
+        with q._lock:
+            q.notes.update(fields)
+
+
 @contextlib.contextmanager
 def query_scope(conf=None, description: str = "", planned=()):
     """Action-level wrapper: opens a QueryExecution unless one is already
     active (nested actions — cache materialization, explain(analyze) —
     join the outer query) or tracing is disabled by conf.  ``planned``
-    holds the closed ``plan.parse``/``plan.analyze`` intervals of the
-    text this action runs; the query that opens adopts them."""
+    holds the closed intervals of what was done for this action before it
+    (:func:`timed_span`); the query that opens adopts them."""
     active = EV.active_query()
     if active is not None:
         if planned:

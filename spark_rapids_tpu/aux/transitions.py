@@ -7,8 +7,10 @@ that claim falsifiable: every H2D upload, D2H download and blocking
 device sync in the package routes through here (the ``sync-site`` lint
 rule pins the discipline for ``block_until_ready``/``jax.device_get``),
 emitting schema-v4 ``hostTransition`` / ``deviceSync`` events and
-aggregating into a process-lifetime ledger that ``QueryExecution``
-snapshots per query.
+adding each crossing to two ledgers: the process-lifetime one
+(``totals()``) and that of the query whose thread made it
+(``QueryExecution.note_transition``, the summary's ``transitions``), so
+the summaries of overlapping queries add up to the process's delta.
 
 Reference analog: the plugin wraps every transition operator
 (GpuRowToColumnarExec / GpuColumnarToRowExec) in dedicated GPU metrics
@@ -55,9 +57,7 @@ _LOCK = threading.Lock()
 
 @dataclasses.dataclass
 class TransitionStats:
-    """Process-lifetime ledger counters.  ``QueryExecution`` snapshots at
-    query start and subtracts at finish — robust to ring-buffer drops,
-    the same discipline as the TaskMetrics registry."""
+    """Process-lifetime ledger counters."""
     h2d_count: int = 0
     h2d_bytes: int = 0
     h2d_seconds: float = 0.0
@@ -67,19 +67,6 @@ class TransitionStats:
     sync_count: int = 0
     sync_seconds: float = 0.0
 
-    def delta(self, start: "TransitionStats") -> dict:
-        """JSON-safe per-query ledger from a start-of-query snapshot."""
-        return {
-            "h2d_count": self.h2d_count - start.h2d_count,
-            "h2d_bytes": self.h2d_bytes - start.h2d_bytes,
-            "h2d_s": round(self.h2d_seconds - start.h2d_seconds, 6),
-            "d2h_count": self.d2h_count - start.d2h_count,
-            "d2h_bytes": self.d2h_bytes - start.d2h_bytes,
-            "d2h_s": round(self.d2h_seconds - start.d2h_seconds, 6),
-            "sync_count": self.sync_count - start.sync_count,
-            "sync_s": round(self.sync_seconds - start.sync_seconds, 6),
-        }
-
 
 _TOTAL = TransitionStats()
 
@@ -88,14 +75,9 @@ def enabled() -> bool:
     return _ENABLED
 
 
-def snapshot() -> TransitionStats:
-    """Copy of the process-lifetime counters (for per-query deltas)."""
-    with _LOCK:
-        return dataclasses.replace(_TOTAL)
-
-
 def totals() -> dict:
-    """Process-lifetime ledger for render_prometheus()."""
+    """Process-lifetime ledger (``render_prometheus()``, the benchmark's
+    window deltas)."""
     with _LOCK:
         return {
             "h2d_count": _TOTAL.h2d_count,
@@ -125,6 +107,12 @@ def sync_from_conf(conf) -> None:
 # they own the timed operation; columnar/transfer.py)
 # ---------------------------------------------------------------------------
 
+def _note_query(door: str, duration_s: float, nbytes: int = 0) -> None:
+    q = EV.active_query()
+    if q is not None:
+        q.note_transition(door, duration_s, int(nbytes))
+
+
 def record_h2d(nbytes: int, duration_s: float, kinds: str = "",
                planes: int = 0) -> None:
     """One packed host->device upload.  ``kinds`` is the comma-joined
@@ -136,6 +124,7 @@ def record_h2d(nbytes: int, duration_s: float, kinds: str = "",
         _TOTAL.h2d_count += 1
         _TOTAL.h2d_bytes += int(nbytes)
         _TOTAL.h2d_seconds += duration_s
+    _note_query("h2d", duration_s, nbytes)
     if _EVENTS:
         EV.emit("hostTransition", direction="h2d", bytes=int(nbytes),
                 duration_s=round(duration_s, 6), kinds=kinds,
@@ -152,6 +141,7 @@ def record_d2h(nbytes: int, duration_s: float, site: str = "download",
         _TOTAL.d2h_count += 1
         _TOTAL.d2h_bytes += int(nbytes)
         _TOTAL.d2h_seconds += duration_s
+    _note_query("d2h", duration_s, nbytes)
     if _EVENTS:
         EV.emit("hostTransition", direction="d2h", bytes=int(nbytes),
                 duration_s=round(duration_s, 6), site=site,
@@ -163,6 +153,7 @@ def _record_sync(site: str, duration_s: float,
     with _LOCK:
         _TOTAL.sync_count += 1
         _TOTAL.sync_seconds += duration_s
+    _note_query("sync", duration_s)
     if _EVENTS:
         payload = {"site": site, "duration_s": round(duration_s, 6)}
         if nbytes is not None:

@@ -218,6 +218,9 @@ class StageProgram:
         with _LOCK:
             _STATS["compiles"] += 1
             _STATS["compile_s"] += dt
+        q = EV.active_query()
+        if q is not None:
+            q.note_compiled(dt)
         from spark_rapids_tpu.aux.events import emit
         emit("stageCompile", stage_kind=self.kind, key=self.key_hash,
              duration_s=round(dt, 6), tier=tier,
@@ -227,10 +230,12 @@ class StageProgram:
     def _dispatch(self, fn, args):
         """The steady path: a call of a program that is already built.
         One ``srt.dispatch`` annotation (no Span object: a query may make
-        thousands) and two clock reads; counted once it has returned."""
+        thousands) and two clock reads; counted once it has returned, in
+        the process's totals and in the query that made the call."""
+        q = EV.active_query()
         t0 = time.perf_counter()
-        with annotation("dispatch", EV.active_query(),
-                        EV.current_span_id(), kind=self.kind):
+        with annotation("dispatch", q, EV.current_span_id(),
+                        kind=self.kind):
             out = fn(*args)
         dt = time.perf_counter() - t0
         with _LOCK:
@@ -238,6 +243,8 @@ class StageProgram:
             _STATS["dispatch_s"] += dt
             _DISPATCHES_BY_KIND[self.kind] = \
                 _DISPATCHES_BY_KIND.get(self.kind, 0) + 1
+        if q is not None:
+            q.note_dispatch(self.kind, dt)
         return out
 
     def __call__(self, *args):
@@ -569,24 +576,6 @@ def stats() -> Dict:
         out["disk_cache_error"] = _DISK["error"]
         out["async_error"] = _ASYNC_ERROR[0]
         return out
-
-
-def dispatch_totals() -> Tuple[int, float, Dict[str, int]]:
-    """The steady-path counters, for a query's start-of-query snapshot."""
-    with _LOCK:
-        return (_STATS["dispatches"], _STATS["dispatch_s"],
-                dict(_DISPATCHES_BY_KIND))
-
-
-def dispatch_delta(start) -> Dict:
-    """What the process dispatched since ``start``: the per-query part of
-    the summary (``QueryExecution.finish``)."""
-    n0, s0, by0 = start
-    n, s, by = dispatch_totals()
-    return {"dispatches": n - n0, "dispatch_s": round(s - s0, 6),
-            "dispatches_by_kind": {k: v - by0.get(k, 0)
-                                   for k, v in by.items()
-                                   if v != by0.get(k, 0)}}
 
 
 def reset_stats() -> None:

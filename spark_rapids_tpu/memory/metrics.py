@@ -77,8 +77,7 @@ class MetricsRegistry:
             self.finished_tasks += 1
 
     def snapshot(self):
-        """(totals copy, finished_tasks) under one lock — the delta basis
-        for per-query summaries (aux/tracing.QueryExecution)."""
+        """(totals copy, finished_tasks) under one lock."""
         with self._lock:
             s = TaskMetrics()
             s.merge(self.total)
@@ -103,7 +102,10 @@ def task_scope(task_id: int, registry: Optional[MetricsRegistry] = None):
         if registry is not None:
             registry.report(ctx.metrics)
         m = ctx.metrics
-        from spark_rapids_tpu.aux.events import emit
+        from spark_rapids_tpu.aux.events import active_query, emit
+        q = active_query()
+        if q is not None:       # the query's summary sums its own tasks
+            q.note_task(m)
         emit("taskEnd", task_id=task_id, retry_count=m.retry_count,
              split_retry_count=m.split_retry_count, oom_count=m.oom_count,
              spill_count=m.spill_count, spill_bytes=m.spill_bytes,

@@ -23,6 +23,8 @@ import time
 import traceback
 from typing import Dict, Optional
 
+from spark_rapids_tpu.aux.tracing import span
+
 #: wait slice between cancellation checks while queued on admission
 _WAIT_SLICE_S = 0.05
 
@@ -55,20 +57,26 @@ class TpuSemaphore:
             if entry is not None:
                 entry["depth"] += 1
                 return
-            self._waiting += 1
-            try:
+            def no_permit() -> bool:
                 # another thread of the SAME task acquiring concurrently
                 # creates the holder entry; re-check it each wake so both
                 # land on one permit at depth 2 (the old duplicate-permit
                 # return dance, folded into the wait condition)
-                t0 = arb.wait_cancellable(
-                    self._cond,
-                    lambda: tid not in self._holders
-                    and self._permits <= 0,
-                    TaskState.BLOCKED_ON_SEMAPHORE,
-                    slice_s=_WAIT_SLICE_S)
-            finally:
-                self._waiting -= 1
+                return tid not in self._holders and self._permits <= 0
+
+            t0 = None
+            if no_permit():
+                # the span opens only where the task waits: its query's
+                # ``device.permit`` seconds are the wait and nothing else
+                self._waiting += 1
+                try:
+                    with span("device.permit", task_id=tid):
+                        t0 = arb.wait_cancellable(
+                            self._cond, no_permit,
+                            TaskState.BLOCKED_ON_SEMAPHORE,
+                            slice_s=_WAIT_SLICE_S)
+                finally:
+                    self._waiting -= 1
             entry = self._holders.get(tid)
             if entry is not None:
                 # a sibling thread of the same task won the race and

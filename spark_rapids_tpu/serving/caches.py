@@ -34,6 +34,7 @@ import time
 from typing import Dict, Optional, Tuple
 
 from spark_rapids_tpu.aux.events import emit
+from spark_rapids_tpu.aux.tracing import note
 from spark_rapids_tpu.plan.base import Exec
 
 
@@ -131,7 +132,10 @@ class PlanCache:
         """Exact-hit lease, or None (miss / busy / stale / disabled).
         A normalized-structure hit with different literal values counts
         as ``norm_hits`` — the caller plans (cheap) but shares the
-        entry's compiled-executable set through literal promotion."""
+        entry's compiled-executable set through literal promotion.  The
+        outcome is noted on the active query's summary (``plan_cache``:
+        ``hit``, ``norm_hit``, ``miss``, ``busy_bypass``,
+        ``invalidated``), beside the cache's own totals."""
         if self.max_plans <= 0 or sig is None:
             return None
         key = (conf_digest, sig.norm)
@@ -139,6 +143,7 @@ class PlanCache:
             entry = self._entries.get(key)
             if entry is None:
                 self.stats["misses"] += 1
+                note(plan_cache="miss")
                 emit("planCache", op="miss", norm=sig.norm[:12])
                 return None
             self._entries.move_to_end(key)
@@ -146,6 +151,7 @@ class PlanCache:
             if variant is None:
                 self.stats["norm_hits"] += 1
                 self.stats["misses"] += 1
+                note(plan_cache="norm_hit")
                 emit("planCache", op="norm_hit", norm=sig.norm[:12],
                      variants=len(entry))
                 return None
@@ -155,6 +161,7 @@ class PlanCache:
                 self.stats["invalidations"] += len(entry)
                 self.total_bytes -= sum(v.nbytes for v in entry.values())
                 del self._entries[key]
+                note(plan_cache="invalidated")
                 emit("planCache", op="invalidate", norm=sig.norm[:12],
                      variants=len(entry))
                 return None
@@ -163,9 +170,11 @@ class PlanCache:
                 # nodes carry per-execution state; racing one instance
                 # from two queries is never worth the risk)
                 self.stats["busy_bypass"] += 1
+                note(plan_cache="busy_bypass")
                 emit("planCache", op="busy", norm=sig.norm[:12])
                 return None
             self.stats["hits"] += 1
+            note(plan_cache="hit")
             emit("planCache", op="hit", norm=sig.norm[:12])
             return PlanLease(variant, "hit")
 

@@ -69,6 +69,14 @@ LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
 STAGE_KEYS = ("queue_wait_s", "admit_wait_s", "lookup_s", "plan_s",
               "compile_s", "execute_s", "collect_s")
 
+#: the stages that are sums of a submission's spans of one name
+#: (``Submission.spans``): the histogram, the ``servingAdmission`` event
+#: and the query's ``phases`` read the same intervals.  ``serve.lookup``
+#: is text to DataFrame, signature, fingerprints and the result-cache
+#: probe, its re-check after admission, and the plan-cache lookup
+STAGE_SPANS = {"queue_wait_s": "serve.queue", "admit_wait_s": "serve.admit",
+               "lookup_s": "serve.lookup"}
+
 
 class LatencyHistogram:
     """One fixed-bucket latency histogram (Prometheus semantics: the
@@ -279,8 +287,18 @@ class Submission:
         #: "planned"; plus timing (``latency_s`` = submit-to-finish,
         #: queue wait included — the number a serving SLO is made of)
         self.info: Dict = {}
+        #: closed ``(name, start, end)`` intervals of the ``serve.*`` spans
+        #: and of the text's planning (``aux.tracing.timed_span``), in the
+        #: order they closed; the query that runs adopts those that closed
+        #: before it opened
+        self.spans: List[tuple] = []
 
     def _finish(self, batch=None, error=None) -> None:
+        stages = self.info.get("stages")
+        if stages is not None:
+            for key, name in STAGE_SPANS.items():
+                stages[key] = round(sum(end - start for n, start, end
+                                        in self.spans if n == name), 6)
         self.info["latency_s"] = round(time.monotonic() - self.submitted, 6)
         self._batch = batch
         self.error = error
@@ -515,14 +533,15 @@ class QueryServer:
         return query
 
     def _serve(self, sub: Submission, query) -> None:
-        t0 = time.monotonic()
-        stages = sub.info["stages"] = {k: 0.0 for k in STAGE_KEYS}
-        stages["queue_wait_s"] = round(t0 - sub.submitted, 6)
+        from spark_rapids_tpu.aux.tracing import timed_span
+        sub.info["stages"] = {k: 0.0 for k in STAGE_KEYS}
+        with timed_span("serve.queue", sub.spans, start=sub.submitted):
+            pass        # nothing ran in it: the annotation marks the pickup
         # result-cache probe BEFORE admission: a cached result needs no
         # device memory reservation, so a hit must not queue behind (or
         # steal a slot from) queries that actually execute
         try:
-            probe = self._probe_result_cache(sub, query, stages)
+            probe = self._probe_result_cache(sub, query)
         except BaseException as e:  # noqa: BLE001 - handed to caller
             sub._finish(error=e)
             self._observe_stages(sub)
@@ -532,17 +551,16 @@ class QueryServer:
             sub._finish(batch=probe["cached"])
             self._observe_stages(sub)
             return
-        reserved = self.admission.admit(
-            sub.serve_id,
-            deadline=sub.submitted + self.admission.timeout_ms / 1000.0)
+        with timed_span("serve.admit", sub.spans):
+            reserved = self.admission.admit(
+                sub.serve_id,
+                deadline=sub.submitted + self.admission.timeout_ms / 1000.0)
         try:
             # conf snapshot AT ADMISSION: online deltas accepted while
             # this query was queued apply to it; deltas accepted during
             # its run apply only to later admissions
             conf = self.conf
             sub.info["reserved_bytes"] = reserved
-            sub.info["admit_wait_s"] = round(time.monotonic() - t0, 4)
-            stages["admit_wait_s"] = sub.info["admit_wait_s"]
             batch = self._execute(sub, query, conf, probe=probe)
             sub._finish(batch=batch)
         except BaseException as e:  # noqa: BLE001 - handed to caller
@@ -551,27 +569,34 @@ class QueryServer:
             self.admission.release(sub.serve_id)
             self._observe_stages(sub)
 
-    def _probe_result_cache(self, sub: Submission, query,
-                            stages: Dict) -> Dict:
-        """Builds the plan, signs it, and probes the result cache under
-        the CURRENT conf.  The probe (plan/signature/digest) is handed
-        to ``_execute`` so an admitted miss does not re-plan unless the
-        online tuner changed the conf while the query waited."""
-        t_lk = time.monotonic()
-        conf = self.conf
+    def _sign(self, sub: Submission, query, conf) -> Dict:
+        """Text to DataFrame (its ``plan.parse``/``plan.analyze`` intervals
+        join the submission's), signature, fingerprints, conf digest and
+        the result cache's key."""
         df = self._build_df(query)
+        if isinstance(query, str):
+            sub.spans.extend(df._planned)
         plan = df._plan
         sig = plan_signature(plan)
-        fps = plan_fingerprints(plan)
         cdig = self._conf_digest(conf)
         rkey = None
         if sig is not None:
             rkey = hashlib.sha1(
                 (cdig + ":" + sig.exact).encode()).hexdigest()
-        cached = self.result_cache.lookup(rkey, fps)
-        stages["lookup_s"] = round(time.monotonic() - t_lk, 6)
-        return {"cached": cached, "plan": plan, "sig": sig, "fps": fps,
+        return {"plan": plan, "sig": sig, "fps": plan_fingerprints(plan),
                 "cdig": cdig, "rkey": rkey}
+
+    def _probe_result_cache(self, sub: Submission, query) -> Dict:
+        """Builds the plan, signs it, and probes the result cache under
+        the CURRENT conf.  The probe (plan/signature/digest) is handed
+        to ``_execute`` so an admitted miss does not re-plan unless the
+        online tuner changed the conf while the query waited."""
+        from spark_rapids_tpu.aux.tracing import timed_span
+        with timed_span("serve.lookup", sub.spans, cache="result"):
+            probe = self._sign(sub, query, self.conf)
+            probe["cached"] = self.result_cache.lookup(probe["rkey"],
+                                                       probe["fps"])
+        return probe
 
     def _observe_stages(self, sub: Submission) -> None:
         """End-of-submission latency decomposition: every stage (and the
@@ -591,35 +616,28 @@ class QueryServer:
                    for k in STAGE_KEYS})
 
     def _execute(self, sub: Submission, query, conf, probe=None):
-        from spark_rapids_tpu.aux.tracing import query_scope
+        from spark_rapids_tpu.aux.tracing import (note, query_scope,
+                                                  timed_span)
         from spark_rapids_tpu.serving.signature import plan_pins
         from spark_rapids_tpu.session import collect_with_speculation
-        stages = sub.info.get("stages")
-        t_lk = time.monotonic()
-        if probe is not None and probe["cdig"] == self._conf_digest(conf):
-            # pre-admission probe still valid: reuse its plan/signature
-            # and re-check only the cache (a concurrent peer may have
-            # published this result while we waited for admission)
+        stages = sub.info["stages"]
+        with timed_span("serve.lookup", sub.spans, cache="result"):
+            if probe is None or probe["cdig"] != self._conf_digest(conf):
+                # the online tuner changed the conf while the query
+                # waited: plan and sign again under the new one
+                probe = self._sign(sub, query, conf)
+            # else the pre-admission probe is still valid: reuse its
+            # plan/signature and re-check only the cache (a concurrent
+            # peer may have published this result while we waited for
+            # admission)
             plan, sig, fps = probe["plan"], probe["sig"], probe["fps"]
             cdig, rkey = probe["cdig"], probe["rkey"]
-        else:
-            df = self._build_df(query)
-            plan = df._plan
-            sig = plan_signature(plan)
-            fps = plan_fingerprints(plan)
-            cdig = self._conf_digest(conf)
-            rkey = None
-            if sig is not None:
-                rkey = hashlib.sha1(
-                    (cdig + ":" + sig.exact).encode()).hexdigest()
-        cached = self.result_cache.lookup(rkey, fps)
-        if stages is not None:
-            stages["lookup_s"] = round(
-                stages.get("lookup_s", 0.0)
-                + (time.monotonic() - t_lk), 6)
+            cached = self.result_cache.lookup(rkey, fps)
         if cached is not None:
             sub.info["resolved"] = "result_cache"
             return cached
+        # what closed before the query opens, for it to adopt
+        planned = tuple(sub.spans)
         lease_box: Dict = {}
 
         def prepared_plan():
@@ -628,7 +646,8 @@ class QueryServer:
             from spark_rapids_tpu.exec.basic import refresh_cte_epochs
             from spark_rapids_tpu.plan.overrides import TpuOverrides
             if "lease" not in lease_box:
-                lease = self.plan_cache.lookup(cdig, sig, fps)
+                with timed_span("serve.lookup", sub.spans, cache="plan"):
+                    lease = self.plan_cache.lookup(cdig, sig, fps)
                 if lease is not None:
                     # cached physical plan: NO planning, NO compile —
                     # just the per-execution preamble (fresh CTE epoch,
@@ -645,6 +664,7 @@ class QueryServer:
                     if lease is None:       # cache disabled / unsigned
                         lease_box["plan"] = executed
                 lease_box["lease"] = lease
+                note(resolved=sub.info["resolved"])
             else:
                 # speculation-overflow replay: exec nodes memoize
                 # per-execution state (exchange stores, join build
@@ -667,25 +687,26 @@ class QueryServer:
                 q.attach_plan(out)
             return out
 
+        prepared_s = 0.0    # inside prepared_plan, over the replays
+
         def timed_prepared_plan():
             # plan_s accumulates across speculation replays (the rare
-            # re-plan path invokes this more than once)
-            t = time.monotonic()
+            # re-plan path invokes this more than once); the plan-cache
+            # lookup inside it is ``lookup_s``'s, with its span
+            nonlocal prepared_s
+            t, n = time.monotonic(), len(sub.spans)
             try:
                 return prepared_plan()
             finally:
-                if stages is not None:
-                    stages["plan_s"] = round(
-                        stages["plan_s"] + time.monotonic() - t, 6)
+                dt = time.monotonic() - t
+                looked = sum(end - start for _, start, end in sub.spans[n:])
+                prepared_s += dt
+                stages["plan_s"] = round(stages["plan_s"] + dt - looked, 6)
 
-        from spark_rapids_tpu.aux import transitions as TR
-        from spark_rapids_tpu.exec import stage_compiler as SC
-        compile_s0 = float(SC.stats()["compile_s"])
-        tr0 = TR.snapshot()
         t_exec = time.monotonic()
         qe = None
         try:
-            with query_scope(conf, f"serve:{sub.tag}") as qe:
+            with query_scope(conf, f"serve:{sub.tag}", planned) as qe:
                 batch = collect_with_speculation(conf,
                                                  timed_prepared_plan)
         except BaseException:
@@ -703,21 +724,20 @@ class QueryServer:
             lease = lease_box.get("lease")
             if lease is not None:
                 lease.release()
-        if stages is not None:
-            # decompose the execution wall: compile from the stage
-            # compiler's measured delta (process-wide — concurrent
-            # peers' compiles can bleed in, same caveat as every shared
-            # counter), collect as the transition ledger's D2H fetch
-            # seconds, execute as the clamped remainder
-            exec_wall = max(0.0, time.monotonic() - t_exec)
-            compile_s = max(0.0,
-                            float(SC.stats()["compile_s"]) - compile_s0)
-            collect_s = float(TR.snapshot().delta(tr0).get("d2h_s", 0.0))
-            stages["compile_s"] = round(compile_s, 6)
-            stages["collect_s"] = round(collect_s, 6)
-            stages["execute_s"] = round(
-                max(0.0, exec_wall - stages["plan_s"] - compile_s
-                    - collect_s), 6)
+        # decompose the execution wall: compile and collect are what THIS
+        # query's summary counted (its own compiles; its own D2H fetch
+        # seconds in the transition ledger: a peer's are in the peer's),
+        # execute is the clamped remainder.  With tracing off
+        # (``spark.rapids.sql.tracing.enabled``) no query opened and the
+        # remainder is all there is
+        done = (qe.summary_dict if qe is not None else None) or {}
+        compile_s = float(done.get("compile_s", 0.0))
+        collect_s = float(done.get("transitions", {}).get("d2h_s", 0.0))
+        stages["compile_s"] = round(compile_s, 6)
+        stages["collect_s"] = round(collect_s, 6)
+        stages["execute_s"] = round(
+            max(0.0, time.monotonic() - t_exec - prepared_s - compile_s
+                - collect_s), 6)
         self.result_cache.put(rkey, fps, batch, pins=plan_pins(plan))
         if self.autotune_enabled and qe is not None:
             self._autotune_step(qe)

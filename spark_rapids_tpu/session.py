@@ -11,7 +11,6 @@ per query, GpuOverrides.scala:4564).
 from __future__ import annotations
 
 import itertools
-import time
 from typing import Dict, List, Optional, Sequence, Union
 
 from spark_rapids_tpu import types as T
@@ -130,19 +129,17 @@ class TpuSession:
         """Executes SQL text against registered temp views (the reference
         accepts arbitrary Spark SQL via Catalyst; here sql/ carries the
         parser + analyzer for the TPC-DS-class dialect)."""
-        from spark_rapids_tpu.aux.tracing import span
+        from spark_rapids_tpu.aux.tracing import timed_span
         from spark_rapids_tpu.sql.analyzer import Analyzer
         from spark_rapids_tpu.sql.parser import parse
         # no query runs yet: the spans go to the profiler's trace at once,
         # and the DataFrame keeps the intervals for the query that runs it
-        t0 = time.monotonic()
-        with span("plan.parse"):
+        planned: list = []
+        with timed_span("plan.parse", planned):
             tree = parse(text)
-        t1 = time.monotonic()
-        with span("plan.analyze"):
+        with timed_span("plan.analyze", planned):
             df = Analyzer(self).plan(tree)
-        df._planned = (("plan.parse", t0, t1),
-                       ("plan.analyze", t1, time.monotonic()))
+        df._planned = tuple(planned)
         return df
 
     def create_or_replace_temp_view(self, name: str, df: "DataFrame") -> None:

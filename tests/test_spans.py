@@ -123,7 +123,9 @@ def test_phases_add_up_to_the_querys_duration():
 
 
 def test_self_time_is_duration_less_what_the_children_cover():
-    """The rule on hand-made intervals: nested, and overlapping threads."""
+    """The rule on hand-made intervals: nested, overlapping threads, and
+    adopted spans (before the root, one inside another, a gap after them
+    that is nobody's)."""
     qe = TR.QueryExecution(description="hand-made")
     qe.root.start, qe.root.end = 100.0, 110.0
 
@@ -133,17 +135,18 @@ def test_self_time_is_duration_less_what_the_children_cover():
         parent.children.append(sp)
         return sp
 
-    child(qe.root, "plan.parse", 90.0, 90.5)        # adopted: before root
+    child(qe.root, "serve.lookup", 89.0, 91.0)      # adopted: before root
+    child(qe.root, "plan.parse", 90.0, 90.5)        # adopted, inside it
     run = child(qe.root, "exec.run", 101.0, 109.0)
     child(run, "xfer.d2h", 102.0, 105.0)
     child(run, "xfer.sync", 104.0, 106.0)           # another thread
     child(qe.root, "result.rows", 109.0, 109.5)
-    qe._adopted_s = 0.5
-    got = qe._phase_self_times(110.0)
-    assert got == {"plan.parse": 0.5, "exec.run": 4.0, "xfer.d2h": 2.0,
-                   "xfer.sync": 2.0, "result.rows": 0.5,
+    got, early_s = qe._phase_self_times(110.0)
+    assert got == {"serve.lookup": 1.5, "plan.parse": 0.5, "exec.run": 4.0,
+                   "xfer.d2h": 2.0, "xfer.sync": 2.0, "result.rows": 0.5,
                    "(unattributed)": 1.5}
-    assert sum(got.values()) == 10.0 + 0.5
+    assert early_s == 2.0
+    assert sum(got.values()) == 10.0 + early_s
 
 
 def test_an_overflowing_speculation_replays_and_says_so():

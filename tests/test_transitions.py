@@ -48,15 +48,23 @@ def _jline(kind, query_id, span_id, ts, v=EV.EVENT_SCHEMA_VERSION,
 
 
 # ---------------------------------------------------------------------------
-# the gateway: counters, snapshot/delta, conf gating
+# the gateway: the process's ledger and the active query's, conf gating
 # ---------------------------------------------------------------------------
 
-def test_gateway_counters_and_delta():
+def _since(start):
+    """What the process's ledger gained since ``start = TR.totals()``."""
+    return {k: v - start[k] for k, v in TR.totals().items()}
+
+
+def test_gateway_counters_and_the_querys_ledger():
+    from spark_rapids_tpu.aux.tracing import QueryExecution
     tpu_session({"spark.rapids.sql.test.enabled": "false"})
-    start = TR.snapshot()
-    TR.record_h2d(1000, 0.25, kinds="dict,flat", planes=3)
-    TR.record_d2h(400, 0.125, site="download")
-    d = TR.snapshot().delta(start)
+    start = TR.totals()
+    with QueryExecution(description="hand-made") as qe:
+        TR.record_h2d(1000, 0.25, kinds="dict,flat", planes=3)
+        TR.record_d2h(400, 0.125, site="download")
+    TR.record_d2h(77, 0.5)          # outside any query: the process's only
+    d = qe.summary_dict["transitions"]
     assert d["h2d_count"] == 1 and d["h2d_bytes"] == 1000
     assert d["d2h_count"] == 1 and d["d2h_bytes"] == 400
     assert abs(d["h2d_s"] - 0.25) < 1e-9
@@ -64,6 +72,10 @@ def test_gateway_counters_and_delta():
     # ledger keys are the fixed 8-key schema, all JSON-scalar
     assert set(d) == {"h2d_count", "h2d_bytes", "h2d_s", "d2h_count",
                       "d2h_bytes", "d2h_s", "sync_count", "sync_s"}
+    total = _since(start)
+    assert total["h2d_count"] == 1 and total["d2h_count"] == 2
+    assert total["d2h_bytes"] == 477
+    assert abs(total["d2h_seconds"] - 0.625) < 1e-6
 
 
 def test_gateway_fetch_and_sync_count_once():
@@ -72,13 +84,13 @@ def test_gateway_fetch_and_sync_count_once():
     boundary crossing is never counted in BOTH ledger columns."""
     import jax.numpy as jnp
     tpu_session({"spark.rapids.sql.test.enabled": "false"})
-    start = TR.snapshot()
+    start = TR.totals()
     host = TR.fetch(jnp.arange(128), site="test-fetch")
     assert host.shape == (128,)
     n = TR.sync_int(jnp.asarray(7), site="test-count")
     assert n == 7
-    d = TR.snapshot().delta(start)
-    assert d["sync_count"] == 2 and d["sync_s"] >= 0.0
+    d = _since(start)
+    assert d["sync_count"] == 2 and d["sync_seconds"] >= 0.0
     assert d["d2h_count"] == 0, \
         "sync-site fetches must land in sync_*, not d2h_*"
 
@@ -88,10 +100,10 @@ def test_gateway_conf_disable_stops_counting():
     try:
         s.set_conf("spark.rapids.sql.transitions.enabled", "false")
         assert not TR.enabled()
-        start = TR.snapshot()
+        start = TR.totals()
         TR.record_h2d(999, 0.5)
         TR.record_d2h(999, 0.5)
-        d = TR.snapshot().delta(start)
+        d = _since(start)
         assert d["h2d_count"] == 0 and d["d2h_count"] == 0
     finally:
         s.set_conf("spark.rapids.sql.transitions.enabled", "true")
