@@ -190,7 +190,8 @@ class QueryExecution:
                                          "pair_rows_padded": 0,
                                          "expand_rows_padded": 0,
                                          "probe_gather_rounds": 0,
-                                         "sized_joins": 0}
+                                         "sized_joins": 0,
+                                         "sized_stages": 0}
         #: what this query's own threads did, added by the layer that did
         #: it: steady dispatches (``exec/stage_compiler.py``), the
         #: gateway's ledger (``aux/transitions.py``), its finished tasks'
@@ -876,7 +877,7 @@ class QueryExecution:
                 f"{k}={summary[k]}" for k in
                 ("dispatches", "dispatch_s", "speculation_replays",
                  "pair_rows_padded", "expand_rows_padded",
-                 "probe_gather_rounds", "sized_joins")
+                 "probe_gather_rounds", "sized_joins", "sized_stages")
                 if k in summary))
         lines.append("== Query Summary ==")
         lines.append(" ".join(
@@ -994,8 +995,8 @@ def run_span(plan):
 def add_count(name: str, n: int = 1) -> None:
     """Adds to a per-query counter of the active query's summary
     (``speculation_replays``, ``pair_rows_padded``,
-    ``expand_rows_padded``, ``probe_gather_rounds``, ``sized_joins``),
-    where the work happens."""
+    ``expand_rows_padded``, ``probe_gather_rounds``, ``sized_joins``,
+    ``sized_stages``), where the work happens."""
     q = EV.active_query()
     if q is not None:
         q.add_count(name, n)

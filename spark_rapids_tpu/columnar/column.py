@@ -44,6 +44,27 @@ def bucket_strlen(n: int) -> int:
     return max(MIN_STR_BUCKET, _next_pow2(n))
 
 
+#: the one floor for every shrink: an operator that sizes the batch it
+#: hands on by a fetched count does so only for an input batch whose
+#: bucket is above this, and never hands on a bucket under it.  A hash
+#: join (``exec/joins.py``) sizes its pair table and output by the probe
+#: batch's candidate total (sync site ``join-size``); a fused stage that
+#: filters (``exec/fused.py``) sizes its output by the filter's live
+#: count (sync site ``stage-size``).  A batch at or under the floor pays
+#: no sync.  The floor is what keeps the shapes still: what a selective
+#: operator keeps of a large batch lands in one bucket whatever its
+#: parameters keep (a ladder that followed the rows down would compile
+#: anew when a literal moves a count across an edge), and a program at
+#: this size costs under a hundredth of one at a fact table's bucket.
+#: Read on the chip for the smallest stage it engages (``date_dim``:
+#: 73,049 rows in a 131,072-row bucket, of which a q3 keeps some 6,000
+#: and a q55 some 30; PERF.md section 6, PR 36): sized,
+#: ``store_scan_agg`` answers 3.83–3.85 queries/s where 3.59 stood and
+#: ``served_streams`` 4.01–4.02 where 3.75–3.76 stood, so the stage has
+#: no floor of its own
+SIZED_MIN_BUCKET = 1 << 15
+
+
 class DeferredCount:
     """A row count living on device until the host actually needs it.
 

@@ -85,7 +85,8 @@ class TpuExpandExec(CpuExpandExec):
     aggregation above it pays for every padded row again: it counts what
     it hands on (``expand_rows_padded``) and cuts nothing itself.  A join
     chain over a large probe side hands on the bucket of what it holds
-    (``exec/joins.py``, ``JOIN_SIZED_MIN_BUCKET``)."""
+    (``exec/joins.py``; the floor is ``columnar/column.py``'s
+    ``SIZED_MIN_BUCKET``)."""
 
     is_device = True
 

@@ -8,6 +8,24 @@ selection mask that the terminal consumes (reductions mask by it; the
 compact terminal sorts the row positions once and gathers by them).  This
 removes whole kernel dispatches and all intermediate HBM materialization.
 
+Sizing of the batch a stage with a filter hands on, by what the stage can
+see of its input (``TpuFusedStageExec``):
+  batch bucket > SIZED_MIN_BUCKET (``columnar/column.py``): by the
+                filter's live count, fetched (one counted scalar sync a
+                batch, site ``stage-size``, counter ``sized_stages``).
+                ``fused.stage`` runs the chain over the input's bucket and
+                returns its planes uncompacted, the compaction's
+                permutation and the count; ``fused.compact`` then gathers
+                every plane at ``max(bucket_rows(count), floor)`` rows: a
+                gathered row is what the terminal costs, the fetch
+                milliseconds, and every operator above (a join's build
+                side, a roll-up's fan-out) runs at the size of what the
+                filter kept.  The row count handed on is known
+  batch bucket <= the floor: one program with the compact terminal
+                inside, no sync, a deferred count, the input's bucket:
+                there the padding is cheap and the round trip is not
+A stage of projections alone keeps every row and is never sized.
+
 The reference dispatches one cuDF kernel per operator and cannot do this
 (GpuProjectExec -> columnarEval chains, basicPhysicalOperators.scala:350);
 whole-stage fusion is the structural advantage of tracing compilation, and
@@ -22,6 +40,7 @@ from typing import List, Sequence, Tuple
 import numpy as np
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.columnar import column as COL
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import (DeferredCount, DeviceColumn,
                                               rc_traceable)
@@ -177,7 +196,8 @@ class TpuFusedStageExec(UnaryExec, _PromotedLiteralsMixin):
         pending = None
         with closing_source(self.child.execute_partition(pidx)) as it:
             for b in it:
-                prog, args, enc = self._program(b)
+                step = self._program(b)
+                prog, args = step[:2]
                 if SC.ASYNC_COMPILE and prog.needs_compile():
                     # background lower+compile; the one-batch look-ahead
                     # below overlaps it with the previous batch's
@@ -192,18 +212,24 @@ class TpuFusedStageExec(UnaryExec, _PromotedLiteralsMixin):
                 # an extra batch's device arrays per fused stage for zero
                 # overlap benefit
                 if prog.compiling():
-                    pending = (prog, args, enc)
+                    pending = step
                 else:
-                    yield self._finish(prog, args, enc)
+                    yield self._finish(*step)
         if pending is not None:
             yield self._finish(*pending)
 
     def _program(self, b):
+        """The stage program for ``b``, its arguments, the encoding plan
+        and whether the batch is sized (the module's header): ``_finish``
+        takes the four."""
         from spark_rapids_tpu.columnar import encoding as ENC
-        from spark_rapids_tpu.ops.batch_ops import compact_planes
+        from spark_rapids_tpu.ops.batch_ops import (compact_planes,
+                                                    compaction_perm)
         jnp = _jx()
         enc = ENC.plan_fused_stage(self.ops, b, cache=self._enc_cache)
         ops = self.ops if enc is None else enc.ops
+        sized = b.bucket > COL.SIZED_MIN_BUCKET and \
+            any(kind == "filter" for kind, _ in self.ops)
         key = (_ops_signature(self.ops), _batch_signature(b), b.bucket,
                None if enc is None else enc.sig)
 
@@ -221,11 +247,14 @@ class TpuFusedStageExec(UnaryExec, _PromotedLiteralsMixin):
                                          lits,
                                          None if plan is None
                                          else enc_args[0])
+                planes = [(c.data, c.valid, c.lengths,
+                           getattr(c, "elem_valid", None)) for c in cols]
+                if sized:
+                    # the terminal's gathers wait for the count
+                    # (``_compact_sized``): the planes leave as they are
+                    return planes, compaction_perm(sel, jnp), jnp.sum(sel)
                 # compact terminal: kept rows to the front
-                return compact_planes(
-                    [(c.data, c.valid, c.lengths,
-                      getattr(c, "elem_valid", None)) for c in cols],
-                    sel, jnp)
+                return compact_planes(planes, sel, jnp)
 
             return run
         from spark_rapids_tpu.exec.stage_compiler import get_or_build
@@ -235,11 +264,44 @@ class TpuFusedStageExec(UnaryExec, _PromotedLiteralsMixin):
         args = (_cols_to_arrs(b), rc_traceable(b.row_count),
                 self._lit_args(),
                 () if enc is None else enc.runtime_args(b))
-        return prog, args, enc
+        return prog, args, enc, sized
 
-    def _finish(self, prog, args, enc=None):
-        outs, cnt = prog(*args)
-        rc = DeferredCount(cnt)
+    @staticmethod
+    def _compact_sized(planes, perm, cnt):
+        """The compact terminal of a sized batch: fetches the live count
+        (one counted scalar sync, site ``stage-size``) and gathers every
+        plane at ``max(bucket_rows(count), floor)`` rows, or at the
+        input's bucket where that is no smaller (a filter that keeps most
+        of a large batch pays the fetch, milliseconds, and shrinks
+        nothing).  Returns the planes and the count, an ``int``."""
+        from spark_rapids_tpu.aux import transitions as TR
+        from spark_rapids_tpu.aux.tracing import add_count
+        from spark_rapids_tpu.exec.stage_compiler import get_or_build
+        from spark_rapids_tpu.ops.batch_ops import take_front_planes
+        jnp = _jx()
+        n = TR.sync_int(cnt, site="stage-size")
+        add_count("sized_stages", 1)
+        out_bucket = min(perm.shape[0],
+                         max(COL.bucket_rows(n), COL.SIZED_MIN_BUCKET))
+        key = (tuple(tuple(None if p is None else (tuple(p.shape),
+                                                   str(p.dtype))
+                           for p in col) for col in planes), out_bucket)
+
+        def build():
+            def run(arrs, front_first, live):
+                return take_front_planes(arrs, front_first, live,
+                                         out_bucket, jnp)
+
+            return run
+        return get_or_build("fused.compact", key, build)(planes, perm,
+                                                         n), n
+
+    def _finish(self, prog, args, enc=None, sized=False):
+        if sized:
+            outs, rc = self._compact_sized(*prog(*args))
+        else:
+            outs, cnt = prog(*args)
+            rc = DeferredCount(cnt)
         fields = self.schema.fields
         cols = []
         for i, ((d, v, ln, ev), f) in enumerate(zip(outs, fields)):

@@ -19,7 +19,8 @@ Structure per partition (TPU path):
 
 Sizing of a hash join's pair table and of the batch it hands on, by what
 the join can see in its input (``_TpuJoinCore._join_device``):
-  probe bucket > JOIN_SIZED_MIN_BUCKET: by the probe's candidate total,
+  probe bucket > SIZED_MIN_BUCKET (``columnar/column.py``, the one floor
+                for every shrink): by the probe's candidate total,
                 fetched (one counted scalar sync a probe batch, site
                 ``join-size``): the padding of a large probe side costs
                 the device seconds, the fetch milliseconds, and every
@@ -44,6 +45,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.aux import transitions as TR
 from spark_rapids_tpu.aux.tracing import add_count
+from spark_rapids_tpu.columnar import column as COL
 from spark_rapids_tpu.columnar.batch import (ColumnarBatch, HostColumnarBatch,
                                              batch_from_arrow,
                                              concat_host_batches)
@@ -66,18 +68,6 @@ BUILD_SWAP_MAX_BYTES = 256 << 20
 #: candidates that verification rejects never flag overflow; the output
 #: table stays at the probe bucket (post-verify pairs truncate back)
 SPECULATIVE_PAIR_HEADROOM = 2
-
-#: a probe batch whose bucket is above this is sized by what it holds:
-#: the join fetches the probe's candidate total (one scalar, sync site
-#: ``join-size``) and its pair table and the batch it hands on take the
-#: bucket of that total, never one under this floor.  A probe batch at
-#: or under it pays no sync and keeps the speculative sizing.  The floor
-#: is what keeps the shapes still: what a selective join keeps of a
-#: large probe lands in one bucket whatever its parameters keep (a
-#: ladder that followed the rows down would compile anew when a literal
-#: moves a count across an edge), and a program at this size costs under
-#: a hundredth of one at a fact table's bucket
-JOIN_SIZED_MIN_BUCKET = 1 << 15
 
 
 from spark_rapids_tpu.columnar.column import known_empty as _known_empty
@@ -495,7 +485,7 @@ class _TpuJoinCore(_JoinBase):
                 # the gathers a probe row made to find its range
                 add_count("probe_gather_rounds", J.PROBE_GATHER_ROUNDS)
                 spec = speculation.active()
-                sized = probe_aug.bucket > JOIN_SIZED_MIN_BUCKET
+                sized = probe_aug.bucket > COL.SIZED_MIN_BUCKET
                 if spec is not None and not sized:
                     # optimistic OUTPUT table = probe bucket (exact for
                     # the FK->PK joins that dominate star schemas: <=1
@@ -528,7 +518,7 @@ class _TpuJoinCore(_JoinBase):
                     add_count("sized_joins", 1)
                     out_bucket = J.bucket_rows(max(total, 1))
                     if sized:
-                        out_bucket = max(out_bucket, JOIN_SIZED_MIN_BUCKET)
+                        out_bucket = max(out_bucket, COL.SIZED_MIN_BUCKET)
                     verify_bucket = out_bucket
                 # the rows of the pair table the device expands and
                 # verifies, whatever the join selects

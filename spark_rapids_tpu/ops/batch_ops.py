@@ -128,19 +128,29 @@ def compaction_perm(keep, jnp):
     return lex_sort_perm([~keep], keep.shape[0], jnp)
 
 
+def take_front_planes(arrs, perm, cnt, out_bucket: int, jnp):
+    """Traceable gather of ``[(data, valid, lengths, elem_valid)]`` by the
+    first ``out_bucket`` positions of ``perm`` (a ``compaction_perm``: the
+    ``cnt`` kept rows first), validity cleared past ``cnt``.  A gathered
+    row is what costs, so ``out_bucket`` is the bucket of what was kept
+    wherever the caller knows it (``exec/fused.py``)."""
+    front = perm[:out_bucket]
+    live = jnp.arange(out_bucket) < cnt
+
+    def move(plane):
+        return None if plane is None else jnp.take(plane, front, axis=0)
+
+    return [(move(d), move(v) & live, move(ln), move(ev))
+            for d, v, ln, ev in arrs]
+
+
 def compact_planes(arrs, keep, jnp):
     """Traceable compaction of ``[(data, valid, lengths, elem_valid)]`` by
     the ``keep`` mask: kept rows first (stable), validity cleared past the
     kept count.  Returns (planes, count)."""
     cnt = jnp.sum(keep)
-    live = jnp.arange(keep.shape[0]) < cnt
-    perm = compaction_perm(keep, jnp)
-
-    def move(plane):
-        return None if plane is None else jnp.take(plane, perm, axis=0)
-
-    return [(move(d), move(v) & live, move(ln), move(ev))
-            for d, v, ln, ev in arrs], cnt
+    return take_front_planes(arrs, compaction_perm(keep, jnp), cnt,
+                             keep.shape[0], jnp), cnt
 
 
 def compact_batch(batch: ColumnarBatch, keep) -> ColumnarBatch:

@@ -14,7 +14,8 @@ so an equi-join becomes:
    range [lo, lo + count) of sorted build positions per probe row, two
    32-bit gathers and no search (static shapes throughout).  The range
    holds every build row of equal hash and, on average, under
-   ``1 / _TABLE_LOAD`` rows of another hash, which step 5 drops;
+   ``1 / _TABLE_LOAD`` rows of another hash (fewer where the probe side
+   is the larger: ``_PROBE_LOAD``), which step 5 drops;
 4. expand candidate pairs into a padded pair table: output position ``r``
    belongs to the last probe row whose offset is ``<= r``, found for all
    positions at once by a histogram of the offsets and a prefix sum
@@ -128,10 +129,31 @@ def _col_sig(c: DeviceColumn) -> Tuple:
 #: reading (v5e, 4.2M-row probe bucket; PERF.md section 6, PR 32): the
 #: probe program takes 69.7 ms against any table of 2^17 to 2^23 slots,
 #: 76.5 ms against 2^24 (64 MiB: the 1.9M-row build side at 8) and 145.6
-#: ms against 2^25 (the same side at 16), and false candidates cost no
-#: time inside the fixed pair window (11% of the probe rows at 8, 23% at
-#: 4, 6% at 16).  So 8 is the most margin that is still free.
+#: ms against 2^25 (the same side at 16).
 _TABLE_LOAD = 8
+#: Slots for each row of the PROBE bucket, where that asks for more than
+#: the build bucket does.  A large join's pair table and the batch it
+#: hands on take the bucket of the candidate total (``exec/joins.py``),
+#: so a false candidate is no longer free: 27,440 live rows of a
+#: 32,768-row build bucket at ``_TABLE_LOAD`` alone (2^18 slots) gave
+#: the fact table's 2.88M probe rows 300,000 false candidates, a
+#: 524,288-row pair table where 65,536 hold the pairs, and every join
+#: above ran at that (0.21 s a star query in ``join.gather`` alone).
+#: The probe's share is capped at ``2^_TABLE_FREE_BITS`` slots.  Read on
+#: the chip, build and probe together, under the fact table's
+#: 4,194,304-row bucket (one call, same seeds; PERF.md section 6, PR 36):
+#: tables of 2^22, 2^23 and 2^24 slots answer 1.841, 1.822-1.840 and
+#: 1.811-1.816 star queries/s, the lookups of a query taking 67.9, 68.0
+#: and 74.1 ms (flat up to 2^23, as PR 32 read the lookup alone), so the
+#: cap is 2^23.  What the rule costs a join that had few false
+#: candidates to lose: the table's zero fill and prefix sum over 8M
+#: slots, 2 ms a build (``store_scan_agg`` 3.846-3.857 queries/s without
+#: the rule, 3.804-3.818 with it).  1 slot a probe row would halve that
+#: and reads the same in the star cell, but leaves a star query's
+#: candidate total at 89% of its 65,536-row bucket where 2 leaves it at
+#: 75%.
+_PROBE_LOAD = 2
+_TABLE_FREE_BITS = 23
 #: No table has more than ``2^_TABLE_MAX_BITS`` slots (512 MiB of int32),
 #: which is ``_TABLE_LOAD`` slots a row up to a build bucket of 2^24 rows.
 #: Above that a slot holds ``bucket / 2^27`` rows of another hash on
@@ -143,10 +165,13 @@ _TABLE_MAX_BITS = 27
 PROBE_GATHER_ROUNDS = 2
 
 
-def _table_bits(bucket: int) -> int:
-    """``k``: the table over a ``bucket``-row build side has ``2^k`` slots.
-    A function of the build bucket alone, so of the program's shapes."""
-    return min((_TABLE_LOAD * bucket).bit_length() - 1, _TABLE_MAX_BITS)
+def _table_bits(bucket: int, probe_bucket: int) -> int:
+    """``k``: the table over a ``bucket``-row build side that a
+    ``probe_bucket``-row batch probes has ``2^k`` slots.  A function of
+    the two buckets alone, so of the programs' shapes."""
+    slots = max(_TABLE_LOAD * bucket,
+                min(_PROBE_LOAD * probe_bucket, 1 << _TABLE_FREE_BITS))
+    return min(slots.bit_length() - 1, _TABLE_MAX_BITS)
 
 
 def _slot_of(h, k: int):
@@ -192,7 +217,8 @@ def build_side(batch: ColumnarBatch, key_ordinals: Sequence[int],
     widths = [max(_n_value_words(b), _n_value_words(p))
               for b, p in zip(kcols, probe_key_cols)]
     bucket = kcols[0].bucket if kcols else batch.bucket
-    k = _table_bits(bucket)
+    k = _table_bits(bucket,
+                    probe_key_cols[0].bucket if probe_key_cols else 0)
     key = ("build", tuple(_col_sig(c) for c in kcols), tuple(widths), k)
     def build():
         dtypes = [c.data_type for c in kcols]

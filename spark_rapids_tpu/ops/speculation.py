@@ -10,7 +10,7 @@ sync the query already pays at collect.  If any flag fired, the action
 replays with speculation disabled (exact, sync-per-join sizing).
 
 Who still speculates: a hash join whose probe batch has a bucket at or
-under ``exec/joins.JOIN_SIZED_MIN_BUCKET`` (32,768 rows).  There the guess
+under ``columnar/column.SIZED_MIN_BUCKET`` (32,768 rows).  There the guess
 costs little (the padding of a small bucket is cheap on the device) and
 the round trip would be most of the join.  Above the floor the balance is
 the other way round — on the chip a scalar fetch costs milliseconds and a

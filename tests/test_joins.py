@@ -8,7 +8,7 @@ import pytest
 from spark_rapids_tpu import functions as F
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.aux import tracing
-from spark_rapids_tpu.exec.joins import JOIN_SIZED_MIN_BUCKET as SIZED_FLOOR
+from spark_rapids_tpu.columnar.column import SIZED_MIN_BUCKET as SIZED_FLOOR
 
 from tests.asserts import assert_tpu_and_cpu_are_equal_collect
 
@@ -160,7 +160,7 @@ def test_join_duplicate_key_explosion(sizing, copies, replays):
 
 #: a hash join sizes its pair table and the batch it hands on by what its
 #: probe batch's bucket says (``exec/joins.py``): above
-#: ``JOIN_SIZED_MIN_BUCKET`` by the probe's candidate total, fetched; at or
+#: ``SIZED_MIN_BUCKET`` by the probe's candidate total, fetched; at or
 #: under it speculatively, with no fetch.  Each case: probe rows, matches a
 #: probe row finds (a fraction: the share of rows that find one), and what
 #: the query's summary and the gateway must then read: ``sized_joins``,
@@ -383,7 +383,7 @@ def _pair_program_args(n):
 
     built = J.BuiltSide(
         batch(), (0,),
-        jnp.zeros((1 << J._table_bits(n)) + 1, dtype=np.int32),
+        jnp.zeros((1 << J._table_bits(n, n)) + 1, dtype=np.int32),
         jnp.zeros(n, dtype=np.int32), [1])
     zeros = jnp.zeros(n, dtype=np.int64)
     return batch(), (0,), built, (False,), zeros, zeros, jnp.int64(0)
@@ -539,7 +539,7 @@ def test_join_types_when_nearly_every_candidate_is_false(how, bits,
     or half, of the live build rows: ``verify`` alone decides what joins,
     for every join type, the non-equi condition and null keys."""
     from spark_rapids_tpu.ops import join_ops as J
-    monkeypatch.setattr(J, "_table_bits", lambda bucket: bits)
+    monkeypatch.setattr(J, "_table_bits", lambda bucket, probe: bits)
     assert_tpu_and_cpu_are_equal_collect(
         lambda s: s.create_dataframe(_left_data(), num_partitions=2)
         .join(s.create_dataframe(_right_data(), num_partitions=2), on="k",
@@ -554,11 +554,25 @@ def test_join_types_when_nearly_every_candidate_is_false(how, bits,
     assert tracing.last_query_summary()["speculation_replays"] == 0
 
 
-def test_table_bits_follow_the_build_bucket_alone():
-    from spark_rapids_tpu.ops import join_ops as J
-    assert J._TABLE_LOAD in (4, 8, 16)
-    for bucket in (1024, 1 << 15, 1 << 21, 1 << 24):
-        assert 1 << J._table_bits(bucket) == J._TABLE_LOAD * bucket
+@pytest.mark.parametrize("build, probe, slots", [
+    # the build side alone sets the table where the probe side asks for
+    # no more: _TABLE_LOAD slots a row of its bucket
+    (1024, 1024, 8 << 10), (1 << 15, 1 << 15, 8 << 15),
+    (1 << 17, 1 << 19, 8 << 17), (1 << 21, 1 << 22, 8 << 21),
+    (1 << 24, 1 << 22, 8 << 24),
     # capped: a table is 512 MiB at most, whatever the build side
-    assert J._table_bits(1 << 25) == J._table_bits(1 << 30) \
-        == J._TABLE_MAX_BITS == 24 + J._TABLE_LOAD.bit_length() - 1
+    (1 << 25, 1 << 22, 1 << 27), (1 << 30, 1 << 22, 1 << 27),
+    # a small dimension under the fact table's bucket: _PROBE_LOAD slots
+    # a probe row
+    (1 << 15, 1 << 22, 1 << 23), (1024, 1 << 18, 1 << 19),
+    # the probe's share stops at the largest table whose lookups are flat
+    (1 << 15, 1 << 25, 1 << 23),
+])
+def test_table_bits_follow_the_larger_ask_of_the_two_buckets(
+        build, probe, slots):
+    """False candidates size a large join's pair table and everything
+    above it, so a large probe side widens a small build side's table;
+    a function of the two buckets alone, so of the programs' shapes."""
+    from spark_rapids_tpu.ops import join_ops as J
+    assert J._TABLE_MAX_BITS == 24 + J._TABLE_LOAD.bit_length() - 1
+    assert 1 << J._table_bits(build, probe) == slots

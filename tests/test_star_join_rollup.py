@@ -13,12 +13,12 @@ and a new GEN/MS/ES triple traces no program.
 import pytest
 
 from spark_rapids_tpu.aux import tracing
+from spark_rapids_tpu.columnar.column import SIZED_MIN_BUCKET
 from spark_rapids_tpu.exec import stage_compiler as SC
-from spark_rapids_tpu.exec.joins import JOIN_SIZED_MIN_BUCKET
 
 SEED = 2147493319
 #: store_sales 144,020 rows: the first join probes with a 262,144-row
-#: bucket, well above ``JOIN_SIZED_MIN_BUCKET``, of which a q27 keeps some
+#: bucket, well above ``SIZED_MIN_BUCKET``, of which a q27 keeps some
 #: 200 rows
 SCALE_DOWN = 20
 PARAMS = {
@@ -95,11 +95,11 @@ def test_the_fan_out_runs_at_the_live_rows_size(star):
         assert len(joins) == 4
         for join in joins:
             assert sum(p["padded_rows"] for p in join["partitions"]) \
-                == JOIN_SIZED_MIN_BUCKET
+                == SIZED_MIN_BUCKET
         scan = max(sum(p["padded_rows"] for p in n["partitions"])
                    for n in s["nodes"] if "Scan" in n["node"])
-        assert scan > JOIN_SIZED_MIN_BUCKET
-        assert s["expand_rows_padded"] == 3 * JOIN_SIZED_MIN_BUCKET
+        assert scan > SIZED_MIN_BUCKET
+        assert s["expand_rows_padded"] == 3 * SIZED_MIN_BUCKET
         assert 0 < s["expand_rows_padded"] <= scan
         expand = next(n for n in s["nodes"] if "Expand" in n["node"])
         assert sum(p["padded_rows"] for p in expand["partitions"]) \
@@ -111,14 +111,20 @@ def test_one_sized_join_a_query_and_its_syncs(star, q):
     """At a twentieth of SF1 the first join keeps some 2,000 rows: it is
     the one probe over the floor (one fetch, site ``join-size``), the
     other three probe at the floor's bucket and speculate (their flags
-    cost the collect one check), and the fan-out forces no count."""
+    cost the collect one check), and the fan-out forces no count.  Two
+    filtered dimensions are over the floor, ``customer_demographics``
+    (96,040 rows) and ``date_dim`` (73,049 at every scale), both in a
+    131,072-row bucket: each stage fetches its live count (site
+    ``stage-size``) and hands the join a build side at the floor's
+    bucket."""
     _, _, runs = star
     for run in (r for r in runs if r["q"] == q):
         s = run["summary"]
         assert s["sized_joins"] == 1
-        assert s["pair_rows_padded"] == (1 + 3 * 2) * JOIN_SIZED_MIN_BUCKET
+        assert s["pair_rows_padded"] == (1 + 3 * 2) * SIZED_MIN_BUCKET
         assert s["speculation_replays"] == 0
-        assert s["transitions"]["sync_count"] == 2
+        assert s["sized_stages"] == 2
+        assert s["transitions"]["sync_count"] == 2 + 2
 
 
 def test_a_query_without_grouping_sets_counts_no_fan_out(star):

@@ -99,6 +99,40 @@ def test_compact_batch_sorts_only_what_orders(tpu_branch, rows):
     assert counts and max(counts) <= 2, counts
 
 
+def test_sized_stage_and_its_compact_terminal(tpu_branch):
+    """The star cell's demographic stage at SF1's shapes (2,097,152 rows,
+    a key and three strings, filter only): the stage program that hands
+    on its planes uncompacted with the permutation and the count, and
+    ``fused.compact`` at the floor's bucket."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec.fused import TpuFusedStageExec
+    from spark_rapids_tpu.columnar.column import SIZED_MIN_BUCKET
+    from spark_rapids_tpu.expressions.base import BoundReference
+    from spark_rapids_tpu.expressions.predicates import EqualTo
+    from spark_rapids_tpu.plan.base import LeafExec
+    from spark_rapids_tpu.plan.stages import PromotedLiteral
+    rows = 1 << 21
+    assert rows > SIZED_MIN_BUCKET
+    batch = _batch(rows, T.LONG, T.STRING, T.STRING, T.STRING)
+    values = ("M", "S", "College")
+    stage = TpuFusedStageExec(
+        [("filter", EqualTo(BoundReference(i + 1, T.STRING, True, f"c{i}"),
+                            PromotedLiteral(v, T.STRING, i)))
+         for i, v in enumerate(values)], LeafExec(),
+        promoted=[PromotedLiteral(v, T.STRING, i)
+                  for i, v in enumerate(values)])
+    prog, specs = _compiles(tpu_branch,
+                            lambda: stage._finish(*stage._program(batch)))
+    assert prog.kind == "fused.stage"
+    counts = TC.sort_operand_counts(prog, specs)
+    assert counts and max(counts) <= 2, counts
+    planes = [(c.data, c.validity, c.lengths, c.elem_valid)
+              for c in batch.columns]
+    prog, _ = _compiles(tpu_branch, stage._compact_sized, planes,
+                        jnp.zeros(rows, dtype=np.int32), np.int32(27_440))
+    assert prog.kind == "fused.compact"
+
+
 def test_double_key_sort(tpu_branch):
     from spark_rapids_tpu.ops.sort_ops import SortOrder, sort_gather_batch
     batch = _batch(LARGE, T.DOUBLE, T.LONG, T.STRING)
@@ -124,9 +158,15 @@ def test_join_build_probe_and_pairs(tpu_branch):
     prog, specs = _compiles(tpu_branch, J.build_side, build, (0,),
                             [probe.columns[0]])
     assert max(TC.sort_operand_counts(prog, specs)) <= 2
+    # a dimension at the floor's bucket under the fact table's: the
+    # probe's bucket widens the table to 2^23 slots
+    fact = _batch(1 << 22, T.LONG)
+    _compiles(tpu_branch, J.build_side, _batch(SMALL, T.LONG, T.DOUBLE),
+              (0,), [fact.columns[0]])
     built = J.BuiltSide(
         build, (0,),
-        jnp.zeros((1 << J._table_bits(LARGE)) + 1, dtype=np.int32),
+        jnp.zeros((1 << J._table_bits(LARGE, 1 << 19)) + 1,
+                  dtype=np.int32),
         jnp.zeros(LARGE, dtype=np.int32), [1])
     _compiles(tpu_branch, J._probe_ranges, [probe.columns[0]], built)
     # the pair table under speculative sizing: twice the probe bucket
