@@ -27,9 +27,12 @@ span vocabulary is a contract (docs/observability.md, PERF.md):
 ``plan.parse``, ``plan.analyze``, ``plan.rewrite``, ``exec.run``,
 ``exec.replay``, ``xfer.h2d``, ``xfer.d2h``, ``xfer.sync``,
 ``compile.build``, ``result.rows``, ``device.permit`` (a wait for the
-device semaphore), a served query's ``serve.queue``, ``serve.admit`` and
-``serve.lookup``; annotation only: ``dispatch`` and ``exec.<node name>``
-(one per batch pull).
+device semaphore), ``task.run`` (one partition task), ``exchange.write``
+(a map batch's partition ids, split and store), ``exchange.read`` (a
+reduce partition handed on), ``broadcast.build`` (a broadcast join's build
+side pulled and concatenated, then keyed), a served query's
+``serve.queue``, ``serve.admit`` and ``serve.lookup``; annotation only:
+``dispatch`` and ``exec.<node name>`` (one per batch pull).
 
 Attribution rule: what a query's summary counts (``dispatches``, the
 transition ledger, the task metrics, ``compile_s``) is added to the
@@ -191,7 +194,13 @@ class QueryExecution:
                                          "expand_rows_padded": 0,
                                          "probe_gather_rounds": 0,
                                          "sized_joins": 0,
-                                         "sized_stages": 0}
+                                         "sized_stages": 0,
+                                         "broadcast_builds": 0,
+                                         "exchanges": 0,
+                                         "exchange_rows": 0,
+                                         "exchange_rows_padded": 0,
+                                         "exchange_pieces": 0,
+                                         "exchange_host_staged_bytes": 0}
         #: what this query's own threads did, added by the layer that did
         #: it: steady dispatches (``exec/stage_compiler.py``), the
         #: gateway's ledger (``aux/transitions.py``), its finished tasks'
@@ -877,7 +886,10 @@ class QueryExecution:
                 f"{k}={summary[k]}" for k in
                 ("dispatches", "dispatch_s", "speculation_replays",
                  "pair_rows_padded", "expand_rows_padded",
-                 "probe_gather_rounds", "sized_joins", "sized_stages")
+                 "probe_gather_rounds", "sized_joins", "sized_stages",
+                 "broadcast_builds", "exchanges", "exchange_rows",
+                 "exchange_rows_padded", "exchange_pieces",
+                 "exchange_host_staged_bytes")
                 if k in summary))
         lines.append("== Query Summary ==")
         lines.append(" ".join(
@@ -951,6 +963,36 @@ def span(name: str, **attrs):
             sp.end = time.monotonic()
 
 
+_DRAINED = object()
+
+
+def span_pulls(name: str, it, **attrs):
+    """:func:`span` over the life of an iterator that other code pulls
+    from: ONE span (opened at the first pull, closed when ``it`` is
+    drained or the generator is closed), under which every pull of ``it``
+    runs, each inside the annotation ``srt.<name>``; closing the generator
+    closes ``it``.  Between pulls the thread is the consumer's and runs
+    under the consumer's span, so a ``with span(...)`` around a ``yield``
+    (whose push and pop the consumer could interleave with its own) is
+    never needed."""
+    q = EV.active_query()
+    sp = q.open_span(name, attrs) if q is not None and not q.finished \
+        else None
+    try:
+        while True:
+            with _running_under(name, q, sp, attrs):
+                item = next(it, _DRAINED)
+            if item is _DRAINED:
+                return
+            yield item
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:   # closed early: the source stops now
+            close()
+        if sp is not None:
+            sp.end = time.monotonic()
+
+
 @contextlib.contextmanager
 def timed_span(name: str, into: list, start: Optional[float] = None,
                **attrs):
@@ -996,7 +1038,8 @@ def add_count(name: str, n: int = 1) -> None:
     """Adds to a per-query counter of the active query's summary
     (``speculation_replays``, ``pair_rows_padded``,
     ``expand_rows_padded``, ``probe_gather_rounds``, ``sized_joins``,
-    ``sized_stages``), where the work happens."""
+    ``sized_stages``, ``broadcast_builds``, ``exchanges`` and the
+    exchange's ``exchange_*``), where the work happens."""
     q = EV.active_query()
     if q is not None:
         q.add_count(name, n)

@@ -660,6 +660,16 @@ EXCHANGE_REUSE_ENABLED = conf_bool(
     "GpuOverrides.scala:4589).",
     True)
 
+AUTO_BROADCAST_JOIN_THRESHOLD = conf_bytes(
+    "spark.sql.autoBroadcastJoinThreshold",
+    "Largest estimated size of a join side that is broadcast to every "
+    "task of the other side instead of hash-exchanging both (Spark's key "
+    "and default; -1 plans no broadcast join by size).  The estimate is "
+    "the bytes of an in-memory relation's referenced columns or a file "
+    "scan's file sizes, unchanged through a filter and scaled by row width "
+    "through a projection (plan/join_selection.py, docs/distributed.md).",
+    "10485760")
+
 ADAPTIVE_COALESCE_ENABLED = conf_bool(
     "spark.sql.adaptive.coalescePartitions.enabled",
     "Post-shuffle adaptive partition coalescing from materialized sizes "

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from spark_rapids_tpu.aux.tracing import add_count, span, span_pulls
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, HostColumnarBatch
 from spark_rapids_tpu.plan.base import Exec, UnaryExec
 from spark_rapids_tpu.plan.partitioning import (Partitioning,
@@ -52,6 +53,69 @@ def _sample_bounds(part: RangePartitioning, sample_rows, to_host_batch):
     rows = [sorted_sample.slice(i, 1) for i in idx]
     from spark_rapids_tpu.columnar.batch import concat_host_batches as cc
     return cc(rows) if rows else HostColumnarBatch([], 0, [])
+
+
+def _note_written(pieces: int, rows: int, padded: int) -> None:
+    """What a map task stored or staged, on the active query's summary:
+    pieces, their live rows, and their rows with the padding."""
+    add_count("exchange_pieces", pieces)
+    add_count("exchange_rows", rows)
+    add_count("exchange_rows_padded", padded)
+
+
+def partition_masks(part: Partitioning, batch: ColumnarBatch, n: int):
+    """One keep-mask a reduce partition over ``batch``'s rows: the
+    partition ids and their comparison with each partition as ONE program
+    (kind ``exchange.pid``).  Padding rows carry the id ``n`` and are in
+    no mask."""
+    from spark_rapids_tpu.columnar.column import (DeferredCount,
+                                                  DeviceColumn, _jnp,
+                                                  rc_traceable)
+    from spark_rapids_tpu.columnar.encoding import (batch_has_encoded,
+                                                    materialize_batch)
+    from spark_rapids_tpu.exec.stage_compiler import get_or_build
+    from spark_rapids_tpu.ops.batch_ops import _col_sig
+    from spark_rapids_tpu.plan.pruning import _refs
+    jnp = _jnp()
+    if batch_has_encoded(batch):
+        # the ids hash values, not codes: decode the columns they read
+        refs: set = set()
+        for e in part.exprs:
+            _refs(e, refs)
+        batch = materialize_batch(batch, ordinals=sorted(refs),
+                                  site="operator")
+    src, more = part.pid_inputs(batch, "exchange.pid")
+
+    def sig(b):
+        return tuple((str(c.data_type),) + _col_sig(c) for c in b.columns)
+
+    def planes(b):
+        return [(c.data, c.validity, c.lengths, c.elem_valid)
+                for c in b.columns]
+
+    def rebuilt(like_types, arrs, rc):
+        cols = [DeviceColumn(d, v, rc, dt, lengths=ln, elem_valid=ev)
+                for (d, v, ln, ev), dt in zip(arrs, like_types)]
+        return ColumnarBatch(cols, rc)
+
+    types = [c.data_type for c in src.columns]
+    more_types = [[c.data_type for c in m.columns] for m in more]
+    more_rows = [int(m.row_count) for m in more]    # host batches uploaded
+    key = (part.program_key(), n, sig(src),
+           tuple((r, sig(m)) for r, m in zip(more_rows, more)))
+
+    def build():
+        def run(arrs, rc, more_arrs):
+            pids = part.pids_from(
+                rebuilt(types, arrs, DeferredCount(rc)),
+                *[rebuilt(t, a, r)
+                  for t, a, r in zip(more_types, more_arrs, more_rows)])
+            return tuple(pids == p for p in range(n))
+        return run
+
+    fn = get_or_build("exchange.pid", key, build)
+    return fn(planes(src), jnp.asarray(rc_traceable(src.row_count)),
+              [planes(m) for m in more])
 
 
 #: defaults for the round-5 shuffle knobs; the convert-time conf values
@@ -220,12 +284,17 @@ class CpuShuffleExchangeExec(UnaryExec):
         # release now, not at GC
         with closing_source(self.child.execute_partition(mp)) as it:
             for hb in it:
-                pids = part.partition_ids_cpu(hb)
-                yield from self._split_pairs(hb, pids, n)
+                with span("exchange.write", map=mp):
+                    pids = part.partition_ids_cpu(hb)
+                    pairs = self._split_pairs(hb, pids, n)
+                    rows = sum(sub.row_count for _, sub in pairs)
+                    _note_written(len(pairs), rows, rows)
+                yield from pairs
 
     def _materialize(self):
         if self._store is not None:
             return
+        add_count("exchanges", 1)
         part = self.partitioning
         n = part.num_partitions
         if isinstance(part, RangePartitioning) and part.bounds is None:
@@ -406,7 +475,8 @@ class CpuShuffleExchangeExec(UnaryExec):
             with self._exec_lock:
                 self._materialize()
         self._prefetch_next(pidx)
-        yield from self._store[pidx]
+        yield from span_pulls("exchange.read", iter(self._store[pidx]),
+                              partition=pidx)
 
     def _prefetch_next(self, pidx: int) -> None:
         """Pipelined shuffle read: while this reduce partition streams to
@@ -516,35 +586,35 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
         env = self.shuffle_env or get_shuffle_env()
         mode = env.mode if env is not None else "DEFAULT"
         part = self.partitioning
-        if mode == "DEFAULT":
-            ctx = self._collective_eligible(part)
-            if ctx is not None:
-                from spark_rapids_tpu.parallel.spmd import SpmdHbmExceeded
-                from spark_rapids_tpu.plan.base import _is_retryable
-                try:
-                    self._materialize_collective(ctx)
-                    return
-                except Exception as e:   # noqa: BLE001 - classified below
-                    if not (_is_retryable(e) or
-                            isinstance(e, SpmdHbmExceeded)):
-                        raise
-                    # per-stage ICI-vs-host choice: a working set that
-                    # cannot fit per-device HBM (SpmdHbmExceeded) takes
-                    # the host-staged spillable path; a lost chip fails
-                    # the whole collective step and degrades the same
-                    # way (Theseus-style: finish the plan when a
-                    # participant dies mid-shuffle)
-                    from spark_rapids_tpu.aux.events import emit
-                    from spark_rapids_tpu.aux.faults import note_recovery
-                    note_recovery("collective_fallbacks")
-                    emit("collectiveFallback",
-                         reason=("hbm" if isinstance(e, SpmdHbmExceeded)
-                                 else "fault"),
-                         error=f"{type(e).__name__}: {e}"[:160])
-                    self._collective = None
         if mode != "DEFAULT":
             super()._materialize()
             return
+        add_count("exchanges", 1)
+        ctx = self._collective_eligible(part)
+        if ctx is not None:
+            from spark_rapids_tpu.parallel.spmd import SpmdHbmExceeded
+            from spark_rapids_tpu.plan.base import _is_retryable
+            try:
+                self._materialize_collective(ctx)
+                return
+            except Exception as e:   # noqa: BLE001 - classified below
+                if not (_is_retryable(e) or
+                        isinstance(e, SpmdHbmExceeded)):
+                    raise
+                # per-stage ICI-vs-host choice: a working set that
+                # cannot fit per-device HBM (SpmdHbmExceeded) takes
+                # the host-staged spillable path; a lost chip fails
+                # the whole collective step and degrades the same
+                # way (Theseus-style: finish the plan when a
+                # participant dies mid-shuffle)
+                from spark_rapids_tpu.aux.events import emit
+                from spark_rapids_tpu.aux.faults import note_recovery
+                note_recovery("collective_fallbacks")
+                emit("collectiveFallback",
+                     reason=("hbm" if isinstance(e, SpmdHbmExceeded)
+                             else "fault"),
+                     error=f"{type(e).__name__}: {e}"[:160])
+                self._collective = None
         if isinstance(part, RangePartitioning) and part.bounds is None:
             self._compute_bounds()
         n = part.num_partitions
@@ -555,12 +625,13 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
             store[0].extend(self.child.execute_all())
             self._store = store
             return
+        from spark_rapids_tpu.columnar.column import (force_counts,
+                                                      learn_count)
+        from spark_rapids_tpu.columnar.encoding import materialize_rle_batch
         from spark_rapids_tpu.ops.batch_ops import (compact_batch,
                                                     shrink_batch)
-        from spark_rapids_tpu.columnar.column import _jnp, rc_traceable
         from spark_rapids_tpu.plan.base import (iter_partition_tasks,
                                                 run_task_iter)
-        jnp = _jnp()
         # HBM guard: the device-resident store keeps one full-bucket
         # compacted copy of every map batch PER reduce partition (~n x
         # input bytes).  When that estimate crosses the free-HBM budget,
@@ -569,6 +640,8 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
         # need to know to flip spark.rapids.shuffle.mode=MULTITHREADED).
         budget = self._device_store_budget()
         state = {"stored_estimate": 0, "host_staging": False}
+        #: (a map batch's count, its device pieces), for the counts below
+        split: List = []
         state_lock = __import__("threading").Lock()
 
         #: only batches whose n-fold padded footprint is material get the
@@ -618,18 +691,34 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
                             state["host_staging"] = True
                     staging = state["host_staging"]
                 if staging:
-                    yield from self._slice_host_pairs(b, p_eff, n)
+                    yield from self._slice_host_pairs(b, p_eff, n, mp)
                     continue
-                pids = p_eff.partition_ids_tpu(b)
-                rowpos = jnp.arange(b.bucket)
-                inrow = rowpos < rc_traceable(b.row_count)
-                for p in range(n):
-                    yield p, compact_batch(b, (pids == p) & inrow)
+                with span("exchange.write", map=mp):
+                    b = materialize_rle_batch(b)
+                    masks = partition_masks(p_eff, b, n)
+                    pieces = [(p, compact_batch(b, masks[p],
+                                                kind="exchange.split"))
+                              for p in range(n)]
+                with state_lock:
+                    split.append((b.row_count, [sub for _, sub in pieces]))
+                yield from pieces
 
         for p, sub in iter_partition_tasks(
                 lambda mp: run_task_iter(map_gen, mp),
                 self.child.num_partitions):
             store[p].append(sub)
+        # the exchange is a materialization boundary: what its device
+        # pieces hold is learned here with ONE fetch for all of them (the
+        # counts of the host-staged ones came with their download), so
+        # the summary's counters, the map side's own row counts and every
+        # reader above (the adaptive reader's sizes) see live rows and
+        # not buckets
+        device = [sub for _, subs in split for sub in subs]
+        force_counts([sub.row_count for sub in device])
+        for count, subs in split:
+            learn_count(count, sum(int(sub.row_count) for sub in subs))
+        _note_written(len(device), sum(int(sub.row_count) for sub in device),
+                      sum(sub.bucket for sub in device))
         self._store = store
 
     def _device_store_budget(self):
@@ -640,9 +729,18 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
             free_device_headroom
         return free_device_headroom(2)
 
-    def _slice_host_pairs(self, b, part, n):
+    def _slice_host_pairs(self, b, part, n, mp=None):
         """One device batch -> (pid, host slice) pairs via the device
         sort-by-pid writer (the _map_pairs core, batch-wise)."""
+        with span("exchange.write", map=mp, staged="host"):
+            pairs = list(self._sliced_on_host(b, part, n))
+            rows = sum(hb.row_count for _, hb in pairs)
+            _note_written(len(pairs), rows, rows)
+            add_count("exchange_host_staged_bytes",
+                      sum(hb.nbytes() for _, hb in pairs))
+        yield from pairs
+
+    def _sliced_on_host(self, b, part, n):
         from spark_rapids_tpu.columnar.column import DeviceColumn, _jnp
         from spark_rapids_tpu.ops.batch_ops import gather_batch
         from spark_rapids_tpu.ops.sort_ops import SortOrder, sort_permutation
@@ -679,11 +777,16 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
             ctx, cols, counts, schema = self._collective
             yield C.shard_to_batch(ctx, cols, counts, schema, pidx)
             return
-        from spark_rapids_tpu.columnar.batch import ColumnarBatch as _CB
+        yield from span_pulls("exchange.read", self._stored(pidx),
+                              partition=pidx)
+
+    def _stored(self, pidx):
+        """A reduce partition's pieces: those on the device as they are,
+        then the host-staged ones, uploaded."""
         from spark_rapids_tpu.exec.basic import upload_batches
         host_pending = []
         for b in self._store[pidx]:
-            if isinstance(b, _CB):
+            if isinstance(b, ColumnarBatch):
                 yield b
             else:
                 host_pending.append(b)
@@ -700,7 +803,7 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
             part = RoundRobinPartitioning(n, start=mp)
         with closing_source(self.child.execute_partition(mp)) as it:
             for b in it:
-                yield from self._slice_host_pairs(b, part, n)
+                yield from self._slice_host_pairs(b, part, n, mp)
 
     def _compute_bounds(self):
         self._compute_bounds_tpu()

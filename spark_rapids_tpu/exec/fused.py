@@ -45,7 +45,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import (DeferredCount, DeviceColumn,
                                               rc_traceable)
 from spark_rapids_tpu.expressions.base import EvalContext, Expression, TCol, \
-    valid_array
+    expr_key, valid_array
 from spark_rapids_tpu.plan.base import Exec, UnaryExec, closing_source
 
 
@@ -62,10 +62,9 @@ def _ops_signature(ops: Sequence[StageOp]) -> Tuple:
     sig = []
     for kind, payload in ops:
         if kind == "filter":
-            sig.append(("F", payload.sql(), str(payload.data_type)))
+            sig.append(("F",) + expr_key(payload))
         else:
-            sig.append(("P", tuple((e.sql(), str(e.data_type))
-                                   for e in payload)))
+            sig.append(("P", tuple(expr_key(e) for e in payload)))
     return tuple(sig)
 
 
@@ -371,8 +370,7 @@ class TpuFusedAggExec(UnaryExec, _PromotedLiteralsMixin):
         # code outputs are only meaningful against their dictionary
         key_dicts = self._key_dicts(enc, all_upd[:nk0])
         key = (_ops_signature(self.ops), _batch_signature(b), b.bucket,
-               tuple((e.sql(), str(e.data_type))
-                     for e in lay.update_input_exprs()),
+               tuple(expr_key(e) for e in lay.update_input_exprs()),
                tuple((o, k, cv, str(dt))
                      for o, k, cv, dt in lay.update_specs()),
                lay.num_keys,
@@ -520,7 +518,7 @@ class TpuFusedAggExec(UnaryExec, _PromotedLiteralsMixin):
         key = ("mergefinal", tuple(_batch_signature(b) for b in partials),
                tuple(b.bucket for b in partials), nk,
                tuple((o, k, cv, str(dt)) for o, k, cv, dt in merge_specs),
-               tuple((e.sql(), str(e.data_type)) for e in final_exprs),
+               tuple(expr_key(e) for e in final_exprs),
                tuple(None if d is None else d.fingerprint
                      for d in enc_dicts))
         def build():

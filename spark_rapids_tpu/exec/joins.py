@@ -38,13 +38,14 @@ shuffled hash join (GpuSortMergeJoinMeta.scala); we always plan hash joins.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.aux import transitions as TR
-from spark_rapids_tpu.aux.tracing import add_count
+from spark_rapids_tpu.aux.tracing import add_count, span
 from spark_rapids_tpu.columnar import column as COL
 from spark_rapids_tpu.columnar.batch import (ColumnarBatch, HostColumnarBatch,
                                              batch_from_arrow,
@@ -347,6 +348,27 @@ def _chain_then_close(consumed, it):
         close_iter(it)
 
 
+@contextlib.contextmanager
+def _build_once(lock, **attrs):
+    """Holds a broadcast join's build lock, under a ``broadcast.build``
+    span (``None``: the join of one partition pair, whose build cache no
+    other task sees: no lock, no span).  A task that has to wait for the
+    lock drops its device admission first: the builder may need the
+    permit."""
+    if lock is None:
+        yield
+        return
+    if not lock.acquire(blocking=False):
+        from spark_rapids_tpu.plan.base import release_semaphore_for_wait
+        release_semaphore_for_wait()
+        lock.acquire()
+    try:
+        with span("broadcast.build", **attrs):
+            yield
+    finally:
+        lock.release()
+
+
 class _TpuJoinCore(_JoinBase):
     """Streamed probe vs built side on device (see module docstring)."""
 
@@ -404,6 +426,22 @@ class _TpuJoinCore(_JoinBase):
         # pair table rows map 1:1 to pair positions (same bucket)
         return keep & ok[:keep.shape[0]]
 
+    @staticmethod
+    def _concat_build(build_batches: List[ColumnarBatch],
+                      schema: T.StructType) -> ColumnarBatch:
+        """The build side as one batch."""
+        from spark_rapids_tpu.columnar import encoding as ENC
+        from spark_rapids_tpu.ops.batch_ops import concat_batches
+        build_batches = [ENC.materialize_rle_batch(b, site="join")
+                         for b in build_batches
+                         if not _known_empty(b.row_count)]
+        build = concat_batches(build_batches) if build_batches else \
+            _empty_device(schema)
+        # concat_batches passes a single input through unchanged —
+        # never mutate it (it may be a shared/cached batch); rewrap
+        # to drop names instead
+        return ColumnarBatch(build.columns, build.row_count)
+
     def _join_device(self, probe_batches: Iterator[ColumnarBatch],
                      build_batches: List[ColumnarBatch],
                      build_cache: Optional[dict] = None,
@@ -420,27 +458,22 @@ class _TpuJoinCore(_JoinBase):
         tables on the build side in star queries).  Output column order
         stays left-then-right via argument swap at gather time."""
         from spark_rapids_tpu.columnar import encoding as ENC
-        from spark_rapids_tpu.ops.batch_ops import concat_batches
         jt = self.join_type
         names = self._out_names
         ls, rs = self.left.schema, self.right.schema
         probe_keys = self.right_keys if swapped else self.left_keys
         build_keys = self.left_keys if swapped else self.right_keys
         cache = build_cache if build_cache is not None else {}
+        # a broadcast join's cache is shared by its probe tasks: what a
+        # task finds missing it builds under the join's lock, so sibling
+        # tasks that arrive together build once and not once each
+        lock = cache.get("lock")
         use_hash = bool(self.left_keys) and jt != J.CROSS
         if "build" in cache:
             build = cache["build"]
         else:
-            build_batches = [ENC.materialize_rle_batch(b, site="join")
-                             for b in build_batches
-                             if not _known_empty(b.row_count)]
-            build = concat_batches(build_batches) if build_batches else \
-                _empty_device(ls if swapped else rs)
-            # concat_batches passes a single input through unchanged —
-            # never mutate it (it may be a shared/cached batch); rewrap
-            # to drop names instead
-            build = ColumnarBatch(build.columns, build.row_count)
-            cache["build"] = build
+            build = cache["build"] = self._concat_build(
+                build_batches, ls if swapped else rs)
         build_key_dicts = ENC.join_key_dicts(build, build_keys) \
             if use_hash else []
         # augmented build sides keyed by the code-space signature (one
@@ -466,21 +499,25 @@ class _TpuJoinCore(_JoinBase):
                                               probe_dicts)]
                 enc_sig = tuple(None if d is None else d.fingerprint
                                 for d in enc_keys)
-                entry = aug_cache.get(enc_sig)
-                if entry is None:
-                    entry = (self._augment_keys(build, build_keys,
-                                                enc_keys), {})
-                    aug_cache[enc_sig] = entry
-                (build_aug, build_ords), built_by_widths = entry
                 probe_aug, probe_ords = self._augment_keys(probe,
                                                            probe_keys,
                                                            enc_keys)
                 pk = [probe_aug.columns[i] for i in probe_ords]
                 wkey = tuple(J._n_value_words(c) for c in pk)
-                built = built_by_widths.get(wkey)
-                if built is None:
-                    built = J.build_side(build_aug, build_ords, pk)
-                    built_by_widths[wkey] = built
+                entry = aug_cache.get(enc_sig)
+                if entry is None or wkey not in entry[1]:
+                    with _build_once(lock, step="key"):
+                        entry = aug_cache.get(enc_sig)
+                        if entry is None:
+                            entry = (self._augment_keys(build, build_keys,
+                                                        enc_keys), {})
+                            aug_cache[enc_sig] = entry
+                        if wkey not in entry[1]:
+                            (build_aug, build_ords), _ = entry
+                            entry[1][wkey] = J.build_side(build_aug,
+                                                          build_ords, pk)
+                (build_aug, build_ords), built_by_widths = entry
+                built = built_by_widths[wkey]
                 lo, counts, offsets, total = J._probe_ranges(pk, built)
                 # the gathers a probe row made to find its range
                 add_count("probe_gather_rounds", J.PROBE_GATHER_ROUNDS)
@@ -698,15 +735,15 @@ class CpuBroadcastHashJoinExec(_CpuJoinCore):
         if getattr(self, "_built_host", None) is None:
             # concurrent probe tasks must not double-build; drop device
             # admission before blocking on the lock (the builder may need it)
-            from spark_rapids_tpu.plan.base import release_semaphore_for_wait
-            release_semaphore_for_wait()
-            with self._exec_lock:
+            with _build_once(self._exec_lock, step="pull",
+                             partitions=self.right.num_partitions):
                 if getattr(self, "_built_host", None) is None:
                     bs = []
                     for p in range(self.right.num_partitions):
                         bs.extend(self.right.execute_partition(p))
                     self._built_host = _concat_or_empty(bs,
                                                         self.right.schema)
+                    add_count("broadcast_builds", 1)
         return self._built_host
 
     def execute_partition(self, pidx):
@@ -724,24 +761,27 @@ class TpuBroadcastHashJoinExec(_TpuJoinCore):
 
     def execute_partition(self, pidx):
         # the build cache persists across probe partitions: the broadcast
-        # side is concatenated, keyed, and hash-sorted exactly once; the
-        # population is locked against concurrent probe tasks (admission
-        # dropped first so the builder can acquire it)
-        if getattr(self, "_build_cache", None) is None or \
-                "batches" not in self._build_cache:
-            from spark_rapids_tpu.plan.base import release_semaphore_for_wait
-            release_semaphore_for_wait()
-            with self._exec_lock:
-                if getattr(self, "_build_cache", None) is None:
-                    self._build_cache = {}
-                if "batches" not in self._build_cache:
+        # side is pulled and concatenated exactly once, by the first probe
+        # task to arrive, and keyed and hash-sorted once for each key
+        # layout a probe presents (``_join_device``), both under the
+        # join's lock: sibling tasks that arrive together wait for the one
+        # build (admission dropped first so the builder can acquire it)
+        cache = getattr(self, "_build_cache", None)
+        if cache is None or "build" not in cache:
+            with _build_once(self._exec_lock, step="pull",
+                             partitions=self.right.num_partitions):
+                cache = getattr(self, "_build_cache", None)
+                if cache is None:
+                    cache = self._build_cache = {"lock": self._exec_lock}
+                if "build" not in cache:
                     bs = []
                     for p in range(self.right.num_partitions):
                         bs.extend(self.right.execute_partition(p))
-                    self._build_cache["batches"] = bs
-        cache = self._build_cache
+                    cache["build"] = self._concat_build(bs,
+                                                        self.right.schema)
+                    add_count("broadcast_builds", 1)
         yield from self._join_device(self.left.execute_partition(pidx),
-                                     cache["batches"], cache)
+                                     [], cache)
 
 
 class CpuBroadcastNestedLoopJoinExec(CpuBroadcastHashJoinExec):

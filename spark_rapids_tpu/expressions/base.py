@@ -318,6 +318,17 @@ class BoundReference(Expression):
     eval_cpu = eval_tpu
 
 
+def expr_key(e: Expression) -> tuple:
+    """What identifies a bound expression to a program cache: its text,
+    its type and the ordinals its references read.  The text alone does
+    not: a named reference renders as its name, and two inputs of one
+    shape may hold that name at two ordinals (``select l.k, v, w`` over
+    ``l`` joined to ``r``, and over ``r`` joined to ``l``)."""
+    return (e.sql(), str(e.data_type),
+            tuple(b.ordinal for b in
+                  e.collect(lambda n: isinstance(n, BoundReference))))
+
+
 class AttributeReference(Expression):
     """Named column reference, resolved to BoundReference at bind time."""
 

@@ -20,7 +20,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, HostColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn, HostColumn
 from spark_rapids_tpu.expressions.base import (EvalContext, Expression, TCol,
-                                               valid_array)
+                                               expr_key, valid_array)
 
 
 # ---------------------------------------------------------------------------
@@ -152,9 +152,10 @@ def _signature(exprs, batch: ColumnarBatch) -> Tuple:
          None if c.lengths is None else True,
          None if c.elem_valid is None else True)
         for c in batch.columns)
-    # sql() alone under-identifies (e.g. lit(1, INT) vs lit(1, LONG) both
-    # render "1"), so the output dtype participates in the key
-    return (tuple((e.sql(), str(e.data_type)) for e in exprs), shape_sig)
+    # sql() alone under-identifies (lit(1, INT) and lit(1, LONG) both
+    # render "1"; a named reference renders its name at any ordinal), so
+    # the output dtype and the ordinals read participate in the key
+    return (tuple(expr_key(e) for e in exprs), shape_sig)
 
 
 def eval_exprs_tpu(exprs: Sequence[Expression], batch: ColumnarBatch,

@@ -153,8 +153,11 @@ def compact_planes(arrs, keep, jnp):
                              keep.shape[0], jnp), cnt
 
 
-def compact_batch(batch: ColumnarBatch, keep) -> ColumnarBatch:
+def compact_batch(batch: ColumnarBatch, keep,
+                  kind: str = "batch.compact") -> ColumnarBatch:
     """Moves kept rows to the front (stable), returns batch with new count.
+    ``kind`` names the program for the stage compiler's counters and the
+    device trace (the exchange's split is ``exchange.split``).
     Dictionary code planes compact like any int plane (the encoding
     survives — late materialization); RLE materializes first.
 
@@ -175,7 +178,7 @@ def compact_batch(batch: ColumnarBatch, keep) -> ColumnarBatch:
 
         return run
     from spark_rapids_tpu.exec.stage_compiler import get_or_build
-    fn = get_or_build("batch.compact", key, build)
+    fn = get_or_build(kind, key, build)
     arrs = [(c.data, c.validity, c.lengths, c.elem_valid)
             for c in batch.columns]
     outs, cnt = fn(arrs, keep)

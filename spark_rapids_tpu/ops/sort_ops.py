@@ -38,6 +38,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
+from spark_rapids_tpu.expressions.base import expr_key
 
 
 def _jx():
@@ -328,7 +329,7 @@ def sort_gather_batch(batch: ColumnarBatch, orders: Sequence[SortOrder],
     key_exprs = list(key_exprs or ())
     key = ("sortgather", tuple(_col_sig(c) for c in batch.columns),
            tuple((c.elem_valid is not None) for c in batch.columns),
-           orders, tuple((e.sql(), str(e.data_type)) for e in key_exprs),
+           orders, tuple(expr_key(e) for e in key_exprs),
            batch.bucket)
 
     def build():

@@ -321,7 +321,8 @@ def run_task_iter(gen_fn, pidx: int):
         from spark_rapids_tpu.aux.faults import maybe_fire
         maybe_fire("task.run")
         arb.register_task(task_id)
-        it = gen_fn(pidx)
+        from spark_rapids_tpu.aux.tracing import span_pulls
+        it = span_pulls("task.run", gen_fn(pidx), partition=pidx)
         try:
             for item in it:
                 arb.note_progress(task_id)

@@ -19,7 +19,8 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, HostColumnarBatch
-from spark_rapids_tpu.expressions.base import EvalContext, Expression
+from spark_rapids_tpu.expressions.base import (EvalContext, Expression,
+                                               expr_key)
 
 
 class Partitioning:
@@ -33,6 +34,22 @@ class Partitioning:
     def partition_ids_tpu(self, batch: ColumnarBatch):
         """int32[bucket] pid per row (padding rows get num_partitions)."""
         raise NotImplementedError
+
+    # -- the same ids as ONE traced program (exec/exchange.py) --------------
+    def program_key(self) -> tuple:
+        """What the ids depend on beside the shapes of ``pid_inputs``."""
+        return (type(self).__name__, self.num_partitions,
+                tuple(expr_key(e) for e in self.exprs))
+
+    def pid_inputs(self, batch: ColumnarBatch, kind: str = "expr.project"):
+        """``(batch, further device batches)`` that :meth:`pids_from`
+        computes the ids from; a program dispatched to make them goes
+        under ``kind``."""
+        return batch, ()
+
+    def pids_from(self, batch: ColumnarBatch, *more):
+        """:meth:`partition_ids_tpu` over :meth:`pid_inputs`; traceable."""
+        return self.partition_ids_tpu(batch)
 
     def partition_ids_cpu(self, batch: HostColumnarBatch) -> np.ndarray:
         raise NotImplementedError
@@ -105,6 +122,9 @@ class RoundRobinPartitioning(Partitioning):
         self.num_partitions = n
         self.start = start
 
+    def program_key(self):
+        return super().program_key() + (self.start,)
+
     def partition_ids_tpu(self, batch):
         from spark_rapids_tpu.columnar.column import _jnp
         jnp = _jnp()
@@ -138,11 +158,13 @@ class RangePartitioning(Partitioning):
         return [s.expr for s in self.specs]
 
     # -- key normalization (shared with the device sort) --------------------
-    def _key_batch_tpu(self, batch: ColumnarBatch) -> ColumnarBatch:
+    def _key_batch_tpu(self, batch: ColumnarBatch,
+                       kind: str = "expr.project") -> ColumnarBatch:
         from spark_rapids_tpu.expressions.base import Alias
         from spark_rapids_tpu.expressions.evaluator import eval_exprs_tpu
         return eval_exprs_tpu(
-            [Alias(s.expr, f"k{i}") for i, s in enumerate(self.specs)], batch)
+            [Alias(s.expr, f"k{i}") for i, s in enumerate(self.specs)], batch,
+            kind=kind)
 
     def _key_batch_cpu(self, batch: HostColumnarBatch) -> HostColumnarBatch:
         from spark_rapids_tpu.expressions.evaluator import (eval_exprs_cpu,)
@@ -183,30 +205,43 @@ class RangePartitioning(Partitioning):
         return (ColumnarBatch(ac, a.row_count, a.names),
                 ColumnarBatch(bc, b.row_count, b.names))
 
+    def program_key(self):
+        return super().program_key() + tuple(
+            (s.ascending, s.effective_nulls_first) for s in self.specs)
+
+    def pid_inputs(self, batch, kind: str = "expr.project"):
+        assert self.bounds is not None, "bounds not computed"
+        keys = self._key_batch_tpu(batch, kind)
+        if self.bounds.row_count == 0:
+            return keys, ()
+        return keys, (self.bounds.to_device(),)
+
     def partition_ids_tpu(self, batch):
+        return self.pids_from(*self.pid_inputs(batch))
+
+    def pids_from(self, keys, bnd=None):
+        """The ids from the evaluated key columns and the bounds' (n-1
+        rows, a count the host knows; none: every row to partition 0)."""
         from spark_rapids_tpu.columnar.column import _jnp
         jnp = _jnp()
-        assert self.bounds is not None, "bounds not computed"
-        keys = self._key_batch_tpu(batch)
-        pos = jnp.arange(batch.bucket, dtype=np.int32)
-        if self.bounds.row_count == 0:
-            return jnp.where(pos < batch.row_count, 0,
-                             self.num_partitions).astype(np.int32)
-        keys, bnd = self._align_widths(keys, self.bounds.to_device(), jnp)
+        bucket = keys.bucket
+        live = jnp.arange(bucket, dtype=np.int32) < keys.row_count
+        if bnd is None:
+            return jnp.where(live, 0, self.num_partitions).astype(np.int32)
+        keys, bnd = self._align_widths(keys, bnd, jnp)
         row_words = self._norm_words(keys, jnp)
         bound_words = self._norm_words(bnd, jnp)
-        pid = jnp.zeros(batch.bucket, dtype=np.int32)
-        for j in range(self.bounds.row_count):
+        pid = jnp.zeros(bucket, dtype=np.int32)
+        for j in range(int(bnd.row_count)):
             # lexicographic row > bound_j
-            gt = jnp.zeros(batch.bucket, dtype=bool)
-            eq = jnp.ones(batch.bucket, dtype=bool)
+            gt = jnp.zeros(bucket, dtype=bool)
+            eq = jnp.ones(bucket, dtype=bool)
             for rw, bw in zip(row_words, bound_words):
                 bj = bw[j]
                 gt = gt | (eq & (rw > bj))
                 eq = eq & (rw == bj)
             pid = pid + gt.astype(np.int32)
-        return jnp.where(pos < batch.row_count, pid,
-                         self.num_partitions).astype(np.int32)
+        return jnp.where(live, pid, self.num_partitions).astype(np.int32)
 
     def partition_ids_cpu(self, batch):
         # genuinely host-side: numpy twin of the device word normalization

@@ -670,12 +670,10 @@ class DataFrame:
         Wrap the right side in functions.broadcast() to force a broadcast
         hash join (reference: GpuBroadcastHashJoinExec rule)."""
         from spark_rapids_tpu.exec.joins import (
-            CpuBroadcastHashJoinExec, CpuBroadcastNestedLoopJoinExec,
-            CpuShuffledHashJoinExec, _normalize_how)
-        from spark_rapids_tpu.exec.exchange import CpuShuffleExchangeExec
+            CpuBroadcastNestedLoopJoinExec, _normalize_how)
         from spark_rapids_tpu.expressions.base import BoundReference
         from spark_rapids_tpu.expressions.conditional import Coalesce
-        from spark_rapids_tpu.plan.partitioning import HashPartitioning
+        from spark_rapids_tpu.plan.join_selection import plan_equi_join
         import spark_rapids_tpu.ops.join_ops as J
         jt = _normalize_how(how)
         lplan, rplan = self._plan, other._plan
@@ -696,22 +694,9 @@ class DataFrame:
         lkeys = [bind_references(col(n), lschema) for n in names]
         rkeys = [bind_references(col(n), rschema) for n in names]
         ns = [null_safe] * len(names)
-        broadcastable = getattr(other, "_broadcast_hint", False) and \
-            jt in (J.INNER, J.LEFT_OUTER, J.LEFT_SEMI, J.LEFT_ANTI)
-        if broadcastable:
-            plan = CpuBroadcastHashJoinExec(lkeys, rkeys, jt, cond, lplan,
-                                            rplan, ns)
-        else:
-            nparts = max(lplan.num_partitions, rplan.num_partitions)
-            if nparts > 1:
-                env = self._session.shuffle_env
-                lplan = CpuShuffleExchangeExec(
-                    HashPartitioning(lkeys, nparts), lplan, shuffle_env=env)
-                rplan = CpuShuffleExchangeExec(
-                    HashPartitioning(rkeys, nparts), rplan, shuffle_env=env)
-                # keys bind identically post-shuffle (same child schema)
-            plan = CpuShuffledHashJoinExec(lkeys, rkeys, jt, cond, lplan,
-                                           rplan, ns)
+        plan = plan_equi_join(
+            self._session, lplan, rplan, lkeys, rkeys, jt, cond, ns,
+            broadcast_right_hint=getattr(other, "_broadcast_hint", False))
         df = DataFrame(plan, self._session)
         if jt in (J.LEFT_SEMI, J.LEFT_ANTI):
             return df

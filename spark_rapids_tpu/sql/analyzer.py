@@ -243,6 +243,30 @@ def _count_table_refs(node, name: str, skip=None) -> int:
     return cnt
 
 
+def _referenced_names(q: A.Select) -> Optional[set]:
+    """Lower-case names of the columns a SELECT's text refers to anywhere
+    (its subqueries included), or ``None`` where a ``*`` in a select list
+    refers to all of them.  Names only: a name two relations share counts
+    for both, which errs towards the larger estimate."""
+    import dataclasses as _dc
+    names: set = set()
+    stack: list = [q]
+    while stack:
+        x = stack.pop()
+        if isinstance(x, A.ColumnRef):
+            names.add(x.name.lower())
+        elif isinstance(x, A.Select) and \
+                any(isinstance(p, A.Star) for p in x.projections):
+            return None
+        elif isinstance(x, A.Join) and x.using:
+            names.update(n.lower() for n in x.using)
+        if _dc.is_dataclass(x) and not isinstance(x, type):
+            stack.extend(getattr(x, f.name) for f in _dc.fields(x))
+        elif isinstance(x, (list, tuple)):
+            stack.extend(x)
+    return names
+
+
 def _has_subquery(e: A.SqlExpr) -> bool:
     if isinstance(e, (A.InSubquery, A.Exists, A.ScalarSubquery)):
         return True
@@ -287,6 +311,9 @@ def _parse_type(name: str) -> T.DataType:
 class Analyzer:
     def __init__(self, session):
         self.session = session
+        #: innermost last: the column names the SELECT being planned refers
+        #: to (``None``: all of them), which the join's size rule reads
+        self._referenced: List[Optional[set]] = [None]
 
     # -- public -------------------------------------------------------------
     def plan(self, q: A.Select):
@@ -402,8 +429,6 @@ class Analyzer:
 
     def _join(self, lplan, rplan, lkeys, rkeys, kind, cond):
         from spark_rapids_tpu.exec import joins as JX
-        from spark_rapids_tpu.exec.exchange import CpuShuffleExchangeExec
-        from spark_rapids_tpu.plan.partitioning import HashPartitioning
         import spark_rapids_tpu.ops.join_ops as J
         how = {"inner": J.INNER, "left": J.LEFT_OUTER,
                "right": J.RIGHT_OUTER, "full": J.FULL_OUTER,
@@ -415,23 +440,24 @@ class Analyzer:
                     f"{kind} join requires at least one equality condition")
             return JX.CpuBroadcastNestedLoopJoinExec([], [], how, cond,
                                                      lplan, rplan)
-        # decompose struct-constructor pairs BEFORE building the hash
-        # partitionings: both sides must shuffle by the same field keys
-        # the join will probe with
+        # decompose struct-constructor pairs BEFORE the join is planned:
+        # both sides must shuffle by the same field keys the join will
+        # probe with
         lkeys, rkeys, nsafe = JX.expand_struct_key_pairs(lkeys, rkeys)
-        nparts = max(lplan.num_partitions, rplan.num_partitions)
-        if nparts > 1:
-            env = self.session.shuffle_env
-            lplan = CpuShuffleExchangeExec(
-                HashPartitioning(lkeys, nparts), lplan, shuffle_env=env)
-            rplan = CpuShuffleExchangeExec(
-                HashPartitioning(rkeys, nparts), rplan, shuffle_env=env)
-        return JX.CpuShuffledHashJoinExec(lkeys, rkeys, how, cond, lplan,
-                                          rplan, null_safe=nsafe)
+        from spark_rapids_tpu.plan.join_selection import plan_equi_join
+        return plan_equi_join(self.session, lplan, rplan, lkeys, rkeys, how,
+                              cond, nsafe, referenced=self._referenced[-1])
 
     # -- select core --------------------------------------------------------
     def _select(self, q: A.Select, cte_env, outer: Optional[Scope]):
         """Returns (plan, output_names)."""
+        self._referenced.append(_referenced_names(q))
+        try:
+            return self._select_core(q, cte_env, outer)
+        finally:
+            self._referenced.pop()
+
+    def _select_core(self, q: A.Select, cte_env, outer: Optional[Scope]):
         from spark_rapids_tpu.exec.basic import (CpuFilterExec,
                                                  CpuProjectExec)
         env = dict(cte_env)

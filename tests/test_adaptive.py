@@ -159,6 +159,8 @@ def test_coordinated_join_side_coalescing():
     db = {"k": rng.integers(0, 40, 2000), "w": rng.integers(0, 9, 2000)}
 
     def q(s):
+        # a SHUFFLED join: Spark's size rule would broadcast these few KB
+        s.set_conf("spark.sql.autoBroadcastJoinThreshold", "-1")
         a = s.create_dataframe(da, num_partitions=4)
         b = s.create_dataframe(db, num_partitions=4)
         return a.join(b, on="k")

@@ -65,6 +65,9 @@ def _copart_join(s, n=4):
 
 
 def _agg_above_join(s, n=4):
+    # the subject is a SHUFFLED join: Spark's size rule would broadcast
+    # tables of a few KB (tests/test_partitioned_store.py has that rule)
+    s.set_conf("spark.sql.autoBroadcastJoinThreshold", "-1")
     left, right = _join_data()
     a = s.create_dataframe(left, num_partitions=n)
     b = s.create_dataframe(right, num_partitions=n)

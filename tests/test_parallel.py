@@ -175,6 +175,9 @@ def test_engine_join_runs_distributed():
              "name": np.array([f"n{i}" for i in range(50)], dtype=object)}
 
     def q(s):
+        # a SHUFFLED join, whose exchanges the mesh takes: Spark's size
+        # rule would broadcast these few KB and exchange nothing
+        s.set_conf("spark.sql.autoBroadcastJoinThreshold", "-1")
         l = s.create_dataframe(left, num_partitions=8)
         r = s.create_dataframe(right, num_partitions=8)
         return l.join(r, on="k", how="inner")
