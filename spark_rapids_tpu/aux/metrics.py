@@ -98,7 +98,9 @@ _END = object()
 
 def instrument_plan(plan: Exec, level: MetricLevel) -> Exec:
     """Wraps every node's execute_partition with metric recording (the
-    GpuMetric counters around internalDoExecuteColumnar).
+    GpuMetric counters around internalDoExecuteColumnar), and an
+    exchange's ``read_range`` beside it (the adaptive reader's way in:
+    first partition, end, pieces).
 
     Metrics are reset first: plan rewrites shallow-copy nodes but SHARE
     the metrics dicts, so without the reset repeated actions on the same
@@ -112,55 +114,61 @@ def instrument_plan(plan: Exec, level: MetricLevel) -> Exec:
         ms = _ensure_metrics(node, level)
         if not ms:
             continue
-        inner = node.execute_partition
-
-        def wrapped(pidx, _inner=inner, _ms=ms, _name=node.name):
-            rows = _ms.get("numOutputRows")
-            batches = _ms.get("numOutputBatches")
-            optime = _ms.get("opTime")
-            q = EV.active_query()
-            pspan = q.start_partition(id(_ms), pidx) if q is not None \
-                else None
-            it = _inner(pidx)
-            try:
-                while True:
-                    t0 = time.perf_counter()
-                    with _tracing.partition_pull(q, pspan, _name):
-                        b = next(it, _END)
-                    if b is _END:
-                        break
-                    dt = time.perf_counter() - t0
-                    if rows is not None:
-                        # deferred device counts must not sync here; track
-                        # them and fold in lazily once the query's own
-                        # download forces them (resolve())
-                        rc = b.row_count
-                        from spark_rapids_tpu.columnar.column import \
-                            DeferredCount
-                        if not isinstance(rc, DeferredCount) or rc.is_forced:
-                            n = int(rc)
-                            rows.add(n)
-                            if pspan is not None:
-                                pspan.rows += n
-                        else:
-                            rows.defer(rc)
-                    if batches is not None:
-                        batches.add(1)
-                    if optime is not None:
-                        optime.add(dt)
-                    if pspan is not None:
-                        pspan.batches += 1
-                        # rows with their padding: the bucket is a host
-                        # int, so no sync (host batches have none)
-                        pspan.padded_rows += getattr(b, "bucket", 0)
-                    yield b
-            finally:
-                if q is not None and pspan is not None:
-                    q.end_partition(pspan)
-
-        node.execute_partition = wrapped
+        for method in ("execute_partition", "read_range"):
+            if hasattr(node, method):
+                setattr(node, method,
+                        _recording(getattr(node, method), ms, node.name))
         node._instrumented = True
     return plan
+
+
+def _recording(inner, ms: Dict[str, OpMetric], name: str):
+    """``inner`` (a bound ``execute_partition`` or ``read_range``) with its
+    rows, batches and time recorded on ``ms`` and on a partition span."""
+    def wrapped(pidx, *more):
+        rows = ms.get("numOutputRows")
+        batches = ms.get("numOutputBatches")
+        optime = ms.get("opTime")
+        q = EV.active_query()
+        pspan = q.start_partition(id(ms), pidx) if q is not None else None
+        it = inner(pidx, *more)
+        try:
+            while True:
+                t0 = time.perf_counter()
+                with _tracing.partition_pull(q, pspan, name):
+                    b = next(it, _END)
+                if b is _END:
+                    break
+                dt = time.perf_counter() - t0
+                if rows is not None:
+                    # deferred device counts must not sync here; track
+                    # them and fold in lazily once the query's own
+                    # download forces them (resolve())
+                    rc = b.row_count
+                    from spark_rapids_tpu.columnar.column import \
+                        DeferredCount
+                    if not isinstance(rc, DeferredCount) or rc.is_forced:
+                        n = int(rc)
+                        rows.add(n)
+                        if pspan is not None:
+                            pspan.rows += n
+                    else:
+                        rows.defer(rc)
+                if batches is not None:
+                    batches.add(1)
+                if optime is not None:
+                    optime.add(dt)
+                if pspan is not None:
+                    pspan.batches += 1
+                    # rows with their padding: the bucket is a host
+                    # int, so no sync (host batches have none)
+                    pspan.padded_rows += getattr(b, "bucket", 0)
+                yield b
+        finally:
+            if q is not None and pspan is not None:
+                q.end_partition(pspan)
+
+    return wrapped
 
 
 def reset_metrics(plan: Exec) -> None:

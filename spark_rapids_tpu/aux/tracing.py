@@ -200,6 +200,7 @@ class QueryExecution:
                                          "exchange_rows": 0,
                                          "exchange_rows_padded": 0,
                                          "exchange_pieces": 0,
+                                         "exchange_read_rows_padded": 0,
                                          "exchange_host_staged_bytes": 0}
         #: what this query's own threads did, added by the layer that did
         #: it: steady dispatches (``exec/stage_compiler.py``), the
@@ -889,6 +890,7 @@ class QueryExecution:
                  "probe_gather_rounds", "sized_joins", "sized_stages",
                  "broadcast_builds", "exchanges", "exchange_rows",
                  "exchange_rows_padded", "exchange_pieces",
+                 "exchange_read_rows_padded",
                  "exchange_host_staged_bytes")
                 if k in summary))
         lines.append("== Query Summary ==")

@@ -184,36 +184,44 @@ def known_empty(rc) -> bool:
     return int(rc) == 0
 
 
-def force_counts(rcs) -> None:
-    """Forces many deferred counts with ONE device sync (stacked fetch; one
-    a device where they live on several).  Callers that need several
-    batches' exact row counts (AQE partition sizing, the exchange's
-    counters) must not pay a host round trip per batch."""
+def fetch_stacked(arrs, site: str) -> list:
+    """Small device arrays of one shape, fetched with ONE sync (stacked;
+    one a device where they live on several: what a mesh's shards hold
+    cannot be stacked by one program).  Host arrays in ``arrs``' order."""
     jnp = _jnp()
+    from spark_rapids_tpu.aux import transitions as TR
+    by_device: dict = {}
+    for i, a in enumerate(arrs):
+        where = frozenset(a.devices()) if hasattr(a, "devices") else None
+        by_device.setdefault(where, []).append(i)
+    out = [None] * len(arrs)
+    for group in by_device.values():
+        stacked = TR.fetch(jnp.stack([jnp.asarray(arrs[i]) for i in group]),
+                           site=site)
+        for i, v in zip(group, stacked):
+            out[i] = v
+    return out
+
+
+def force_counts(rcs) -> None:
+    """Forces many deferred counts with ONE device sync
+    (:func:`fetch_stacked`).  Callers that need several batches' exact row
+    counts (AQE partition sizing) must not pay a host round trip per
+    batch."""
     pending = [rc for rc in rcs
                if isinstance(rc, DeferredCount) and not rc.is_forced]
     if not pending:
         return
-    from spark_rapids_tpu.aux import transitions as TR
-    # one fetch a device: counts of a mesh's shards live on their shards'
-    # devices and cannot be stacked by one program
-    by_device: dict = {}
-    for rc in pending:
-        dev = rc.traceable()
-        where = frozenset(dev.devices()) if hasattr(dev, "devices") else None
-        by_device.setdefault(where, []).append(rc)
-    for group in by_device.values():
-        stacked = TR.fetch(jnp.stack([jnp.asarray(rc.traceable())
-                                      for rc in group]),
-                           site="count-force-batch")
-        for rc, v in zip(group, stacked):
-            rc._val = int(v)
+    got = fetch_stacked([rc.traceable() for rc in pending],
+                        site="count-force-batch")
+    for rc, v in zip(pending, got):
+        rc._val = int(v)
 
 
 def learn_count(rc, value: int) -> None:
     """Tells a deferred count what the host has learned another way (an
-    exchange's map batch: the sum of its pieces' fetched counts), so later
-    readers of it neither sync nor stay blind."""
+    exchange's map batch: the sum of its fetched per-partition counts), so
+    later readers of it neither sync nor stay blind."""
     if isinstance(rc, DeferredCount) and not rc.is_forced:
         rc._val = int(value)
 

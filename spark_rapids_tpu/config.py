@@ -343,9 +343,9 @@ SPECULATIVE_SIZING_ENABLED = conf_bool(
 
 SHUFFLE_DEVICE_SHRINK_THRESHOLD = conf_bytes(
     "spark.rapids.shuffle.deviceStore.shrinkThresholdBytes",
-    "Map batches whose reduce-fanout-multiplied padded footprint exceeds "
-    "this are padding-shrunk (costs one count sync) before the "
-    "per-partition compacts of the device-resident shuffle store.",
+    "A map batch whose padded footprint exceeds this is padding-shrunk "
+    "(costs one count sync) before the device-resident shuffle store "
+    "sorts and keeps its one copy of it.",
     "64m")
 
 DOWNLOAD_SPECULATIVE_ROWS = conf_int(

@@ -29,65 +29,14 @@ class CoalescedPartitionSpec:
 
 @dataclasses.dataclass(frozen=True)
 class PartialPartitionSpec:
-    """Read a slice of one reduce partition's batches (skew split)."""
+    """Read one reduce partition's rows of the map-side pieces
+    ``[batch_start, batch_end)`` (skew split)."""
     partition: int
     batch_start: int
     batch_end: int
 
 
 PartitionSpec = Union[CoalescedPartitionSpec, PartialPartitionSpec]
-
-
-def _partition_sizes(exchange, target_bytes: Optional[int] = None
-                     ) -> List[int]:
-    """Materializes the exchange and sizes each reduce partition (the AQE
-    'query stage statistics' step).
-
-    Sync discipline: padded (bucket) sizes are computable WITHOUT a device
-    round trip; logical sizes need the deferred counts forced (one
-    device sync per exchange).  When the padded total already fits
-    ``target_bytes``, the coalesce decision ("merge everything") is
-    identical either way — the padded sizes are returned and the sync is
-    skipped entirely (the common case for every exchange of a small-SF
-    query)."""
-    import numpy as np
-    exchange._materialize()
-    if getattr(exchange, "_collective", None) is not None:
-        # mesh path: partitions are device shards; size = rows * row width
-        _ctx, cols, counts, schema = exchange._collective
-        from spark_rapids_tpu.aux import transitions as TR
-        counts_h = TR.fetch(counts, site="aqe-shard-counts")
-        row_bytes = sum(
-            getattr(f.data_type, "np_dtype", None).itemsize
-            if getattr(f.data_type, "np_dtype", None) is not None else 16
-            for f in schema.fields) + len(schema.fields)
-        return [int(c) * row_bytes for c in counts_h]
-    def sizes_now():
-        out = []
-        for p in range(exchange.num_partitions):
-            total = 0
-            for b in exchange._store[p]:
-                if hasattr(b, "sized_nbytes"):
-                    total += b.sized_nbytes()
-                elif hasattr(b, "nbytes"):
-                    total += b.nbytes()
-            out.append(total)
-        return out
-
-    padded = sizes_now()   # no sync: unforced counts report bucket bytes
-    if target_bytes is not None and sum(padded) <= target_bytes:
-        return padded
-    # above target: the decision needs logical sizes — force the deferred
-    # counts in ONE sync so sized_nbytes reports rows-x-width (padded
-    # sizes would make every partition look uniformly huge and disable
-    # coalesce/skew decisions entirely)
-    from spark_rapids_tpu.columnar.column import force_counts
-    force_counts([b.row_count
-                  for p in range(exchange.num_partitions)
-                  for b in exchange._store[p]
-                  if hasattr(b, "row_count")])
-    # counts forced: sized_nbytes now reports logical rows x width
-    return sizes_now()
 
 
 def _balanced_contiguous(sizes: Sequence[int],
@@ -163,21 +112,18 @@ def _emit_coalesce_event(before: int, after: int, align: int,
 
 def skew_split_specs(exchange, pidx: int,
                      target_bytes: int) -> List[PartialPartitionSpec]:
-    """Splits one partition's batch list into roughly target-sized runs
-    (PartialReducerPartitionSpec analog)."""
-    exchange._materialize()
-    batches = exchange._store[pidx]
+    """Splits one partition's map-side pieces into roughly target-sized
+    runs (PartialReducerPartitionSpec analog)."""
+    sizes = exchange.piece_sizes(pidx)
     specs = []
     start = 0
     acc = 0
-    for i, b in enumerate(batches):
-        sz = b.sized_nbytes() if hasattr(b, "sized_nbytes") else \
-            (b.nbytes() if hasattr(b, "nbytes") else 0)
+    for i, sz in enumerate(sizes):
         if i > start and acc + sz > target_bytes:
             specs.append(PartialPartitionSpec(pidx, start, i))
             start, acc = i, 0
         acc += sz
-    specs.append(PartialPartitionSpec(pidx, start, len(batches)))
+    specs.append(PartialPartitionSpec(pidx, start, len(sizes)))
     return specs
 
 
@@ -217,8 +163,8 @@ class SharedCoalesceSpecs:
                 if self._specs is None:
                     # halve the target per side: the padded-fits-target
                     # shortcut must hold for the SUM of both sides
-                    lsz = _partition_sizes(self._exs[0], self._target // 2)
-                    rsz = _partition_sizes(self._exs[1], self._target // 2)
+                    lsz = self._exs[0].partition_sizes(self._target // 2)
+                    rsz = self._exs[1].partition_sizes(self._target // 2)
                     sizes = [a + b for a, b in zip(lsz, rsz)]
                     # whole-partition coalescing only — a partial split
                     # on one side without the other would break pairing
@@ -262,8 +208,8 @@ class AdaptiveShuffleReaderExec(UnaryExec):
             release_semaphore_for_wait()
             with self._exec_lock:
                 if self._specs is None:
-                    sizes = _partition_sizes(self.children[0],
-                                             self.target_bytes)
+                    sizes = self.children[0].partition_sizes(
+                        self.target_bytes)
                     self._specs = coalesce_specs(sizes, self.target_bytes,
                                                  self._align)
                     _emit_coalesce_event(
@@ -280,17 +226,12 @@ class AdaptiveShuffleReaderExec(UnaryExec):
         spec = self.specs[pidx]
         ex = self.children[0]
         if isinstance(spec, CoalescedPartitionSpec):
-            for p in range(spec.start, min(spec.end, ex.num_partitions)):
-                yield from ex.execute_partition(p)
+            yield from ex.read_range(
+                spec.start, min(spec.end, ex.num_partitions))
         else:
-            ex._materialize()
-            batches = ex._store[spec.partition]
-            for b in batches[spec.batch_start:spec.batch_end]:
-                if ex.is_device and not hasattr(b, "bucket"):
-                    from spark_rapids_tpu.exec.basic import upload_batches
-                    yield from upload_batches([b])
-                else:
-                    yield b
+            yield from ex.read_range(
+                spec.partition, spec.partition + 1,
+                (spec.batch_start, spec.batch_end))
 
     def node_desc(self):
         if self._specs is None:

@@ -5,17 +5,21 @@ GpuPartitioning.scala:37) + RapidsShuffleInternalManagerBase.scala (writer
 materializes per-reduce-partition blocks; reader fetches + concatenates) +
 ShuffleBufferCatalog (shuffle payloads tracked spillable).
 
-In-process redesign: the "transport" collapses to a per-exec shuffle store
-of spillable host batches (host-staged shuffle = the reference's default
-mode, which serializes batches to host via JCudfSerialization).  The device
-write path is one fused pass: evaluate pid per row, stable-sort by pid,
-copy to host once, slice per target partition.  The multi-node design
-(ICI all-to-all within a slice, host-staged DCN across) plugs in behind the
-same exec via the parallel/ package.
+In-process redesign: the "transport" collapses to a per-exec shuffle store.
+The host exchange keeps spillable host batches grouped by reduce partition
+(host-staged shuffle = the reference's default mode, which serializes
+batches to host via JCudfSerialization).  The device exchange keeps ONE
+piece a map batch in HBM, its rows stable-sorted by reduce partition id
+with the counts a partition beside it (Spark's sort shuffle: one data
+file a map task and an index of offsets), and a reduce read gathers a
+row range of each piece into one batch at the bucket of what it holds.
+The multi-node design (ICI all-to-all within a slice, host-staged DCN
+across) plugs in behind the same exec via the parallel/ package.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -63,18 +67,24 @@ def _note_written(pieces: int, rows: int, padded: int) -> None:
     add_count("exchange_rows_padded", padded)
 
 
-def partition_masks(part: Partitioning, batch: ColumnarBatch, n: int):
-    """One keep-mask a reduce partition over ``batch``'s rows: the
-    partition ids and their comparison with each partition as ONE program
-    (kind ``exchange.pid``).  Padding rows carry the id ``n`` and are in
-    no mask."""
+def _batch_sig(b: ColumnarBatch) -> tuple:
+    from spark_rapids_tpu.ops.batch_ops import _col_sig
+    return tuple((str(c.data_type),) + _col_sig(c) for c in b.columns)
+
+
+def _planes(b: ColumnarBatch) -> list:
+    return [(c.data, c.validity, c.lengths, c.elem_valid) for c in b.columns]
+
+
+def partition_ids(part: Partitioning, batch: ColumnarBatch, n: int):
+    """int32 reduce partition id of every row of ``batch`` as ONE program
+    (kind ``exchange.pid``).  Padding rows carry the id ``n``."""
     from spark_rapids_tpu.columnar.column import (DeferredCount,
                                                   DeviceColumn, _jnp,
                                                   rc_traceable)
     from spark_rapids_tpu.columnar.encoding import (batch_has_encoded,
                                                     materialize_batch)
     from spark_rapids_tpu.exec.stage_compiler import get_or_build
-    from spark_rapids_tpu.ops.batch_ops import _col_sig
     from spark_rapids_tpu.plan.pruning import _refs
     jnp = _jnp()
     if batch_has_encoded(batch):
@@ -86,13 +96,6 @@ def partition_masks(part: Partitioning, batch: ColumnarBatch, n: int):
                                   site="operator")
     src, more = part.pid_inputs(batch, "exchange.pid")
 
-    def sig(b):
-        return tuple((str(c.data_type),) + _col_sig(c) for c in b.columns)
-
-    def planes(b):
-        return [(c.data, c.validity, c.lengths, c.elem_valid)
-                for c in b.columns]
-
     def rebuilt(like_types, arrs, rc):
         cols = [DeviceColumn(d, v, rc, dt, lengths=ln, elem_valid=ev)
                 for (d, v, ln, ev), dt in zip(arrs, like_types)]
@@ -101,21 +104,276 @@ def partition_masks(part: Partitioning, batch: ColumnarBatch, n: int):
     types = [c.data_type for c in src.columns]
     more_types = [[c.data_type for c in m.columns] for m in more]
     more_rows = [int(m.row_count) for m in more]    # host batches uploaded
-    key = (part.program_key(), n, sig(src),
-           tuple((r, sig(m)) for r, m in zip(more_rows, more)))
+    key = (part.program_key(), n, _batch_sig(src),
+           tuple((r, _batch_sig(m)) for r, m in zip(more_rows, more)))
 
     def build():
         def run(arrs, rc, more_arrs):
-            pids = part.pids_from(
+            return part.pids_from(
                 rebuilt(types, arrs, DeferredCount(rc)),
                 *[rebuilt(t, a, r)
                   for t, a, r in zip(more_types, more_arrs, more_rows)])
-            return tuple(pids == p for p in range(n))
         return run
 
     fn = get_or_build("exchange.pid", key, build)
-    return fn(planes(src), jnp.asarray(rc_traceable(src.row_count)),
-              [planes(m) for m in more])
+    return fn(_planes(src), jnp.asarray(rc_traceable(src.row_count)),
+              [_planes(m) for m in more])
+
+
+def sort_by_partition(batch: ColumnarBatch, pids, n: int):
+    """``batch``'s rows ordered by reduce partition id, stable (a
+    partition's rows keep the map batch's order; padding rows, id ``n``,
+    come last), at the batch's bucket, and the ``n + 1`` counts of the ids
+    as a device array: ONE program (kind ``exchange.sort``) of one packed
+    32-bit sort of (id, row position) and one gather a plane.  The layout
+    is Spark's sort shuffle's: one data file a map task ordered by reduce
+    partition, and an index of offsets.  Dictionary code planes move like
+    any int plane; RLE materializes first."""
+    from spark_rapids_tpu.columnar.column import _jnp
+    from spark_rapids_tpu.columnar.encoding import (materialize_rle_batch,
+                                                    rewrap_like)
+    from spark_rapids_tpu.exec.stage_compiler import get_or_build
+    from spark_rapids_tpu.ops.sort_ops import lex_sort_perm
+    jnp = _jnp()
+    batch = materialize_rle_batch(batch)
+    bucket = int(pids.shape[0])
+
+    def build():
+        def run(arrs, pids):
+            perm = lex_sort_perm(
+                [(pids.astype(np.uint32), n.bit_length())], bucket, jnp)
+
+            def move(plane):
+                return None if plane is None else \
+                    jnp.take(plane, perm, axis=0)
+
+            # counted by comparison: a ``bincount`` is a scatter-add, 57 ns
+            # an id on the chip (1.86 ms a 32,768-row batch; PERF.md
+            # section 6, PR 38)
+            ids = jnp.arange(n + 1, dtype=pids.dtype)
+            counts = jnp.sum(pids[:, None] == ids[None, :], axis=0,
+                             dtype=np.int32)
+            return ([tuple(move(x) for x in planes) for planes in arrs],
+                    counts)
+        return run
+
+    fn = get_or_build("exchange.sort", (n, bucket, _batch_sig(batch)), build)
+    outs, counts = fn(_planes(batch), pids)
+    rc = batch.row_count
+    cols = [rewrap_like(c, d, v, rc, ln, ev)
+            for (d, v, ln, ev), c in zip(outs, batch.columns)]
+    return ColumnarBatch(cols, rc, batch.names), counts
+
+
+#: pieces one read program takes; a map side of more is read in groups
+#: whose outputs, sorted pieces themselves, are read again
+_READ_GROUP = 32
+
+
+@dataclasses.dataclass
+class _SortedPiece:
+    """A map batch as the exchange keeps it: rows ordered by reduce
+    partition id.  ``batch`` is on the device at the map batch's bucket, or
+    on the host at its live rows (the staged fallback); ``counts`` are its
+    rows a reduce partition, on the device until the map side's one fetch
+    (:meth:`_SortedStore.learn`); ``starts``, their running sum from 0
+    (``n + 1`` entries), is what the host reads ranges by from then on."""
+    batch: object
+    counts: object = None
+    starts: Optional[np.ndarray] = None
+
+    def settle(self, counts, n: int) -> None:
+        """Takes the fetched counts (of the ids 0 to ``n``: the last is
+        the padding's)."""
+        self.counts = None
+        self.starts = np.concatenate(
+            ([0], np.cumsum(np.asarray(counts[:n], dtype=np.int64))))
+
+    @property
+    def on_device(self) -> bool:
+        return isinstance(self.batch, ColumnarBatch)
+
+    def rows(self, start: int = 0, end: Optional[int] = None) -> int:
+        """Live rows of the reduce partitions ``[start, end)``."""
+        end = len(self.starts) - 1 if end is None else end
+        return int(self.starts[end] - self.starts[start])
+
+    def row_bytes(self) -> float:
+        rows = self.batch.bucket if self.on_device else self.batch.row_count
+        return self.batch.nbytes() / max(rows, 1)
+
+
+class _SortedStore:
+    """The device shuffle's store: ONE piece a map batch, in map order.  A
+    reduce read is a row range of each piece."""
+
+    def __init__(self, n: int, pieces: List[_SortedPiece]):
+        self.n = n
+        self.pieces = pieces
+
+    def learn(self) -> None:
+        """The map side's ONE fetch: the count vectors of all device
+        pieces together (the staged ones' came with their download).
+        Teaches every map batch its row count, brings the pieces to one
+        device and one encoding a column for the reads (a mesh's shards
+        hand on batches committed to their own devices), and notes what
+        was stored."""
+        from spark_rapids_tpu.columnar.column import (fetch_stacked,
+                                                      learn_count)
+        from spark_rapids_tpu.columnar.encoding import align_batches
+        from spark_rapids_tpu.ops.batch_ops import _align_batch_devices
+        device = [p for p in self.pieces if p.on_device]
+        got = fetch_stacked([p.counts for p in device],
+                            site="count-force-batch")
+        for p, counts in zip(device, got):
+            p.settle(counts, self.n)
+            learn_count(p.batch.row_count, p.rows())
+        together = align_batches(
+            _align_batch_devices([p.batch for p in device]), site="exchange")
+        for p, b in zip(device, together):
+            p.batch = b
+        _note_written(len(device), sum(p.rows() for p in device),
+                      sum(p.batch.bucket for p in device))
+
+    # -- what the adaptive reader sizes its specs by -------------------------
+    def partition_sizes(self) -> List[int]:
+        """Live bytes a reduce partition."""
+        sizes = np.zeros(self.n)
+        for p in self.pieces:
+            sizes += np.diff(p.starts) * p.row_bytes()
+        return [int(x) for x in sizes]
+
+    def piece_sizes(self, pidx: int) -> List[int]:
+        """Live bytes of reduce partition ``pidx`` a piece, in map order."""
+        return [int(p.rows(pidx, pidx + 1) * p.row_bytes())
+                for p in self.pieces]
+
+    # -- reduce side ---------------------------------------------------------
+    def read(self, start: int, end: int, pieces=None):
+        """The rows of the reduce partitions ``[start, end)`` (of the
+        pieces ``pieces[0]`` up to ``pieces[1]`` alone, a skewed
+        partition's run of map batches): what the device pieces hold as
+        ONE batch at the bucket of its rows, partition by partition and
+        within a partition in map order, then a slice of each staged piece,
+        uploaded.  A range that holds no row yields nothing."""
+        from spark_rapids_tpu.exec.basic import upload_batches
+        chosen = self.pieces if pieces is None else \
+            self.pieces[pieces[0]:pieces[1]]
+        device = [p for p in chosen if p.on_device]
+        while len(device) > _READ_GROUP:
+            groups = [device[i:i + _READ_GROUP]
+                      for i in range(0, len(device), _READ_GROUP)]
+            device = [m for m in (_read_rows(g, start, end, self.n)
+                                  for g in groups) if m is not None]
+        got = _read_rows(device, start, end, self.n) if device else None
+        if got is not None:
+            yield got.batch
+        staged = [p.batch.slice(int(p.starts[start]), p.rows(start, end))
+                  for p in chosen
+                  if not p.on_device and p.rows(start, end)]
+        if staged:
+            yield from upload_batches(staged)
+
+
+def _read_rows(pieces: List[_SortedPiece], start: int, end: int,
+               n: int) -> Optional[_SortedPiece]:
+    """The rows that device ``pieces`` hold of the reduce partitions
+    ``[start, end)``, as one sorted piece at the bucket of those rows: ONE
+    program (kind ``exchange.read``).  Its key is shapes alone (the
+    pieces' signatures, ``n``, the output bucket); the pieces' starts and
+    the range are run-time arguments.  A run is one (partition, piece)
+    pair's rows; the runs are laid partition by partition, so a read of
+    ``[start, end)`` is the single-partition reads one after another.  For
+    output row r the run is found from the runs' offsets by a histogram
+    and its running sum (``expand_positions``), the source row is the
+    run's first row plus r less the run's offset, and every plane is
+    gathered once, at the output bucket, from the pieces' planes laid end
+    to end: the cost follows what is read, not what was stored."""
+    from spark_rapids_tpu.columnar.column import bucket_rows
+    total = sum(p.rows(start, end) for p in pieces)
+    if total == 0:
+        return None
+    out_bucket = bucket_rows(total)
+    add_count("exchange_read_rows_padded", out_bucket)
+    first = pieces[0].batch
+    merged = _SortedPiece(
+        _gather_rows(pieces, start, end, n, total, out_bucket)
+        if first.columns else ColumnarBatch([], total, first.names))
+    live = np.zeros(n, dtype=np.int64)
+    for p in pieces:
+        live[start:end] += np.diff(p.starts)[start:end]
+    merged.settle(live, n)
+    return merged
+
+
+def _gather_rows(pieces: List[_SortedPiece], start: int, end: int, n: int,
+                 total: int, out_bucket: int) -> ColumnarBatch:
+    """:func:`_read_rows`' program and its call: the ``total`` rows of
+    ``[start, end)`` at ``out_bucket``."""
+    from spark_rapids_tpu.columnar.column import _jnp
+    from spark_rapids_tpu.columnar.encoding import rewrap_like
+    from spark_rapids_tpu.exec.stage_compiler import get_or_build
+    from spark_rapids_tpu.ops.batch_ops import expand_positions, prefix_sum
+    first = pieces[0].batch
+    jnp = _jnp()
+    k = len(pieces)
+    buckets = [p.batch.bucket for p in pieces]
+    #: a piece's first row in the planes laid end to end; a row there is
+    #: addressed in 32 bits wherever the pieces' buckets allow it
+    index = np.int32 if sum(buckets) < 1 << 31 else np.int64
+    bases = np.concatenate(([0], np.cumsum(buckets)[:-1])).astype(index)
+    ncols = len(first.columns)
+    # per-column max string/array width across pieces
+    widths = [max((p.batch.columns[ci].data.shape[1] for p in pieces
+                   if p.batch.columns[ci].lengths is not None), default=0)
+              for ci in range(ncols)]
+
+    def build():
+        def run(all_arrs, starts, lo, hi):
+            # starts: int32[k, n + 1]; a run (partition p, piece i) sits at
+            # p * k + i
+            part = jnp.arange(n, dtype=np.int32)
+            wanted = (part >= lo) & (part < hi)
+            counts = jnp.where(wanted[None, :],
+                               starts[:, 1:] - starts[:, :-1], 0)
+            counts = counts.T.reshape(n * k)
+            offsets = prefix_sum(counts, jnp) - counts
+            source = (starts[:, :-1] + bases[:, None]).T.reshape(n * k)
+            r = jnp.arange(out_bucket, dtype=np.int32)
+            run_of = expand_positions(offsets, out_bucket, jnp)
+            alive = r < jnp.sum(counts)
+            src = jnp.where(
+                alive, r + jnp.take(source - offsets, run_of, axis=0),
+                0).astype(index)
+
+            def move(planes, width=None):
+                if planes[0] is None:
+                    return None
+                if width is not None:
+                    planes = [jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
+                              if x.shape[1] < width else x for x in planes]
+                return jnp.take(jnp.concatenate(planes, axis=0), src,
+                                axis=0)
+
+            outs = []
+            for ci in range(ncols):
+                d, v, ln, ev = zip(*(arrs[ci] for arrs in all_arrs))
+                w = widths[ci] if ln[0] is not None else None
+                outs.append((move(d, w), move(v) & alive, move(ln),
+                             move(ev, w)))
+            return outs
+        return run
+
+    fn = get_or_build(
+        "exchange.read",
+        (n, out_bucket, tuple(_batch_sig(p.batch) for p in pieces)), build)
+    starts = np.stack([p.starts for p in pieces]).astype(np.int32)
+    outs = fn([_planes(p.batch) for p in pieces], starts, np.int32(start),
+              np.int32(end))
+    return ColumnarBatch(
+        [rewrap_like(c, d, v, total, ln, ev)
+         for (d, v, ln, ev), c in zip(outs, first.columns)],
+        total, first.names)
 
 
 #: defaults for the round-5 shuffle knobs; the convert-time conf values
@@ -467,6 +725,24 @@ class CpuShuffleExchangeExec(UnaryExec):
 
     # -- reduce side --------------------------------------------------------
     def execute_partition(self, pidx):
+        yield from self._read(pidx, pidx + 1)
+
+    def read_range(self, start, end, pieces=None):
+        """The reduce partitions ``[start, end)`` as one read (the
+        adaptive reader's coalesced spec); with ``pieces`` only the
+        map-side pieces ``pieces[0]`` up to ``pieces[1]`` (its skew
+        split).  Recorded on the node's metrics as ``execute_partition``
+        is (``aux/metrics.py``)."""
+        yield from self._read(start, end, pieces)
+
+    def _read(self, start, end, pieces=None):
+        self._materialized()
+        for p in range(start, end):
+            self._prefetch_next(p)
+            yield from span_pulls("exchange.read", self._stored(p, pieces),
+                                  partition=p)
+
+    def _materialized(self) -> None:
         from spark_rapids_tpu.plan.base import release_semaphore_for_wait
         if self._store is None:
             # drop device admission before blocking on the map side (the
@@ -474,9 +750,50 @@ class CpuShuffleExchangeExec(UnaryExec):
             release_semaphore_for_wait()
             with self._exec_lock:
                 self._materialize()
-        self._prefetch_next(pidx)
-        yield from span_pulls("exchange.read", iter(self._store[pidx]),
-                              partition=pidx)
+
+    def _stored(self, pidx: int, pieces=None):
+        """Reduce partition ``pidx``'s batches, one a map-side piece."""
+        stored = self._store[pidx]
+        return iter(stored if pieces is None
+                    else stored[pieces[0]:pieces[1]])
+
+    # -- what the adaptive reader sizes its specs by (exec/adaptive.py) ------
+    def partition_sizes(self, target_bytes: Optional[int] = None
+                        ) -> List[int]:
+        """Materializes the exchange and sizes each reduce partition (the
+        AQE 'query stage statistics' step).
+
+        Sync discipline: padded (bucket) sizes are computable WITHOUT a
+        device round trip; logical sizes need the deferred counts forced
+        (one device sync per exchange).  When the padded total already
+        fits ``target_bytes``, the coalesce decision ("merge everything")
+        is identical either way — the padded sizes are returned and the
+        sync is skipped entirely."""
+        def sizes_now():
+            return [sum(self.piece_sizes(p))
+                    for p in range(self.num_partitions)]
+
+        padded = sizes_now()   # no sync: unforced counts report bucket bytes
+        if target_bytes is not None and sum(padded) <= target_bytes:
+            return padded
+        # above target: the decision needs logical sizes — force the
+        # deferred counts in ONE sync so sized_nbytes reports rows-x-width
+        # (padded sizes would make every partition look uniformly huge and
+        # disable coalesce/skew decisions entirely)
+        from spark_rapids_tpu.columnar.column import force_counts
+        force_counts([b.row_count
+                      for p in range(self.num_partitions)
+                      for b in self._store[p]
+                      if hasattr(b, "row_count")])
+        return sizes_now()
+
+    def piece_sizes(self, pidx: int) -> List[int]:
+        """Bytes of reduce partition ``pidx`` a map-side piece (a batch
+        whose count is deferred and unforced reports its bucket's)."""
+        self._materialize()
+        return [b.sized_nbytes() if hasattr(b, "sized_nbytes") else
+                (b.nbytes() if hasattr(b, "nbytes") else 0)
+                for b in self._store[pidx]]
 
     def _prefetch_next(self, pidx: int) -> None:
         """Pipelined shuffle read: while this reduce partition streams to
@@ -497,12 +814,21 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
     DEFAULT mode within one process keeps the store DEVICE-RESIDENT: map
     output batches never leave HBM (reference: the UCX caching writer keeps
     shuffle output on device in ShuffleBufferCatalog,
-    RapidsShuffleInternalManagerBase.scala:1034).  Each map batch is first
-    shrunk to its live row bucket (one sync at this materialization
-    boundary), then each reduce partition is produced by a mask+compact
-    kernel whose output count stays deferred.  The store is NOT yet
-    catalog-spillable — an oversized shuffle should use MULTITHREADED mode
-    (host-staged, spill-file backed) via spark.rapids.shuffle.mode.
+    RapidsShuffleInternalManagerBase.scala:1034).  The store holds ONE
+    piece a map batch, its rows ordered by reduce partition id with the
+    counts a partition beside it (``sort_by_partition``: Spark's sort
+    shuffle layout, one data file a map task and an index of offsets);
+    nothing on the map side waits for the device.  The exchange is a
+    materialization boundary: at the end of its map side the count vectors
+    of all pieces are fetched together (ONE sync an exchange), which
+    teaches every map batch its row count and the adaptive reader the
+    partitions' sizes.  A reduce read, of one partition or of a contiguous
+    range of them, is a row range of each piece, gathered by one program
+    into one batch at the bucket of what it holds (``_read_rows``).  The
+    store is NOT yet catalog-spillable: map output past the free-HBM
+    budget is kept on the host in the same layout (the staged fallback),
+    and an oversized shuffle should use MULTITHREADED mode (host-staged,
+    spill-file backed) via spark.rapids.shuffle.mode.
 
     MULTITHREADED/CACHED modes keep the host-staged path from the base
     class (process-boundary semantics, spillable storage).
@@ -619,35 +945,27 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
             self._compute_bounds()
         n = part.num_partitions
         from spark_rapids_tpu.plan.partitioning import SinglePartitioning
-        store: List[List] = [[] for _ in range(n)]
         if isinstance(part, SinglePartitioning) or n == 1:
             # child partitions run as concurrent tasks via execute_all
-            store[0].extend(self.child.execute_all())
-            self._store = store
+            self._store = [list(self.child.execute_all())]
             return
-        from spark_rapids_tpu.columnar.column import (force_counts,
-                                                      learn_count)
-        from spark_rapids_tpu.columnar.encoding import materialize_rle_batch
-        from spark_rapids_tpu.ops.batch_ops import (compact_batch,
-                                                    shrink_batch)
+        from spark_rapids_tpu.ops.batch_ops import shrink_batch
         from spark_rapids_tpu.plan.base import (iter_partition_tasks,
                                                 run_task_iter)
-        # HBM guard: the device-resident store keeps one full-bucket
-        # compacted copy of every map batch PER reduce partition (~n x
-        # input bytes).  When that estimate crosses the free-HBM budget,
-        # fall back to the host-staged path automatically instead of
-        # OOMing the device (DEFAULT is the default mode; users shouldn't
-        # need to know to flip spark.rapids.shuffle.mode=MULTITHREADED).
+        # HBM guard: the device-resident store keeps one copy of every map
+        # batch at its bucket.  When that estimate crosses the free-HBM
+        # budget, fall back to the host-staged path automatically instead
+        # of OOMing the device (DEFAULT is the default mode; users
+        # shouldn't need to know to flip
+        # spark.rapids.shuffle.mode=MULTITHREADED).
         budget = self._device_store_budget()
         state = {"stored_estimate": 0, "host_staging": False}
-        #: (a map batch's count, its device pieces), for the counts below
-        split: List = []
         state_lock = __import__("threading").Lock()
 
-        #: only batches whose n-fold padded footprint is material get the
-        #: padding-shrink (shrink needs the exact count -> a device
-        #: sync); below the threshold the compacts just keep the input
-        #: bucket and counts stay deferred (sync-free map side)
+        #: only a batch whose padded footprint is material gets the
+        #: padding-shrink (shrink needs the exact count -> a device sync);
+        #: below the threshold the piece keeps the input bucket and its
+        #: counts stay on the device (sync-free map side)
         shrink_threshold = self.shrink_threshold_bytes \
             if self.shrink_threshold_bytes is not None \
             else SHRINK_THRESHOLD_BYTES
@@ -657,31 +975,25 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
             p_eff = part
             if isinstance(part, RoundRobinPartitioning):
                 p_eff = RoundRobinPartitioning(n, start=mp)
-            # STREAMED (materializing the whole partition to batch the
-            # count syncs would defeat the host-staging fallback below):
-            # only batches whose n-fold footprint is material pay the
-            # shrink (and its one count sync); small batches flow through
-            # sync-free with deferred counts.  closing_source: an
+            # STREAMED (materializing the whole partition first would
+            # defeat the host-staging fallback below).  closing_source: an
             # abandoned map task stops the chain now, not at GC
             with closing_source(self.child.execute_partition(mp)) as it:
                 yield from _map_core(it, mp, p_eff)
 
         def _map_core(it, mp, p_eff):
             for b in it:
-                # cap the n-fold storage cost: drop padding before the
-                # per-partition compacts
-                if b.nbytes() * n > shrink_threshold:
+                if b.nbytes() > shrink_threshold:
                     b = shrink_batch(b)
                 with state_lock:
                     if not state["host_staging"]:
-                        state["stored_estimate"] += b.nbytes() * n
+                        state["stored_estimate"] += b.nbytes()
                         if budget is not None and \
                                 state["stored_estimate"] > budget:
-                            # auto-fallback: the rest of the map output
-                            # goes through the host-staged writer; batches
-                            # already compacted stay on device (they fit
-                            # the budget) and execute_partition handles
-                            # the mixed store
+                            # auto-fallback: the rest of the map output is
+                            # kept on the host; pieces already stored stay
+                            # on device (they fit the budget) and a read
+                            # handles the mixed store
                             import logging
                             logging.getLogger(__name__).info(
                                 "device shuffle store would exceed HBM "
@@ -691,34 +1003,23 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
                             state["host_staging"] = True
                     staging = state["host_staging"]
                 if staging:
-                    yield from self._slice_host_pairs(b, p_eff, n, mp)
+                    piece = self._staged_piece(b, p_eff, n, mp)
+                    _note_written(1, piece.rows(), piece.rows())
+                    yield piece
                     continue
                 with span("exchange.write", map=mp):
-                    b = materialize_rle_batch(b)
-                    masks = partition_masks(p_eff, b, n)
-                    pieces = [(p, compact_batch(b, masks[p],
-                                                kind="exchange.split"))
-                              for p in range(n)]
-                with state_lock:
-                    split.append((b.row_count, [sub for _, sub in pieces]))
-                yield from pieces
+                    piece = _SortedPiece(*sort_by_partition(
+                        b, partition_ids(p_eff, b, n), n))
+                yield piece
 
-        for p, sub in iter_partition_tasks(
-                lambda mp: run_task_iter(map_gen, mp),
-                self.child.num_partitions):
-            store[p].append(sub)
-        # the exchange is a materialization boundary: what its device
-        # pieces hold is learned here with ONE fetch for all of them (the
-        # counts of the host-staged ones came with their download), so
-        # the summary's counters, the map side's own row counts and every
-        # reader above (the adaptive reader's sizes) see live rows and
-        # not buckets
-        device = [sub for _, subs in split for sub in subs]
-        force_counts([sub.row_count for sub in device])
-        for count, subs in split:
-            learn_count(count, sum(int(sub.row_count) for sub in subs))
-        _note_written(len(device), sum(int(sub.row_count) for sub in device),
-                      sum(sub.bucket for sub in device))
+        store = _SortedStore(n, list(iter_partition_tasks(
+            lambda mp: run_task_iter(map_gen, mp),
+            self.child.num_partitions)))
+        # what the pieces hold is learned here with ONE fetch for all of
+        # them, so the summary's counters, the map side's own row counts
+        # and every reader above (the adaptive reader's sizes) see live
+        # rows and not buckets
+        store.learn()
         self._store = store
 
     def _device_store_budget(self):
@@ -729,63 +1030,28 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
             free_device_headroom
         return free_device_headroom(2)
 
-    def _slice_host_pairs(self, b, part, n, mp=None):
-        """One device batch -> (pid, host slice) pairs via the device
-        sort-by-pid writer (the _map_pairs core, batch-wise)."""
-        with span("exchange.write", map=mp, staged="host"):
-            pairs = list(self._sliced_on_host(b, part, n))
-            rows = sum(hb.row_count for _, hb in pairs)
-            _note_written(len(pairs), rows, rows)
-            add_count("exchange_host_staged_bytes",
-                      sum(hb.nbytes() for _, hb in pairs))
-        yield from pairs
-
-    def _sliced_on_host(self, b, part, n):
-        from spark_rapids_tpu.columnar.column import DeviceColumn, _jnp
-        from spark_rapids_tpu.ops.batch_ops import gather_batch
-        from spark_rapids_tpu.ops.sort_ops import SortOrder, sort_permutation
-        jnp = _jnp()
-        pids = part.partition_ids_tpu(b)
-        pid_col = DeviceColumn(pids.astype(np.int64),
-                               jnp.ones(b.bucket, dtype=bool),
-                               b.row_count, None)
-        aug = ColumnarBatch([pid_col] + list(b.columns), b.row_count)
-        perm = sort_permutation(aug, [SortOrder(0, True, True)])
-        shuffled = gather_batch(b, perm, b.row_count)
+    def _staged_piece(self, b, part, n, mp=None) -> _SortedPiece:
+        """One device batch as a piece kept on the host: the device
+        store's layout (``sort_by_partition``), downloaded, with its
+        counts (one fetch a batch, site ``shuffle-pid-counts``)."""
         from spark_rapids_tpu.aux import transitions as TR
-        counts = TR.fetch(jnp.bincount(
-            jnp.clip(pids, 0, n), length=n + 1),
-            site="shuffle-pid-counts")[:n]
-        hb = shuffled.to_host(spec_rows=self.dl_spec_rows)
-        hb.names = b.names
-        off = 0
-        for p in range(n):
-            if counts[p]:
-                yield p, hb.slice(off, int(counts[p]))
-            off += int(counts[p])
+        with span("exchange.write", map=mp, staged="host"):
+            shuffled, counts = sort_by_partition(
+                b, partition_ids(part, b, n), n)
+            counts = TR.fetch(counts, site="shuffle-pid-counts")
+            hb = shuffled.to_host(spec_rows=self.dl_spec_rows)
+            hb.names = b.names
+            add_count("exchange_host_staged_bytes", hb.nbytes())
+        piece = _SortedPiece(hb)
+        piece.settle(counts, n)
+        return piece
 
-    def execute_partition(self, pidx):
-        from spark_rapids_tpu.plan.base import release_semaphore_for_wait
-        if self._store is None and self._collective is None:
-            release_semaphore_for_wait()
-            with self._exec_lock:
-                self._materialize()
-        if self._store is not None:
-            self._prefetch_next(pidx)
-        if self._collective is not None:
-            from spark_rapids_tpu.parallel import collective as C
-            ctx, cols, counts, schema = self._collective
-            yield C.shard_to_batch(ctx, cols, counts, schema, pidx)
-            return
-        yield from span_pulls("exchange.read", self._stored(pidx),
-                              partition=pidx)
-
-    def _stored(self, pidx):
-        """A reduce partition's pieces: those on the device as they are,
+    def _stored(self, pidx, pieces=None):
+        """A reduce partition's batches: those on the device as they are,
         then the host-staged ones, uploaded."""
         from spark_rapids_tpu.exec.basic import upload_batches
         host_pending = []
-        for b in self._store[pidx]:
+        for b in super()._stored(pidx, pieces):
             if isinstance(b, ColumnarBatch):
                 yield b
             else:
@@ -793,17 +1059,64 @@ class TpuShuffleExchangeExec(CpuShuffleExchangeExec):
         if host_pending:
             yield from upload_batches(host_pending)
 
+    def _read(self, start, end, pieces=None):
+        if self._collective is None:
+            self._materialized()
+        if self._collective is not None:
+            from spark_rapids_tpu.parallel import collective as C
+            ctx, cols, counts, schema = self._collective
+            for p in range(start, end):
+                yield C.shard_to_batch(ctx, cols, counts, schema, p)
+        elif isinstance(self._store, _SortedStore):
+            yield from span_pulls("exchange.read",
+                                  self._store.read(start, end, pieces),
+                                  partition=start)
+        else:
+            yield from super()._read(start, end, pieces)
+
+    def partition_sizes(self, target_bytes=None):
+        """The base class's, for a list store; a mesh's shards by their
+        fetched counts; the sorted store's live bytes, which need no
+        fetch."""
+        self._materialize()
+        if self._collective is not None:
+            # mesh path: partitions are device shards; size = rows * row
+            # width
+            _ctx, _cols, counts, schema = self._collective
+            from spark_rapids_tpu.aux import transitions as TR
+            counts_h = TR.fetch(counts, site="aqe-shard-counts")
+            row_bytes = sum(
+                getattr(f.data_type, "np_dtype", None).itemsize
+                if getattr(f.data_type, "np_dtype", None) is not None else 16
+                for f in schema.fields) + len(schema.fields)
+            return [int(c) * row_bytes for c in counts_h]
+        if isinstance(self._store, _SortedStore):
+            # the counts came with the map side's one fetch
+            return self._store.partition_sizes()
+        return super().partition_sizes(target_bytes)
+
+    def piece_sizes(self, pidx):
+        self._materialize()
+        if isinstance(self._store, _SortedStore):
+            return self._store.piece_sizes(pidx)
+        return super().piece_sizes(pidx)
+
     def _map_pairs(self, mp: int, n: int):
-        """Device shuffle write: pid eval + stable sort-by-pid on device,
-        ONE host copy, then arrow slicing per reduce partition (shared
-        per-batch core: ``_slice_host_pairs``)."""
+        """Device shuffle write of the MULTITHREADED and CACHED modes: a
+        map batch sorted by reduce partition id on the device, ONE host
+        copy (``_staged_piece``), then a slice a reduce partition."""
         from spark_rapids_tpu.plan.base import closing_source
         part = self.partitioning
         if isinstance(part, RoundRobinPartitioning):
             part = RoundRobinPartitioning(n, start=mp)
         with closing_source(self.child.execute_partition(mp)) as it:
             for b in it:
-                yield from self._slice_host_pairs(b, part, n, mp)
+                piece = self._staged_piece(b, part, n, mp)
+                pairs = [(p, piece.batch.slice(int(piece.starts[p]),
+                                               piece.rows(p, p + 1)))
+                         for p in range(n) if piece.rows(p, p + 1)]
+                _note_written(len(pairs), piece.rows(), piece.rows())
+                yield from pairs
 
     def _compute_bounds(self):
         self._compute_bounds_tpu()

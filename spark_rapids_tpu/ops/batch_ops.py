@@ -157,7 +157,7 @@ def compact_batch(batch: ColumnarBatch, keep,
                   kind: str = "batch.compact") -> ColumnarBatch:
     """Moves kept rows to the front (stable), returns batch with new count.
     ``kind`` names the program for the stage compiler's counters and the
-    device trace (the exchange's split is ``exchange.split``).
+    device trace.
     Dictionary code planes compact like any int plane (the encoding
     survives — late materialization); RLE materializes first.
 

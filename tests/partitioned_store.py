@@ -3,8 +3,8 @@
 benchmark generator's tables at a twentieth of SF1 registered in 1, 4 or 10
 partitions, and every text of a file answered once in each layout.  Three
 files, so that three xdist workers share the compiles and none is over
-300 s (a 10-partition plan concatenates a hundred exchange pieces in one
-program, which the CPU compiler takes its time over)."""
+300 s (a 10-partition plan builds ten tasks' programs and a broadcast
+build's ten-input concat, which the CPU compiler takes its time over)."""
 
 import gc
 import json

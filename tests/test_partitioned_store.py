@@ -133,20 +133,34 @@ def test_the_exchange_counters_add_up(store, n, mode):
     assert summary["exchange_rows"] == read > 0
     assert summary["exchange_rows"] == sum(x["numOutputRows"]
                                            for x in children)
-    # a device piece is stored at a bucket, never under its live rows
+    # a device piece is stored at a bucket, never under its live rows, and
+    # a read writes a bucket, never under the rows it holds
     assert summary["exchange_rows_padded"] >= summary["exchange_rows"]
+    assert summary["exchange_read_rows_padded"] >= summary["exchange_rows"]
     assert summary["exchange_host_staged_bytes"] == 0
-    # a map batch stores a piece a reduce partition: n map tasks under the
-    # aggregate's exchange, fewer under one whose child the adaptive
-    # reader coalesced
-    assert summary["exchange_pieces"] % n == 0
-    assert 0 < summary["exchange_pieces"] <= n * n * len(exchanges)
+    # a map batch stores ONE piece, its rows ordered by reduce partition:
+    # n map tasks under the aggregate's exchange, fewer under one whose
+    # child the adaptive reader coalesced
+    assert len(exchanges) <= summary["exchange_pieces"] \
+        <= n * len(exchanges)
     if mode == "rule":
-        assert summary["exchange_pieces"] == n * n
+        assert summary["exchange_pieces"] == n
     kinds = summary["dispatches_by_kind"]
-    assert 0 < kinds["exchange.pid"] <= n * len(exchanges)
-    assert 0 < kinds["exchange.split"] <= summary["exchange_pieces"]
-    assert "batch.compact" not in kinds
+    # (the first call of a program is its compile, not a dispatch)
+    assert 0 < kinds["exchange.sort"] <= summary["exchange_pieces"]
+    assert kinds["exchange.sort"] <= kinds["exchange.pid"] \
+        <= n * len(exchanges)
+    assert "exchange.split" not in kinds and "batch.compact" not in kinds
+    # a read hands on one batch, so the coalescer above it has nothing to
+    # concatenate: no ``batch.concat`` is dispatched for an exchange (what
+    # is left are the broadcast builds' pulls, one a join at most)
+    for x in exchanges:
+        assert x["numOutputBatches"] == len(x["partitions"])
+        above = by_id[x["parent_id"]]
+        while "Coalesce" not in above["node"]:
+            above = by_id[above["parent_id"]]
+        assert above["numOutputBatches"] == x["numOutputBatches"]
+    assert kinds.get("batch.concat", 0) <= summary["broadcast_builds"]
 
 
 @pytest.mark.parametrize("n", LAYOUTS[1:])

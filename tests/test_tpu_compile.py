@@ -99,6 +99,36 @@ def test_compact_batch_sorts_only_what_orders(tpu_branch, rows):
     assert counts and max(counts) <= 2, counts
 
 
+@pytest.mark.parametrize("rows", [SMALL, 1 << 22])
+def test_exchange_sorts_a_map_batch_by_partition_id(tpu_branch, rows):
+    """``exchange.sort`` at the partial aggregate's bucket and at a fact
+    partition's: one one-operand sort of the packed (id, position), one
+    gather a plane."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec.exchange import sort_by_partition
+    batch = _batch(rows, T.LONG, T.DOUBLE, T.INT, T.STRING)
+    prog, specs = _compiles(tpu_branch, sort_by_partition, batch,
+                            jnp.zeros(rows, dtype=np.int32), 10)
+    assert prog.kind == "exchange.sort"
+    counts = TC.sort_operand_counts(prog, specs)
+    assert counts and max(counts) == 1, counts
+
+
+def test_exchange_reads_ten_pieces_at_the_bucket_of_their_rows(tpu_branch):
+    """``exchange.read`` as ``store_sf10_tasks`` runs it: ten pieces at the
+    32,768-row floor, some four thousand rows read."""
+    from spark_rapids_tpu.exec import exchange as X
+    pieces = []
+    for _ in range(10):
+        piece = X._SortedPiece(_batch(SMALL, T.INT, T.STRING, T.DOUBLE,
+                                      T.LONG))
+        piece.settle([410] * 10, 10)
+        pieces.append(piece)
+    prog, specs = _compiles(tpu_branch, X._read_rows, pieces, 0, 10, 10)
+    assert prog.kind == "exchange.read"
+    assert not TC.sort_operand_counts(prog, specs)
+
+
 def test_sized_stage_and_its_compact_terminal(tpu_branch):
     """The star cell's demographic stage at SF1's shapes (2,097,152 rows,
     a key and three strings, filter only): the stage program that hands
