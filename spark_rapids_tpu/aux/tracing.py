@@ -45,6 +45,7 @@ same in a crowd as alone.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import heapq
@@ -88,6 +89,47 @@ def last_query_summary() -> Optional[dict]:
     process."""
     with _LAST_LOCK:
         return _LAST_SUMMARY
+
+
+#: the most gap intervals a summary lists (``device_gaps.intervals``)
+MAX_GAP_INTERVALS = 512
+
+_CLOCK_LOCK = threading.Lock()
+_CLOCK_ANCHOR: Optional[dict] = None
+
+
+def clock_anchor() -> dict:
+    """The span tree's clock (``time.monotonic``) and the profiler's host
+    clock read together, once a process: ``{"monotonic_s", "wall_ns"}``.
+    A profiler trace stamps its host and device events with
+    ``time.time_ns()``, less its ``profile_start_time`` (a stat of the
+    xplane's ``Task Environment`` plane), so an instant ``t`` of a summary
+    lies at ``wall_ns + 1e9 * (t - monotonic_s) - profile_start_time``
+    nanoseconds of the trace."""
+    global _CLOCK_ANCHOR
+    with _CLOCK_LOCK:
+        if _CLOCK_ANCHOR is None:
+            m0 = time.monotonic_ns()
+            wall = time.time_ns()
+            m1 = time.monotonic_ns()
+            _CLOCK_ANCHOR = {"monotonic_s": (m0 + m1) / 2e9, "wall_ns": wall}
+        return _CLOCK_ANCHOR
+
+
+def _coverage(intervals):
+    """``measure(a, b)``: how much of ``[a, b]`` the sorted, disjoint
+    ``intervals`` cover (a bisection, not a scan)."""
+    starts = [s for s, _e in intervals]
+    ends = [e for _s, e in intervals]
+    before = list(itertools.accumulate(
+        (e - s for s, e in intervals), initial=0.0))
+
+    def upto(x):
+        i = bisect.bisect_right(starts, x)
+        return 0.0 if i == 0 else \
+            before[i - 1] + min(x, ends[i - 1]) - starts[i - 1]
+
+    return lambda a, b: upto(b) - upto(a) if b > a else 0.0
 
 
 def _nondefault_conf(conf) -> dict:
@@ -200,7 +242,6 @@ class QueryExecution:
                                          "exchange_rows": 0,
                                          "exchange_rows_padded": 0,
                                          "exchange_pieces": 0,
-                                         "exchange_read_rows_padded": 0,
                                          "exchange_host_staged_bytes": 0}
         #: what this query's own threads did, added by the layer that did
         #: it: steady dispatches (``exec/stage_compiler.py``), the
@@ -211,6 +252,11 @@ class QueryExecution:
         self._compile_s = 0.0
         self._ledger = dict(_LEDGER_ZERO)
         self._tasks = dict(_TASKS_ZERO)
+        #: the gateway's gap ledger, as far as it is this query's: the
+        #: device gaps its device work closed, ``(start, end, kind, site,
+        #: late)``, and its ``unplaced`` and ``eager_device_calls`` counts
+        self._gaps: List[tuple] = []
+        self._gap_counts = {"unplaced": 0, "eager_device_calls": 0}
         #: facts of the query that the layer above knows (:func:`note`):
         #: a served query's ``resolved`` and ``plan_cache``
         self.notes: Dict = {}
@@ -361,6 +407,19 @@ class QueryExecution:
             led[door + "_s"] += seconds
             if nbytes:
                 led[door + "_bytes"] += nbytes
+
+    def note_device_work(self, gap, end: float, unplaced: bool,
+                         eager: bool) -> None:
+        """Device work of this query closed ``gap`` (the gateway's open
+        gap, or None) at ``end``, found the device drained with none open
+        (``unplaced``), or was an eager call (``transitions.device_work``)."""
+        with self._lock:
+            if gap is not None:
+                start, site, fetcher, late = gap
+                kind = "fetch" if fetcher == self.query_id else "query"
+                self._gaps.append((start, end, kind, site, late))
+            self._gap_counts["unplaced"] += unplaced
+            self._gap_counts["eager_device_calls"] += eager
 
     def note_task(self, m) -> None:
         """A finished task's ``TaskMetrics`` (``memory/metrics.py``)."""
@@ -702,6 +761,8 @@ class QueryExecution:
         from spark_rapids_tpu.aux import transitions as TR
         if TR.enabled():
             summary["transitions"] = ledger
+            summary["device_gaps"] = self._device_gaps(now)
+        summary["clock"] = clock_anchor()
         # calibrated cost-model cross-check (report-only; docs/history.md):
         # predicted wall time from the tools/history machine profile vs
         # this query's measured duration, emitted before sinks close so
@@ -778,7 +839,7 @@ class QueryExecution:
     def _phase_spans(self) -> List[Span]:
         return self._spans_of("phase")
 
-    def _phase_self_times(self, now: float):
+    def _phase_self_times(self, now: float, within=None):
         """``({span name: self seconds}, early seconds)`` over the phase
         spans: a span's duration less the part that the phase spans
         inside it cover (the choosing-metrics rule).  Every instant goes
@@ -788,7 +849,13 @@ class QueryExecution:
         ``(unattributed)``, one before the root began to nothing.  The
         early seconds are what the adopted spans cover of the time before
         the root began, so the entries add up to the root's duration plus
-        those: ``duration_s``."""
+        those: ``duration_s``.
+
+        ``within`` (sorted, disjoint ``(start, end)`` intervals: the
+        query's device gaps) counts only the instants inside them, and
+        leaves none out: one with no span open goes to ``(between
+        queries)`` before the first phase span began (the root, where none
+        did) and to ``(unattributed)`` from then on."""
         lo, hi = self.root.start, (self.root.end if self.root.end
                                    is not None else now)
         spans = []      # (depth among phase spans, start, name)
@@ -811,26 +878,70 @@ class QueryExecution:
         with self._lock:
             walk(self.root, 0)
         edges.sort()
+        first = min([lo] + [start for _d, start, _n in spans])
+        if within is None:
+            at = lo
+
+            def measure(a, b):
+                return b - a
+
+            def none_open(a, b):
+                if b > lo:
+                    out["(unattributed)"] += b - max(a, lo)
+        else:
+            at = min([first] + [start for start, _e in within[:1]])
+            measure = _coverage(within)
+            out["(between queries)"] = 0.0
+
+            def none_open(a, b):
+                out["(between queries)"] += measure(a, min(b, first))
+                out["(unattributed)"] += measure(max(a, first), b)
         early = 0.0
         open_heap: List = []    # (-depth, -start, index): innermost first
         closed = set()
-        at = lo
         for t, opens, i in edges:
             while open_heap and open_heap[0][2] in closed:
                 heapq.heappop(open_heap)
             if open_heap:
                 name = spans[open_heap[0][2]][2]
-                out[name] = out.get(name, 0.0) + (t - at)
+                out[name] = out.get(name, 0.0) + measure(at, t)
                 early += max(0.0, min(t, lo) - at)
-            elif t > lo:
-                out["(unattributed)"] += t - max(at, lo)
+            else:
+                none_open(at, t)
             at = t
             if opens:
                 heapq.heappush(open_heap, (-spans[i][0], -spans[i][1], i))
             else:
                 closed.add(i)
-        out["(unattributed)"] += hi - max(at, lo)
+        none_open(at, hi)
         return {k: round(v, 6) for k, v in out.items()}, early
+
+    def _device_gaps(self, now: float) -> dict:
+        """The summary's ``device_gaps``: the gaps this query's device work
+        closed, by kind, by the span its threads were in (the phases'
+        rule, over the gaps' instants) and by the fetch that drained the
+        device, with the intervals themselves (the first
+        ``MAX_GAP_INTERVALS``)."""
+        with self._lock:
+            gaps = sorted(self._gaps)
+            counts = dict(self._gap_counts)
+        by_kind = {"fetch": 0.0, "query": 0.0}
+        by_site: Dict[str, float] = {}
+        for start, end, kind, site, _late in gaps:
+            by_kind[kind] += end - start
+            by_site[site] = by_site.get(site, 0.0) + end - start
+        by_span = self._phase_self_times(
+            now, within=[(g[0], g[1]) for g in gaps])[0] if gaps else {}
+        return {"fetch_s": round(by_kind["fetch"], 6),
+                "query_s": round(by_kind["query"], 6),
+                "count": len(gaps),
+                "late": sum(1 for g in gaps if g[4]),
+                **counts,
+                "by_span": {k: v for k, v in by_span.items() if v > 0},
+                "by_site": {k: round(v, 6) for k, v in by_site.items()},
+                "intervals": [[round(g[0], 6), round(g[1], 6), g[2], g[3]]
+                              for g in gaps[:MAX_GAP_INTERVALS]],
+                "intervals_dropped": max(0, len(gaps) - MAX_GAP_INTERVALS)}
 
     # -- rendering -----------------------------------------------------------
     def render_tree(self, show_partitions: bool = False) -> str:
@@ -890,7 +1001,6 @@ class QueryExecution:
                  "probe_gather_rounds", "sized_joins", "sized_stages",
                  "broadcast_builds", "exchanges", "exchange_rows",
                  "exchange_rows_padded", "exchange_pieces",
-                 "exchange_read_rows_padded",
                  "exchange_host_staged_bytes")
                 if k in summary))
         lines.append("== Query Summary ==")
@@ -914,6 +1024,12 @@ class QueryExecution:
                 f"({tr.get('d2h_bytes', 0)}B {tr.get('d2h_s', 0.0)}s) "
                 f"syncs={tr.get('sync_count', 0)} "
                 f"({tr.get('sync_s', 0.0)}s)")
+        gaps = summary.get("device_gaps")
+        if gaps:
+            lines.append("== Device gaps ==")
+            lines.append(" ".join(f"{k}={gaps[k]}" for k in (
+                "fetch_s", "query_s", "count", "late", "unplaced",
+                "eager_device_calls")))
         return "\n".join(lines)
 
 

@@ -28,6 +28,27 @@ optimization object.  Semantics:
   download is a sync too, but it is counted ONCE, as a transition;
   ``sync_count``/``sync_seconds`` cover only the non-transfer syncs.
 
+**The device-gap ledger** (same switch) books each interval in which the
+chip's queue is known to be empty.  Every program call hands the gateway
+its outputs (:func:`device_work`: ``StageProgram`` dispatches, the eager
+calls of ``ops/batch_ops.take_front``), and the smallest output leaf of
+the newest becomes "the newest": programs run in the order they are
+enqueued, whichever thread enqueued them, so the newest being ready means
+nothing is queued.  When a blocking wait returns (:func:`waited`: every
+sync wrapper here and the ``xfer.d2h`` doors of ``columnar/transfer.py``)
+and the newest is ready (a non-blocking ``is_ready()``), a gap opens then,
+with the fetch's site and the fetching query; if the newest was ready
+already when the wait began, the gap opens at the wait's entry and is
+``late`` (it may have begun earlier).  The next device work, from any
+thread, closes it when its call returns (the device cannot start a
+program before it has been handed over) and books it to the query whose
+work closed it (``QueryExecution.note_device_work``): ``fetch`` where that
+query made the draining fetch, ``query`` otherwise.  Device work that
+finds the newest ready with no gap open counts ``unplaced``: the device
+ran dry at an instant the program cannot know.  ``totals()`` keeps
+``gap_count`` / ``gap_seconds`` over the process, gaps closed outside any
+query included.  H2D uploads are not programs and are not seen.
+
 Conf (``spark.rapids.sql.transitions.*``) syncs through
 ``sync_from_conf`` at session construction / ``set_conf`` — the same
 process-singleton lifecycle as the sampler and lock-order validator.
@@ -66,9 +87,23 @@ class TransitionStats:
     d2h_seconds: float = 0.0
     sync_count: int = 0
     sync_seconds: float = 0.0
+    gap_count: int = 0
+    gap_seconds: float = 0.0
 
 
 _TOTAL = TransitionStats()
+
+
+@dataclasses.dataclass
+class _DeviceQueue:
+    """What the gap ledger knows of the device's queue (under ``_LOCK``)."""
+    #: the smallest output leaf of the newest device work
+    newest: object = None
+    #: the open gap: (start, fetch site, fetching query's id, late)
+    gap: Optional[tuple] = None
+
+
+_QUEUE = _DeviceQueue()
 
 
 def enabled() -> bool:
@@ -88,6 +123,8 @@ def totals() -> dict:
             "d2h_seconds": round(_TOTAL.d2h_seconds, 6),
             "sync_count": _TOTAL.sync_count,
             "sync_seconds": round(_TOTAL.sync_seconds, 6),
+            "gap_count": _TOTAL.gap_count,
+            "gap_seconds": round(_TOTAL.gap_seconds, 6),
         }
 
 
@@ -162,6 +199,90 @@ def _record_sync(site: str, duration_s: float,
 
 
 # ---------------------------------------------------------------------------
+# the device-gap ledger
+# ---------------------------------------------------------------------------
+
+def _ready(leaf) -> bool:
+    """Whether ``leaf``'s program has finished, without blocking; a deleted
+    array says nothing: not ready.  The caller holds ``_LOCK``, under which
+    the engine deletes arrays (:func:`delete_arrays`): jax's ``is_ready()``
+    of a deleted array crashes the process."""
+    return not leaf.is_deleted() and leaf.is_ready()
+
+
+def delete_arrays(arrays) -> None:
+    """Deletes device arrays (``arr.delete()``) under the gap ledger's
+    lock, so that a readiness check never meets a half-deleted array;
+    one already deleted or donated is skipped."""
+    with _LOCK:
+        for arr in arrays:
+            if arr is not None and hasattr(arr, "delete"):
+                try:
+                    arr.delete()
+                except Exception:   # noqa: BLE001 - already donated/deleted
+                    pass
+
+
+def _smallest_leaf(out):
+    """The smallest device array of ``out``: all of a program's outputs
+    become ready together, and holding the smallest keeps nothing large
+    alive."""
+    import jax
+    leaves = [x for x in jax.tree_util.tree_leaves(out)
+              if hasattr(x, "is_ready")]
+    return min(leaves, key=lambda x: x.size) if leaves else None
+
+
+def device_work(out, q, eager: bool = False) -> None:
+    """Device work has just been handed over (a program call by query
+    ``q``, or None outside one; ``eager``: eager ``jnp`` calls, not a stage
+    program): an open gap closes now, booked to ``q``; with none open, a
+    newest output already finished counts ``unplaced``; ``out``'s smallest
+    leaf becomes the newest."""
+    if not _ENABLED:
+        return
+    now = time.monotonic()
+    leaf = _smallest_leaf(out)
+    with _LOCK:
+        gap, _QUEUE.gap = _QUEUE.gap, None
+        unplaced = gap is None and _QUEUE.newest is not None \
+            and _ready(_QUEUE.newest)
+        if leaf is not None:
+            _QUEUE.newest = leaf
+        if gap is not None:
+            _TOTAL.gap_count += 1
+            _TOTAL.gap_seconds += now - gap[0]
+    if q is not None and (gap is not None or unplaced or eager):
+        q.note_device_work(gap, now, unplaced, eager)
+
+
+def waited(site: str, wait):
+    """``(wait(), seconds)``: one blocking wait on the device, watched for
+    the drain.  If the newest device work is ready when the wait returns,
+    the queue is empty: a gap opens then (at the wait's entry, ``late``,
+    where the newest was ready before it began), unless one is open."""
+    if not _ENABLED:
+        t0 = time.monotonic()
+        out = wait()
+        return out, time.monotonic() - t0
+    with _LOCK:
+        before = _QUEUE.newest
+        ready_before = before is not None and _ready(before)
+    t0 = time.monotonic()
+    out = wait()
+    t1 = time.monotonic()
+    with _LOCK:
+        newest = _QUEUE.newest if _QUEUE.gap is None else None
+        if newest is not None:
+            late = ready_before and newest is before
+            if late or _ready(newest):
+                q = EV.active_query()
+                _QUEUE.gap = (t0 if late else t1, site,
+                              q.query_id if q is not None else None, late)
+    return out, t1 - t0
+
+
+# ---------------------------------------------------------------------------
 # blocking-sync wrappers (THE sanctioned sync call sites; the sync-site
 # lint rule bans raw block_until_ready/jax.device_get elsewhere).  Each
 # is one ``xfer.sync`` span over the interval the ledger sums; the two
@@ -174,9 +295,7 @@ def _sync(site: str, wait, with_bytes: bool = False):
     """One blocking sync: an ``xfer.sync`` span over ``wait()`` and the
     same interval in the ledger."""
     with span("xfer.sync", site=site):
-        t0 = time.perf_counter()
-        out = wait()
-        dt = time.perf_counter() - t0
+        out, dt = waited(site, wait)
     _record_sync(site, dt, nbytes=out.nbytes if with_bytes else None)
     return out
 

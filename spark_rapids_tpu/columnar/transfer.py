@@ -464,9 +464,8 @@ def download_host_batch(cb, spec_rows=None) -> "object":
     # single packed buffer per round trip (ledger: duration is the true
     # blocking fetch — counted as a transition, not a sync)
     with span("xfer.d2h", site="download"):
-        t0_d2h = time.perf_counter()
-        buf = np.asarray(_pack_planes(planes, shrink, rc_traceable(rc)))
-        dt_d2h = time.perf_counter() - t0_d2h
+        buf, dt_d2h = TR.waited("download", lambda: np.asarray(
+            _pack_planes(planes, shrink, rc_traceable(rc))))
     TR.record_d2h(buf.nbytes, dt_d2h, site="download", planes=len(planes))
     fetched, n = _unpack_buffer(buf, planes, shrink)
     if deferred:
@@ -475,9 +474,8 @@ def download_host_batch(cb, spec_rows=None) -> "object":
         # speculation miss: fetch again at the exact size (one more trip)
         shrink = min(bucket, bucket_rows(max(n, 1), minimum=8))
         with span("xfer.d2h", site="download-miss"):
-            t0_d2h = time.perf_counter()
-            buf = np.asarray(_pack_planes(planes, shrink, n))
-            dt_d2h = time.perf_counter() - t0_d2h
+            buf, dt_d2h = TR.waited("download-miss", lambda: np.asarray(
+                _pack_planes(planes, shrink, n)))
         TR.record_d2h(buf.nbytes, dt_d2h, site="download-miss",
                       planes=len(planes))
         fetched, _ = _unpack_buffer(buf, planes, shrink)

@@ -294,7 +294,6 @@ def _read_rows(pieces: List[_SortedPiece], start: int, end: int,
     if total == 0:
         return None
     out_bucket = bucket_rows(total)
-    add_count("exchange_read_rows_padded", out_bucket)
     first = pieces[0].batch
     merged = _SortedPiece(
         _gather_rows(pieces, start, end, n, total, out_bucket)

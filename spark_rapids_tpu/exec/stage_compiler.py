@@ -43,6 +43,7 @@ import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from spark_rapids_tpu.aux import events as EV
+from spark_rapids_tpu.aux import transitions as TR
 from spark_rapids_tpu.aux.tracing import annotation, span
 
 __all__ = ["get_or_build", "stats", "reset_stats", "clear",
@@ -231,13 +232,15 @@ class StageProgram:
         """The steady path: a call of a program that is already built.
         One ``srt.dispatch`` annotation (no Span object: a query may make
         thousands) and two clock reads; counted once it has returned, in
-        the process's totals and in the query that made the call."""
+        the process's totals and in the query that made the call, and
+        handed to the gateway's gap ledger (``transitions.device_work``)."""
         q = EV.active_query()
         t0 = time.perf_counter()
         with annotation("dispatch", q, EV.current_span_id(),
                         kind=self.kind):
             out = fn(*args)
         dt = time.perf_counter() - t0
+        TR.device_work(out, q)
         with _LOCK:
             _STATS["dispatches"] += 1
             _STATS["dispatch_s"] += dt
@@ -285,6 +288,7 @@ class StageProgram:
                 # silently re-execute the program.
                 t0 = time.perf_counter()
                 out = self._fn(*args)
+                TR.device_work(out, EV.active_query())
                 with self._lock:
                     first_drift = not self._drifted
                     self._drifted = True
@@ -302,7 +306,9 @@ class StageProgram:
                     first = True
         if first:
             with span("compile.build", kind=self.kind, tier="jit"):
-                return self._first_dispatch(args)
+                out = self._first_dispatch(args)
+            TR.device_work(out, EV.active_query())
+            return out
         return self._dispatch(self._fn, args)
 
     def _first_dispatch(self, args):

@@ -123,17 +123,14 @@ def _attribution_tags() -> tuple:
 
 def _delete_device_batch(batch: ColumnarBatch) -> None:
     """Releases device buffers eagerly (reference: RapidsBuffer.free /
-    cudf close; jax arrays support explicit .delete())."""
-    for col in batch.columns:
-        # run_ends: RleColumn's extra plane; DICTIONARY value planes are
-        # shared process-wide and must never be deleted with a batch
-        for arr in (col.data, col.validity, col.lengths,
-                    getattr(col, "run_ends", None)):
-            if arr is not None and hasattr(arr, "delete"):
-                try:
-                    arr.delete()
-                except Exception:
-                    pass  # already donated/deleted
+    cudf close; jax arrays support explicit .delete()), through the
+    gateway, whose gap ledger asks arrays whether they are ready."""
+    from spark_rapids_tpu.aux.transitions import delete_arrays
+    # run_ends: RleColumn's extra plane; DICTIONARY value planes are
+    # shared process-wide and must never be deleted with a batch
+    delete_arrays([arr for col in batch.columns
+                   for arr in (col.data, col.validity, col.lengths,
+                               getattr(col, "run_ends", None))])
 
 
 class BufferCatalog:

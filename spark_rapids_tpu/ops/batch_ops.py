@@ -18,6 +18,8 @@ from typing import Sequence, Tuple
 import numpy as np
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.aux import events as EV
+from spark_rapids_tpu.aux import transitions as TR
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import (DeferredCount, DeviceColumn,
                                               bucket_rows, rc_traceable,
@@ -242,6 +244,9 @@ def take_front(batch: ColumnarBatch, n) -> ColumnarBatch:
     cols = [rewrap_like(c, c.data, c.validity & keep, n, c.lengths,
                         c.elem_valid)
             for c in batch.columns]
+    # eager calls, not a stage program: the gateway's gap ledger sees them
+    TR.device_work(cols[-1].validity if cols else keep, EV.active_query(),
+                   eager=True)
     return ColumnarBatch(cols, n, batch.names)
 
 

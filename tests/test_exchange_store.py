@@ -236,7 +236,6 @@ def test_other_data_at_the_same_shapes_builds_no_program():
         assert 0 < kinds["exchange.sort"] <= kinds["exchange.pid"]
         assert kinds["exchange.read"] > 0
         assert "exchange.split" not in kinds and "batch.concat" not in kinds
-        assert summary["exchange_read_rows_padded"] \
-            >= summary["exchange_rows"] > 0
+        assert summary["exchange_rows"] > 0
     finally:
         s.stop()
