@@ -29,6 +29,11 @@ the join can see in its input (``_TpuJoinCore._join_device``):
                 bucket x SPECULATIVE_PAIR_HEADROOM and no sync: there the
                 padding is cheap and the round trip is not
   replay / speculativeSizing.enabled=false: exactly, a fetch every join
+A build side tabulated as a direct-address table (``ops/join_ops.py``:
+one unique, narrowly spread integral key; counter ``direct_joins``) gives
+a probe row one pair at most: a small probe's table is its bucket with
+no headroom and no overflow flag, and with no non-equi ``condition`` the
+pairs are handed on as they come, dense, with no compaction.
 Outer joins append the unmatched probe rows and semi/anti joins answer
 per probe row, so those still hand on a probe-sized batch.
 
@@ -514,16 +519,23 @@ class _TpuJoinCore(_JoinBase):
                             aug_cache[enc_sig] = entry
                         if wkey not in entry[1]:
                             (build_aug, build_ords), _ = entry
-                            entry[1][wkey] = J.build_side(build_aug,
-                                                          build_ords, pk)
+                            entry[1][wkey] = J.build_side(
+                                build_aug, build_ords, pk, self.null_safe)
                 (build_aug, build_ords), built_by_widths = entry
                 built = built_by_widths[wkey]
                 lo, counts, offsets, total = J._probe_ranges(pk, built)
                 # the gathers a probe row made to find its range
-                add_count("probe_gather_rounds", J.PROBE_GATHER_ROUNDS)
+                add_count("probe_gather_rounds", built.gather_rounds)
+                direct = built.direct is not None
+                if direct:
+                    add_count("direct_joins", 1)
                 spec = speculation.active()
                 sized = probe_aug.bucket > COL.SIZED_MIN_BUCKET
-                if spec is not None and not sized:
+                if spec is not None and not sized and direct:
+                    # a direct probe row has one pair at most: the probe
+                    # bucket holds them all, and nothing can overflow
+                    out_bucket = verify_bucket = probe_aug.bucket
+                elif spec is not None and not sized:
                     # optimistic OUTPUT table = probe bucket (exact for
                     # the FK->PK joins that dominate star schemas: <=1
                     # build match per probe row), but candidates are
@@ -564,6 +576,7 @@ class _TpuJoinCore(_JoinBase):
                     probe_aug, probe_ords, built, self.null_safe, lo,
                     offsets, total, verify_bucket)
             else:
+                direct = False
                 l_idx, r_idx, keep, pair_bucket = J.cross_pairs(probe, build)
             probe_pay = probe
             build_pay = build
@@ -584,7 +597,14 @@ class _TpuJoinCore(_JoinBase):
                                            rows, None, n, names,
                                            out_bucket=probe.bucket)
                 continue
-            l, r, n = J.compact_pairs(l_idx, r_idx, keep)
+            if direct and self.condition is None:
+                # a direct table's pairs are dense already: the first
+                # ``total`` in probe order, all kept
+                l, r = l_idx, r_idx
+                n = total if isinstance(total, int) else \
+                    COL.DeferredCount(total)
+            else:
+                l, r, n = J.compact_pairs(l_idx, r_idx, keep)
             if use_hash and pair_bucket > out_bucket:
                 # speculative sizing only (a table sized by its fetched
                 # total IS its output bucket).  The post-verify overflow

@@ -28,6 +28,23 @@ so an equi-join becomes:
    unmatched rows for outer joins, or reduce to per-row match flags for
    semi/anti.
 
+Where the build side allows it, a DIRECT-ADDRESS table replaces steps 1-5
+(``build_side``; DuckDB's perfect hash join): one equi-join key,
+integral on both sides (byte, short, int, long, date, or a code-space
+key's int32 codes), not null-safe, whose live non-null build keys are
+unique and span ``max - min + 1 <= 2^k`` slots, ``k`` being what
+``_table_bits`` gives the hash path for the same two buckets, so the
+direct table is never larger than the table it replaces.  The build
+program scatters each live build row's index to ``table[key - min]``
+and returns ``min``, ``max`` and a duplicate flag, which the host
+fetches once a built side (site ``join-build``) to choose the path.  A
+probe row then makes ONE 32-bit gather, ``table[key - min]`` (the
+build row, or -1), so it has at most one pair: the pairs come out dense
+in probe order, with no false candidate to verify and nothing to
+compact.  Any other join (several keys, strings outside code space,
+floating keys, duplicate or too widely spread build keys, ``<=>``)
+takes the hash path.
+
 Nested-loop (cross / condition-only) joins reuse steps 4-6 with the
 candidate set = the full cartesian product of in-row positions.
 
@@ -161,14 +178,26 @@ _TABLE_FREE_BITS = 23
 #: more pairs for ``verify`` to drop, and the same rows kept.
 _TABLE_MAX_BITS = 27
 #: Gathers a probe row makes to find its candidate range (the counter
-#: ``probe_gather_rounds``): ``starts[b]`` and ``starts[b + 1]``.
+#: ``probe_gather_rounds``): ``starts[b]`` and ``starts[b + 1]``; a
+#: direct-address table's probe makes ``DIRECT_GATHER_ROUNDS``.
 PROBE_GATHER_ROUNDS = 2
+DIRECT_GATHER_ROUNDS = 1
+#: Key types whose value is one integral word, so that ``key - min``
+#: addresses a direct table (a code-space key arrives as INT codes).
+_DIRECT_TYPES = (T.ByteType, T.ShortType, T.IntegerType, T.LongType,
+                 T.DateType)
+_INT64_MIN, _INT64_MAX = np.iinfo(np.int64).min, np.iinfo(np.int64).max
 
 
 def _table_bits(bucket: int, probe_bucket: int) -> int:
     """``k``: the table over a ``bucket``-row build side that a
     ``probe_bucket``-row batch probes has ``2^k`` slots.  A function of
-    the two buckets alone, so of the programs' shapes."""
+    the two buckets alone, so of the programs' shapes.  A direct-address
+    table takes the same ``2^k`` slots and its keys' span is capped by
+    them: its probe's gather costs what the bucket-start table's did (flat
+    up to 2^23 slots), it never holds more bytes than the table it
+    replaces, and a span that varies with a query's literals never
+    changes a program's shape."""
     slots = max(_TABLE_LOAD * bucket,
                 min(_PROBE_LOAD * probe_bucket, 1 << _TABLE_FREE_BITS))
     return min(slots.bit_length() - 1, _TABLE_MAX_BITS)
@@ -197,22 +226,97 @@ def _bucket_starts(slot_sorted, live_sorted, k: int, jnp):
 
 @dataclasses.dataclass
 class BuiltSide:
-    """The build (hash) side, sorted by key hash — reusable across many
-    probe batches (reference: the build-side hash table in GpuHashJoin)."""
+    """The build side, tabulated for probing — reusable across many probe
+    batches (reference: the build-side hash table in GpuHashJoin).  The
+    hash path sorts it by key hash (``starts``, ``perm``); the direct path
+    addresses it by key (``direct``, ``bounds``) and has neither."""
     batch: ColumnarBatch          # original build batch
     key_ordinals: Tuple[int, ...]
     starts: object                # int32[2^k + 1]: _bucket_starts
     perm: object                  # int32[bucket]: sorted pos -> original row
     widths: List[int]             # string word widths agreed with probe side
+    direct: object = None         # int32[2^k]: build row of key - min, or -1
+    bounds: object = None         # int64[3] on device: min, max, duplicates
+
+    @property
+    def gather_rounds(self) -> int:
+        """Gathers a probe row makes (the counter ``probe_gather_rounds``)."""
+        return DIRECT_GATHER_ROUNDS if self.direct is not None \
+            else PROBE_GATHER_ROUNDS
 
 
 def build_side(batch: ColumnarBatch, key_ordinals: Sequence[int],
+               probe_key_cols: Sequence[DeviceColumn],
+               null_safe: Sequence[bool]) -> BuiltSide:
+    """Tabulates the build side: a direct-address table where the join's
+    key and the build side's keys allow one (module docstring), else the
+    hash path's bucket-start table."""
+    key_ordinals = tuple(key_ordinals)
+    kcols = [batch.columns[i] for i in key_ordinals]
+    if len(kcols) == 1 and not null_safe[0] and all(
+            isinstance(c.data_type, _DIRECT_TYPES)
+            for c in (kcols[0], probe_key_cols[0])):
+        built = _direct_side(batch, key_ordinals, probe_key_cols[0])
+        if built is not None:
+            return built
+    return _hash_side(batch, key_ordinals, probe_key_cols)
+
+
+def _direct_side(batch: ColumnarBatch, key_ordinals: Tuple[int, ...],
+                 probe_key: DeviceColumn) -> Optional[BuiltSide]:
+    """One program scatters each live build row's index to ``table[key -
+    min]`` over ``2^k`` slots and returns the live keys' ``min``, ``max``
+    and whether two rows share a key (a row that lost its slot to another
+    row: exact, and all a scatter needs); the host fetches the three once
+    (site ``join-build``).  ``None`` where the keys repeat or span more
+    than ``2^k`` (the span taken in Python ints, so no extreme key
+    overflows it): the caller takes the hash path."""
+    from spark_rapids_tpu.aux import transitions as TR
+    from spark_rapids_tpu.columnar.column import rc_traceable
+    jnp = _jx()
+    col = batch.columns[key_ordinals[0]]
+    bucket = col.bucket
+    k = _table_bits(bucket, probe_key.bucket)
+    key = ("direct", (_col_sig(col),), k)
+    def build():
+        import jax
+        slots = 1 << k
+
+        def run(data, valid, row_count):
+            rowpos = jnp.arange(bucket, dtype=np.int32)
+            live = valid & (rowpos < row_count)
+            x = data.astype(np.int64)
+            lo = jnp.min(jnp.where(live, x, _INT64_MAX))
+            hi = jnp.max(jnp.where(live, x, _INT64_MIN))
+            # key - min as uint64 is exact for every live key, whatever
+            # the span; a key past the slots is no live row's slot
+            off = jax.lax.bitcast_convert_type(x, np.uint64) - \
+                jax.lax.bitcast_convert_type(lo, np.uint64)
+            fits = live & (off < np.uint64(slots))
+            slot = jnp.where(fits, off, slots).astype(np.int32)
+            table = jnp.full(slots, -1, dtype=np.int32).at[slot].set(
+                rowpos, mode="drop")
+            lost = fits & (jnp.take(table, jnp.minimum(slot, slots - 1))
+                           != rowpos)
+            return table, jnp.stack([lo, hi, jnp.any(lost).astype(np.int64)])
+
+        return run
+    from spark_rapids_tpu.exec.stage_compiler import get_or_build
+    fn = get_or_build("join.build", key, build)
+    table, bounds = fn(col.data, col.validity, rc_traceable(batch.row_count))
+    lo, hi, dup = (int(v) for v in TR.fetch(bounds, site="join-build"))
+    if dup or hi - lo + 1 > 1 << k:
+        return None
+    return BuiltSide(batch, key_ordinals, None, None, [1], direct=table,
+                     bounds=bounds)
+
+
+def _hash_side(batch: ColumnarBatch, key_ordinals: Tuple[int, ...],
                probe_key_cols: Sequence[DeviceColumn]) -> BuiltSide:
     """Sorts the build side by key hash and tabulates where each slot of
     the hash's top bits starts (one jitted program)."""
     from spark_rapids_tpu.ops.sort_ops import lex_sort_perm
     jnp = _jx()
-    key_ordinals = tuple(key_ordinals)
     kcols = [batch.columns[i] for i in key_ordinals]
     widths = [max(_n_value_words(b), _n_value_words(p))
               for b, p in zip(kcols, probe_key_cols)]
@@ -249,7 +353,10 @@ def _probe_ranges(probe_keys: List[DeviceColumn], built: BuiltSide):
     """Per-probe-row candidate range in the sorted build side: the rows of
     the probe hash's slot, read from ``built.starts`` with
     ``PROBE_GATHER_ROUNDS`` 32-bit gathers and no search.
-    Returns (lo, counts, offsets, total) — total is the one host sync."""
+    Returns (lo, counts, offsets, total) — total is the one host sync.
+    Against a direct table ``lo`` is the build row (``_direct_probe``)."""
+    if built.direct is not None:
+        return _direct_probe(probe_keys[0], built)
     jnp = _jx()
     key = ("probe", tuple(_col_sig(c) for c in probe_keys),
            built.starts.shape, tuple(built.widths))
@@ -290,13 +397,77 @@ def _probe_ranges(probe_keys: List[DeviceColumn], built: BuiltSide):
     return lo, counts, offsets, total   # total: 0-d device (caller decides)
 
 
+def _direct_probe(probe_key: DeviceColumn, built: BuiltSide):
+    """``_probe_ranges`` against a direct table: a live probe row whose key
+    lies in ``[min, max]`` (tested before the subtraction, so no key
+    overflows it) reads ``row = table[key - min]``, one 32-bit gather, and
+    has one candidate where ``row >= 0``; that candidate is its pair.
+    Returns (row, counts, offsets, total): int32 counts and offsets (a
+    total never passes the probe's bucket), an int64 total."""
+    jnp = _jx()
+    key = ("direct_probe", _col_sig(probe_key), tuple(built.direct.shape))
+    def build():
+        import jax
+        bucket = probe_key.bucket
+
+        def run(data, valid, row_count, table, bounds):
+            # the named scopes are the hash path's names for the same phases
+            with jax.named_scope("lookup"):
+                x = data.astype(np.int64)
+                inrow = jnp.arange(bucket, dtype=np.int32) < row_count
+                hit = valid & inrow & (x >= bounds[0]) & (x <= bounds[1])
+                slot = (jnp.where(hit, x, bounds[0]) - bounds[0]).astype(
+                    np.int32)
+                row = jnp.where(hit, jnp.take(table, slot), -1)
+            with jax.named_scope("offsets"):
+                counts = (row >= 0).astype(np.int32)
+                offsets = prefix_sum(counts, jnp) - counts
+                return row, counts, offsets, jnp.sum(counts, dtype=np.int64)
+
+        return run
+    from spark_rapids_tpu.exec.stage_compiler import get_or_build
+    from spark_rapids_tpu.columnar.column import rc_traceable
+    fn = get_or_build("join.probe", key, build)
+    return fn(probe_key.data, probe_key.validity,
+              rc_traceable(probe_key.row_count), built.direct, built.bounds)
+
+
+def _direct_pairs(rows, offsets, total, out_bucket: int):
+    """``_expand_verify`` against a direct table: pair ``r`` is the probe
+    row ``p`` whose offset is ``r`` and its build row ``rows[p]``, one
+    gather a pair; every candidate is a pair, so nothing is verified and
+    the kept pairs are the first ``total``, in probe order."""
+    jnp = _jx()
+    p_bucket = int(rows.shape[0])
+    key = ("direct_pairs", out_bucket, p_bucket)
+    def build():
+        import jax
+
+        def run(rows, offsets, total):
+            with jax.named_scope("expand"):
+                r = jnp.arange(out_bucket, dtype=np.int32)
+                p = jnp.clip(expand_positions(offsets, out_bucket, jnp), 0,
+                             p_bucket - 1)
+                b = jnp.take(rows, p)
+                return p.astype(np.int64), b.astype(np.int64), r < total
+
+        return run
+    from spark_rapids_tpu.exec.stage_compiler import get_or_build
+    fn = get_or_build("join.pair", key, build)
+    l_idx, r_idx, keep = fn(rows, offsets, total)
+    return l_idx, r_idx, keep, out_bucket
+
+
 def _expand_verify(probe: ColumnarBatch, probe_ordinals, built: BuiltSide,
                    null_safe: Tuple[bool, ...], lo, offsets, total,
                    out_bucket: int):
     """Expands candidate ranges to a padded pair table and verifies true key
     equality.  Returns (l_idx, r_idx, keep, pair_bucket).  ``total`` may be
     a 0-d device scalar (speculative sizing: caller picked ``out_bucket``
-    and tracks overflow via ops/speculation.py) or a host int (exact)."""
+    and tracks overflow via ops/speculation.py) or a host int (exact).
+    Against a direct table nothing needs verifying (``_direct_pairs``)."""
+    if built.direct is not None:
+        return _direct_pairs(lo, offsets, total, out_bucket)
     jnp = _jx()
     pkeys = [probe.columns[i] for i in probe_ordinals]
     bkeys = [built.batch.columns[i] for i in built.key_ordinals]

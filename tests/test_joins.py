@@ -164,24 +164,28 @@ def test_join_duplicate_key_explosion(sizing, copies, replays):
 #: under it speculatively, with no fetch.  Each case: probe rows, matches a
 #: probe row finds (a fraction: the share of rows that find one), and what
 #: the query's summary and the gateway must then read: ``sized_joins``,
-#: ``pair_rows_padded``, the join's output bucket, syncs by site
+#: ``direct_joins``, ``pair_rows_padded``, the join's output bucket, syncs
+#: by site.  A dimension of unique keys is a direct table (its build fetch
+#: is site ``join-build``): a small probe's pair table is its bucket, with
+#: no overflow flag for the collect to check
 SIZED_CASES = {
     # 65,536-row probe bucket, a hundredth of the rows join: the floor
     "over-the-floor-selective": (50_000, 0.01, dict(
-        sized=1, pair_rows=SIZED_FLOOR, out_bucket=SIZED_FLOOR,
-        syncs={"join-size": 1})),
+        sized=1, direct=1, pair_rows=SIZED_FLOOR, out_bucket=SIZED_FLOOR,
+        syncs={"join-build": 1, "join-size": 1})),
     # 200,000 pairs from a 65,536-row probe bucket: past bucket x headroom,
-    # which a speculative join answers by running the whole query twice
+    # which a speculative join answers by running the whole query twice;
+    # four build rows a key, so the hash path
     "over-the-floor-fan-out": (50_000, 4, dict(
-        sized=1, pair_rows=1 << 18, out_bucket=1 << 18,
-        syncs={"join-size": 1})),
-    # a probe bucket AT the floor: no fetch, bucket x headroom, as ever
+        sized=1, direct=0, pair_rows=1 << 18, out_bucket=1 << 18,
+        syncs={"join-build": 1, "join-size": 1})),
+    # a probe bucket AT the floor: no fetch of the pair count
     "at-the-floor": (30_000, 0.01, dict(
-        sized=0, pair_rows=2 * SIZED_FLOOR, out_bucket=SIZED_FLOOR,
-        syncs={"speculation-overflow": 1})),
+        sized=0, direct=1, pair_rows=SIZED_FLOOR, out_bucket=SIZED_FLOOR,
+        syncs={"join-build": 1})),
     "small": (3_000, 0.01, dict(
-        sized=0, pair_rows=2 * 4096, out_bucket=4096,
-        syncs={"speculation-overflow": 1})),
+        sized=0, direct=1, pair_rows=4096, out_bucket=4096,
+        syncs={"join-build": 1})),
 }
 
 
@@ -231,22 +235,25 @@ def test_join_sized_by_what_its_probe_bucket_says(case, syncs_by_site):
     summary = tracing.last_query_summary()
     assert summary["speculation_replays"] == 0
     assert summary["sized_joins"] == want["sized"]
+    assert summary["direct_joins"] == want["direct"]
     assert summary["pair_rows_padded"] == want["pair_rows"]
     assert _join_buckets(summary) == [want["out_bucket"]]
     # the sized join pays its one fetch and registers no overflow flag,
-    # so the collect has none to check; a speculative one pays that check
+    # so the collect has none to check; nor does a direct one
     assert syncs_by_site == want["syncs"]
-    assert summary["transitions"]["sync_count"] == 1
+    assert summary["transitions"]["sync_count"] == sum(want["syncs"].values())
 
 
 @pytest.mark.parametrize("first_keeps, handed_on, sized, pair_rows, syncs", [
     # the first join keeps 500 of 50,000 rows and hands on the floor's
-    # bucket: the second probes at 32,768 rows, speculatively
-    (0.01, SIZED_FLOOR, 1, SIZED_FLOOR + 2 * SIZED_FLOOR,
-     {"join-size": 1, "speculation-overflow": 1}),
+    # bucket: the second probes at 32,768 rows, speculatively, and its
+    # direct table (five unique keys) leaves it no overflow to flag
+    (0.01, SIZED_FLOOR, 1, SIZED_FLOOR + SIZED_FLOOR,
+     {"join-build": 2, "join-size": 1}),
     # the first keeps 40,000 and hands on 65,536 rows: the second is over
     # the floor too, and keeps a tenth of them
-    (0.8, 1 << 16, 2, (1 << 16) + SIZED_FLOOR, {"join-size": 2}),
+    (0.8, 1 << 16, 2, (1 << 16) + SIZED_FLOOR,
+     {"join-build": 2, "join-size": 2}),
 ], ids=["second-at-the-floor", "second-over-the-floor"])
 def test_chained_joins_probe_at_the_bucket_the_first_hands_on(
         first_keeps, handed_on, sized, pair_rows, syncs, syncs_by_site):
@@ -495,7 +502,7 @@ def test_probe_ranges_contain_the_searched_ranges(case):
     from spark_rapids_tpu.ops import join_ops as J
     bkeys, b_bucket, pkeys, p_bucket = PROBE_CASES[case]
     bcol, pcol = _long_column(bkeys, b_bucket), _long_column(pkeys, p_bucket)
-    built = J.build_side(ColumnarBatch([bcol], len(bkeys), ["k"]), (0,),
+    built = J._hash_side(ColumnarBatch([bcol], len(bkeys), ["k"]), (0,),
                          [pcol])
     lo, counts, offsets, total = (np.asarray(a) for a in
                                   J._probe_ranges([pcol], built))

@@ -21,6 +21,8 @@ from tests.asserts import tpu_session
 JOIN_AGG = ("SELECT name, SUM(qty) AS q FROM sales JOIN dim "
             "ON sales.sk = dim.sk GROUP BY name ORDER BY q DESC, name "
             "LIMIT 5")
+#: a join whose build side repeats its keys: the hash path
+SELF_JOIN = "SELECT COUNT(*) AS c FROM sales a JOIN sales b ON a.sk = b.sk"
 
 
 def _star_session(extra=None):
@@ -240,13 +242,19 @@ def test_the_hash_join_notes_the_gathers_of_its_probes():
     ``join.probe`` programs were built with, two a probed batch (the
     bucket-start table's ``starts[b]`` and ``starts[b + 1]``) where a
     binary search made two a round."""
-    from spark_rapids_tpu.ops.join_ops import PROBE_GATHER_ROUNDS
+    from spark_rapids_tpu.ops.join_ops import (DIRECT_GATHER_ROUNDS,
+                                               PROBE_GATHER_ROUNDS)
     s = _star_session()
-    s.sql(JOIN_AGG).collect()
-    summary = TR.last_query_summary()
-    probes = summary["dispatches_by_kind"]["join.probe"]
-    assert probes >= 1 and PROBE_GATHER_ROUNDS == 2
-    assert summary["probe_gather_rounds"] == PROBE_GATHER_ROUNDS * probes
+    for text, direct in ((JOIN_AGG, True), (SELF_JOIN, False)):
+        for _ in range(2):      # a program's first call is its compile
+            s.sql(text).collect()
+        summary = TR.last_query_summary()
+        probes = summary["dispatches_by_kind"]["join.probe"]
+        assert probes >= 1 and PROBE_GATHER_ROUNDS == 2
+        # ``dim``'s keys are unique: one gather of its direct table
+        rounds = DIRECT_GATHER_ROUNDS if direct else PROBE_GATHER_ROUNDS
+        assert summary["direct_joins"] == (probes if direct else 0)
+        assert summary["probe_gather_rounds"] == rounds * probes
 
 
 def test_spans_outside_a_query_only_annotate():
@@ -371,6 +379,10 @@ def test_the_xplane_names_programs_by_kind(xplane):
 SCOPES = {"join.probe": ("hash", "lookup", "offsets"),
           "join.pair": ("expand", "verify"),
           "fused.agg_update": ("keys", "update")}
+#: the same kinds' programs against a direct-address table, which has no
+#: hash to take and no candidate to verify
+DIRECT_SCOPES = {"join.probe": ("lookup", "offsets"),
+                 "join.pair": ("expand",)}
 
 
 @pytest.fixture(scope="module")
@@ -388,7 +400,9 @@ def lowered():
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(SC.StageProgram, "__call__", recording)
-        _star_session().sql(JOIN_AGG).collect()
+        s = _star_session()
+        s.sql(JOIN_AGG).collect()
+        s.sql(SELF_JOIN).collect()
     return texts
 
 
@@ -399,8 +413,13 @@ def test_named_scopes_name_the_phases_inside_a_program(kind, lowered):
     lowered program as the device trace shows it."""
     import re
     assert lowered.get(kind), sorted(lowered)
+    every = set()
     for text in lowered[kind]:
         seen = set(re.findall(
             r'"jit\(run\[' + re.escape(kind) + r'\]\)/(?:jit\(main\)/)?'
             r'([a-z]+)/', text))
-        assert set(SCOPES[kind]) <= seen, (kind, sorted(seen))
+        assert set(DIRECT_SCOPES.get(kind, SCOPES[kind])) <= seen, \
+            (kind, sorted(seen))
+        every |= seen
+    # the hash path's programs (the self-join's) name all of theirs
+    assert set(SCOPES[kind]) <= every, (kind, sorted(every))

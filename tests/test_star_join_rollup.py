@@ -110,21 +110,25 @@ def test_the_fan_out_runs_at_the_live_rows_size(star):
 def test_one_sized_join_a_query_and_its_syncs(star, q):
     """At a twentieth of SF1 the first join keeps some 2,000 rows: it is
     the one probe over the floor (one fetch, site ``join-size``), the
-    other three probe at the floor's bucket and speculate (their flags
-    cost the collect one check), and the fan-out forces no count.  Two
-    filtered dimensions are over the floor, ``customer_demographics``
-    (96,040 rows) and ``date_dim`` (73,049 at every scale), both in a
-    131,072-row bucket: each stage fetches its live count (site
-    ``stage-size``) and hands the join a build side at the floor's
-    bucket."""
+    other three probe at the floor's bucket, and the fan-out forces no
+    count.  Every join is a fact-to-dimension join on a unique key: four
+    direct tables, each built with one fetch (site ``join-build``), so
+    the three small probes' pair tables are their buckets and flag no
+    overflow for the collect to check.  Two filtered dimensions are over
+    the floor, ``customer_demographics`` (96,040 rows) and ``date_dim``
+    (73,049 at every scale), both in a 131,072-row bucket: each stage
+    fetches its live count (site ``stage-size``) and hands the join a
+    build side at the floor's bucket."""
     _, _, runs = star
     for run in (r for r in runs if r["q"] == q):
         s = run["summary"]
         assert s["sized_joins"] == 1
-        assert s["pair_rows_padded"] == (1 + 3 * 2) * SIZED_MIN_BUCKET
+        assert s["direct_joins"] == 4
+        assert s["probe_gather_rounds"] == 4
+        assert s["pair_rows_padded"] == (1 + 3) * SIZED_MIN_BUCKET
         assert s["speculation_replays"] == 0
         assert s["sized_stages"] == 2
-        assert s["transitions"]["sync_count"] == 2 + 2
+        assert s["transitions"]["sync_count"] == 1 + 4 + 2
 
 
 def test_a_query_without_grouping_sets_counts_no_fan_out(star):

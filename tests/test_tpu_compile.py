@@ -185,13 +185,13 @@ def test_join_build_probe_and_pairs(tpu_branch):
     from spark_rapids_tpu.ops import join_ops as J
     build = _batch(LARGE, T.LONG, T.DOUBLE)
     probe = _batch(1 << 19, T.LONG, T.INT)
-    prog, specs = _compiles(tpu_branch, J.build_side, build, (0,),
+    prog, specs = _compiles(tpu_branch, J._hash_side, build, (0,),
                             [probe.columns[0]])
     assert max(TC.sort_operand_counts(prog, specs)) <= 2
     # a dimension at the floor's bucket under the fact table's: the
     # probe's bucket widens the table to 2^23 slots
     fact = _batch(1 << 22, T.LONG)
-    _compiles(tpu_branch, J.build_side, _batch(SMALL, T.LONG, T.DOUBLE),
+    _compiles(tpu_branch, J._hash_side, _batch(SMALL, T.LONG, T.DOUBLE),
               (0,), [fact.columns[0]])
     built = J.BuiltSide(
         build, (0,),
@@ -203,6 +203,34 @@ def test_join_build_probe_and_pairs(tpu_branch):
     ranges = jnp.zeros(1 << 19, dtype=np.int64)
     _compiles(tpu_branch, J._expand_verify, probe, (0,), built, (False,),
               ranges, ranges, jnp.int64(0), LARGE)
+
+
+def test_direct_join_build_probe_and_pairs(tpu_branch):
+    """The direct-address path at ``store_scan_agg``'s shapes: a dimension
+    at the floor's bucket under the fact table's 4,194,304 rows (a 2^23-
+    slot table), a q3's 524,288-row pair table.  No program sorts."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import join_ops as J
+    fact = _batch(1 << 22, T.LONG)
+    dim = _batch(SMALL, T.LONG, T.DOUBLE)
+    prog, specs = _compiles(tpu_branch, J.build_side, dim, (0,),
+                            [fact.columns[0]], (False,))
+    assert prog.kind == "join.build"
+    assert not TC.sort_operand_counts(prog, specs)
+    slots = 1 << J._table_bits(SMALL, 1 << 22)
+    assert slots == 1 << 23
+    built = J.BuiltSide(dim, (0,), None, None, [1],
+                        direct=jnp.zeros(slots, dtype=np.int32),
+                        bounds=jnp.zeros(3, dtype=np.int64))
+    prog, specs = _compiles(tpu_branch, J._probe_ranges, [fact.columns[0]],
+                            built)
+    assert prog.kind == "join.probe"
+    assert not TC.sort_operand_counts(prog, specs)
+    rows = jnp.zeros(1 << 22, dtype=np.int32)
+    prog, specs = _compiles(tpu_branch, J._expand_verify, fact, (0,), built,
+                            (False,), rows, rows, 400_000, 1 << 19)
+    assert prog.kind == "join.pair"
+    assert not TC.sort_operand_counts(prog, specs)
 
 
 def test_window_over_partition(tpu_branch):
