@@ -169,11 +169,23 @@ class _DropCell:
 _DROP_LOCK = threading.Lock()
 _RETIRED_DROPS = 0
 _LIVE_DROP_CELLS: set = set()
+#: cells whose ring was collected, not yet folded into ``_RETIRED_DROPS``
+_DEAD_DROP_CELLS: list = []
 
 
 def _retire_drop_cell(cell: _DropCell) -> None:
+    # the ring's finalizer takes no lock: a garbage collection can run it
+    # on a thread that already holds ``_DROP_LOCK``; the cell stays in
+    # ``_LIVE_DROP_CELLS``, and so in the total, until it is folded
+    _DEAD_DROP_CELLS.append(cell)
+
+
+def _fold_dead_cells() -> None:
+    """Moves collected rings' counts into the retired total; under
+    ``_DROP_LOCK``."""
     global _RETIRED_DROPS
-    with _DROP_LOCK:
+    while _DEAD_DROP_CELLS:
+        cell = _DEAD_DROP_CELLS.pop()
         _LIVE_DROP_CELLS.discard(cell)
         _RETIRED_DROPS += cell.n
 
@@ -184,6 +196,7 @@ def ring_dropped_total() -> int:
     profiler surface so a silently-trimmed buffer is never mistaken for
     'nothing happened'."""
     with _DROP_LOCK:
+        _fold_dead_cells()
         return _RETIRED_DROPS + sum(c.n for c in _LIVE_DROP_CELLS)
 
 
@@ -199,6 +212,7 @@ class RingBufferSink(EventSink):
         self._lock = threading.Lock()
         self._drop_cell = _DropCell()
         with _DROP_LOCK:
+            _fold_dead_cells()
             _LIVE_DROP_CELLS.add(self._drop_cell)
         weakref.finalize(self, _retire_drop_cell, self._drop_cell)
 

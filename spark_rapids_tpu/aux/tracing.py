@@ -237,6 +237,7 @@ class QueryExecution:
                                          "probe_gather_rounds": 0,
                                          "sized_joins": 0,
                                          "direct_joins": 0,
+                                         "join_cols_pruned": 0,
                                          "sized_stages": 0,
                                          "broadcast_builds": 0,
                                          "exchanges": 0,
@@ -1000,8 +1001,8 @@ class QueryExecution:
                 ("dispatches", "dispatch_s", "speculation_replays",
                  "pair_rows_padded", "expand_rows_padded",
                  "probe_gather_rounds", "sized_joins", "direct_joins",
-                 "sized_stages", "broadcast_builds", "exchanges",
-                 "exchange_rows",
+                 "join_cols_pruned", "sized_stages", "broadcast_builds",
+                 "exchanges", "exchange_rows",
                  "exchange_rows_padded", "exchange_pieces",
                  "exchange_host_staged_bytes")
                 if k in summary))
@@ -1158,9 +1159,9 @@ def add_count(name: str, n: int = 1) -> None:
     """Adds to a per-query counter of the active query's summary
     (``speculation_replays``, ``pair_rows_padded``,
     ``expand_rows_padded``, ``probe_gather_rounds``, ``sized_joins``,
-    ``direct_joins``, ``sized_stages``, ``broadcast_builds``,
-    ``exchanges`` and the exchange's ``exchange_*``), where the work
-    happens."""
+    ``direct_joins``, ``join_cols_pruned``, ``sized_stages``,
+    ``broadcast_builds``, ``exchanges`` and the exchange's
+    ``exchange_*``), where the work happens."""
     q = EV.active_query()
     if q is not None:
         q.add_count(name, n)

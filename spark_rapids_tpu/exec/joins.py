@@ -120,7 +120,8 @@ class _JoinBase(BinaryExec):
     def __init__(self, left_keys: Sequence[Expression],
                  right_keys: Sequence[Expression], join_type: str,
                  condition: Optional[Expression], left: Exec, right: Exec,
-                 null_safe: Optional[Sequence[bool]] = None):
+                 null_safe: Optional[Sequence[bool]] = None,
+                 selection: Optional[Sequence[int]] = None):
         super().__init__(left, right)
         # struct-CONSTRUCTOR key pairs decompose into field-wise NULL-SAFE
         # pairs (Spark struct equality semantics; constructors are never
@@ -132,6 +133,12 @@ class _JoinBase(BinaryExec):
         self.join_type = join_type
         self.condition = condition
         self.null_safe = tuple(nss)
+        #: the output columns, as ordinals over ``left ++ right`` (semi
+        #: and anti joins: over ``left``) in output order; ``None``: all
+        #: of them.  Column pruning (plan/pruning.py) narrows it to what
+        #: the consumers read: keys and filter-only columns are read from
+        #: the children and not gathered
+        self.selection = None if selection is None else tuple(selection)
         if len(self.left_keys) != len(self.right_keys):
             raise ValueError("left/right key counts differ")
         for lk, rk in zip(self.left_keys, self.right_keys):
@@ -145,18 +152,32 @@ class _JoinBase(BinaryExec):
                     "is not supported (arrays/structs/maps join as "
                     "payload, not keys)")
 
-    @property
-    def schema(self) -> T.StructType:
+    def _pair_fields(self) -> List[T.StructField]:
+        """The fields of ``left ++ right`` as the join emits them (semi
+        and anti joins: ``left``'s)."""
         ls, rs = self.left.schema, self.right.schema
         if self.join_type in (J.LEFT_SEMI, J.LEFT_ANTI):
-            return ls
+            return list(ls.fields)
         lnull = self.join_type in (J.RIGHT_OUTER, J.FULL_OUTER)
         rnull = self.join_type in (J.LEFT_OUTER, J.FULL_OUTER)
         fields = [T.StructField(f.name, f.data_type, f.nullable or lnull)
                   for f in ls.fields]
         fields += [T.StructField(f.name, f.data_type, f.nullable or rnull)
                    for f in rs.fields]
+        return fields
+
+    @property
+    def schema(self) -> T.StructType:
+        fields = self._pair_fields()
+        if self.selection is not None:
+            fields = [fields[o] for o in self.selection]
         return T.StructType(fields)
+
+    @property
+    def cols_pruned(self) -> int:
+        """The columns of ``left ++ right`` the join does not hand on."""
+        return 0 if self.selection is None else \
+            len(self._pair_fields()) - len(self.selection)
 
     @property
     def _out_names(self) -> List[str]:
@@ -247,27 +268,36 @@ def _cpu_key_pairs(lkb: HostColumnarBatch, rkb: HostColumnarBatch,
 
 
 def _take_with_nulls(hb: HostColumnarBatch, idx: np.ndarray,
-                     names: List[str]):
-    """Arrow take where a negative index produces an all-null row."""
+                     cols: Sequence[int]):
+    """Arrow take of the columns ``cols`` where a negative index produces
+    an all-null row."""
     import pyarrow as pa
+    if not cols:
+        return []
     mask = idx < 0
     safe = np.where(mask, 0, idx)
     indices = pa.array(safe, mask=mask)
-    tab = pa.Table.from_arrays([c.arrow for c in hb.columns],
-                               names=[f"c{i}" for i in
-                                      range(hb.num_columns)])
-    taken = tab.take(indices)
-    cols = [batch_from_arrow(taken).columns[i]
-            for i in range(hb.num_columns)]
-    return cols
+    tab = pa.Table.from_arrays([hb.columns[i].arrow for i in cols],
+                               names=[f"c{i}" for i in range(len(cols))])
+    return list(batch_from_arrow(tab.take(indices)).columns)
 
 
 def _cpu_assemble(left: HostColumnarBatch, right: HostColumnarBatch,
-                  lmap: np.ndarray, rmap: np.ndarray,
-                  names: List[str]) -> HostColumnarBatch:
-    cols = _take_with_nulls(left, lmap, names) + \
-        _take_with_nulls(right, rmap, names)
-    return HostColumnarBatch(cols, len(lmap), names)
+                  lmap: np.ndarray, rmap: np.ndarray, names: List[str],
+                  selection: Optional[Sequence[int]] = None
+                  ) -> HostColumnarBatch:
+    """The output rows of ``left ++ right`` by the two maps: the columns
+    ``selection`` names (ordinals over ``left ++ right``, in output order;
+    ``None``: all of them)."""
+    nl = left.num_columns
+    if selection is None:
+        selection = range(nl + right.num_columns)
+    lsel = [o for o in selection if o < nl]
+    rsel = [o for o in selection if o >= nl]
+    taken = dict(zip(lsel + rsel,
+                     _take_with_nulls(left, lmap, lsel) +
+                     _take_with_nulls(right, rmap, [o - nl for o in rsel])))
+    return HostColumnarBatch([taken[o] for o in selection], len(lmap), names)
 
 
 class _CpuJoinCore(_JoinBase):
@@ -307,13 +337,16 @@ class _CpuJoinCore(_JoinBase):
             keep = self._pair_condition_keep(left, right, lidx, ridx)
             lidx, ridx = lidx[keep], ridx[keep]
         names = self._out_names
+        sel = self.selection
         if jt in (J.INNER, J.CROSS):
-            return _cpu_assemble(left, right, lidx, ridx, names)
+            return _cpu_assemble(left, right, lidx, ridx, names, sel)
         if jt in (J.LEFT_SEMI, J.LEFT_ANTI):
             matched = np.zeros(nl, dtype=bool)
             matched[lidx] = True
             rows = np.flatnonzero(matched if jt == J.LEFT_SEMI else ~matched)
-            cols = _take_with_nulls(left, rows.astype(np.int64), names)
+            cols = _take_with_nulls(left, rows.astype(np.int64),
+                                    range(left.num_columns) if sel is None
+                                    else sel)
             return HostColumnarBatch(cols, len(rows), names)
         parts_l, parts_r = [lidx], [ridx]
         if jt in (J.LEFT_OUTER, J.FULL_OUTER):
@@ -330,7 +363,7 @@ class _CpuJoinCore(_JoinBase):
             parts_r.append(ur)
         lmap = np.concatenate(parts_l)
         rmap = np.concatenate(parts_r)
-        return _cpu_assemble(left, right, lmap, rmap, names)
+        return _cpu_assemble(left, right, lmap, rmap, names, sel)
 
 
 # ---------------------------------------------------------------------------
@@ -465,6 +498,10 @@ class _TpuJoinCore(_JoinBase):
         from spark_rapids_tpu.columnar import encoding as ENC
         jt = self.join_type
         names = self._out_names
+        # the columns gathered, over left ++ right; every other column of
+        # the children is read (keys, the condition) and not handed on
+        sel = self.selection
+        pruned = self.cols_pruned
         ls, rs = self.left.schema, self.right.schema
         probe_keys = self.right_keys if swapped else self.left_keys
         build_keys = self.left_keys if swapped else self.right_keys
@@ -491,6 +528,9 @@ class _TpuJoinCore(_JoinBase):
         for probe in probe_batches:
             if _known_empty(probe.row_count):
                 continue
+            if pruned:
+                # one output batch a probe batch
+                add_count("join_cols_pruned", pruned)
             probe = ENC.materialize_rle_batch(probe, site="join")
             if use_hash:
                 # a key joins in code space only when BOTH sides carry
@@ -595,7 +635,7 @@ class _TpuJoinCore(_JoinBase):
                     rows, n = J.unmatched_positions(~flags, probe.row_count)
                 yield J.gather_join_output(probe_pay, empty_right,
                                            rows, None, n, names,
-                                           out_bucket=probe.bucket)
+                                           out_bucket=probe.bucket, cols=sel)
                 continue
             if direct and self.condition is None:
                 # a direct table's pairs are dense already: the first
@@ -627,14 +667,17 @@ class _TpuJoinCore(_JoinBase):
                 lmap, rmap, total_out, ob = J.concat_matched_unmatched(
                     l, r, n, ul, un)
                 yield J.gather_join_output(probe_pay, build_pay, lmap, rmap,
-                                           total_out, names, out_bucket=ob)
+                                           total_out, names, out_bucket=ob,
+                                           cols=sel)
             elif swapped:
                 # emit left-then-right: build side IS the left child here
                 yield J.gather_join_output(build_pay, probe_pay, r, l, n,
-                                           names, out_bucket=pair_bucket)
+                                           names, out_bucket=pair_bucket,
+                                           cols=sel)
             else:
                 yield J.gather_join_output(probe_pay, build_pay, l, r, n,
-                                           names, out_bucket=pair_bucket)
+                                           names, out_bucket=pair_bucket,
+                                           cols=sel)
         # outer-join: unmatched build rows after the probe stream drains
         if jt in (J.RIGHT_OUTER, J.FULL_OUTER):
             if build_matched is None:
@@ -643,8 +686,11 @@ class _TpuJoinCore(_JoinBase):
                 build_matched = jnp.zeros(build.bucket, dtype=bool)
             ub, un = J.unmatched_positions(build_matched, build.row_count)
             probe_empty = _empty_device(ls)
+            if pruned:
+                add_count("join_cols_pruned", pruned)
             yield J.gather_join_output(probe_empty, build, None, ub, un,
-                                       names, out_bucket=build.bucket)
+                                       names, out_bucket=build.bucket,
+                                       cols=sel)
 
 
 # ---------------------------------------------------------------------------
@@ -838,7 +884,8 @@ def _reg(cpu_cls, tpu_cls, desc):
         cpu_cls,
         convert=lambda p, m: tpu_cls(p.left_keys, p.right_keys, p.join_type,
                                      p.condition, p.children[0],
-                                     p.children[1], p.null_safe),
+                                     p.children[1], p.null_safe,
+                                     p.selection),
         sig=TS.BASIC_WITH_ARRAYS,
         exprs_of=_join_exprs,
         extra_tag=_tag_join_keys,
@@ -852,7 +899,7 @@ def _convert_shuffled(p, m):
     out = TpuSubPartitionHashJoinExec(p.left_keys, p.right_keys,
                                       p.join_type, p.condition,
                                       p.children[0], p.children[1],
-                                      p.null_safe)
+                                      p.null_safe, p.selection)
     out.subpartition_threshold = C.parse_bytes(
         m.conf.get(C.JOIN_SUBPARTITION_THRESHOLD.key))
     out.num_subpartitions = int(m.conf.get(C.JOIN_NUM_SUBPARTITIONS.key))

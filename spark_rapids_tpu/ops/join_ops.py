@@ -626,14 +626,19 @@ def unmatched_positions(flags, row_count: int):
 def gather_join_output(probe: ColumnarBatch, build: ColumnarBatch,
                        l_map, r_map, count,
                        names: Optional[List[str]] = None,
-                       out_bucket: Optional[int] = None) -> ColumnarBatch:
+                       out_bucket: Optional[int] = None,
+                       cols: Optional[Sequence[int]] = None
+                       ) -> ColumnarBatch:
     """Materializes join output rows: probe columns gathered by ``l_map``,
     build columns by ``r_map``; a negative map entry yields a null row for
-    that side (outer-join null extension).  ``count`` may be a
-    :class:`DeferredCount` (no host sync) when ``out_bucket`` is given;
-    either map may be ``None``, meaning "all null rows for that side"
-    (the constant -1 map is generated inside the program — shipping a
-    bucket-sized host constant would cost a real transfer)."""
+    that side (outer-join null extension).  ``cols`` names the output
+    columns as ordinals over ``probe.columns + build.columns``, in output
+    order (``None``: all of them): a column it leaves out is not
+    gathered.  ``count`` may be a :class:`DeferredCount` (no host sync)
+    when ``out_bucket`` is given; either map may be ``None``, meaning "all
+    null rows for that side" (the constant -1 map is generated inside the
+    program — shipping a bucket-sized host constant would cost a real
+    transfer)."""
     from spark_rapids_tpu.columnar.column import (DeferredCount,
                                                   rc_traceable)
     jnp = _jx()
@@ -650,60 +655,59 @@ def gather_join_output(probe: ColumnarBatch, build: ColumnarBatch,
         return jnp.pad(jnp.asarray(m), (0, pad), constant_values=-1)
 
     l_map, r_map = _pad(l_map), _pad(r_map)
+    n_probe = len(probe.columns)
+    protos = list(probe.columns) + list(build.columns)
+    if cols is not None:
+        protos = [protos[o] for o in cols]
+    from_build = tuple(o >= n_probe for o in
+                       (range(len(protos)) if cols is None else cols))
     key = ("jgather", out_bucket, maps_bucket,
            l_map is None, r_map is None,
-           tuple(_col_sig(c) for c in probe.columns),
-           tuple(_col_sig(c) for c in build.columns))
+           tuple(zip(from_build, (_col_sig(c) for c in protos))))
     def build_fn():
-        p_bucket, b_bucket = probe.bucket, build.bucket
         no_l, no_r = l_map is None, r_map is None
 
-        def run(parrs, barrs, l_map, r_map, count):
-            r = jnp.arange(out_bucket, dtype=np.int64)
-            live = r < count
-            safe_r = jnp.clip(r, 0, maps_bucket - 1)
-            neg = jnp.full(out_bucket, -1, dtype=np.int64)
-            lm = neg if no_l else jnp.take(l_map, safe_r)
-            rm = neg if no_r else jnp.take(r_map, safe_r)
+        def rows(m, absent):
+            # the map's first ``out_bucket`` entries, by a static slice
+            # (-1 past its end): positions past ``count`` are dead rows
+            if absent:
+                return jnp.full(out_bucket, -1, dtype=np.int64)
+            if maps_bucket >= out_bucket:
+                return m[:out_bucket]
+            return jnp.concatenate(
+                [m, jnp.full(out_bucket - maps_bucket, -1, dtype=m.dtype)])
+
+        def run(arrs, l_map, r_map, count):
+            live = jnp.arange(out_bucket, dtype=np.int64) < count
+            lm, rm = rows(l_map, no_l), rows(r_map, no_r)
             outs = []
-            for (d, v, ln, ev) in parrs:
-                sl = jnp.clip(lm, 0, p_bucket - 1)
-                nd = jnp.take(d, sl, axis=0)
-                nv = jnp.take(v, sl, axis=0) & (lm >= 0) & live
-                nl = None if ln is None else jnp.take(ln, sl, axis=0)
-                ne = None if ev is None else jnp.take(ev, sl, axis=0)
-                outs.append((nd, nv, nl, ne))
-            for (d, v, ln, ev) in barrs:
-                sr = jnp.clip(rm, 0, b_bucket - 1)
-                nd = jnp.take(d, sr, axis=0)
-                nv = jnp.take(v, sr, axis=0) & (rm >= 0) & live
-                nl = None if ln is None else jnp.take(ln, sr, axis=0)
-                ne = None if ev is None else jnp.take(ev, sr, axis=0)
+            for right, (d, v, ln, ev) in zip(from_build, arrs):
+                m = rm if right else lm
+                s = jnp.clip(m, 0, d.shape[0] - 1)
+                nd = jnp.take(d, s, axis=0)
+                nv = jnp.take(v, s, axis=0) & (m >= 0) & live
+                nl = None if ln is None else jnp.take(ln, s, axis=0)
+                ne = None if ev is None else jnp.take(ev, s, axis=0)
                 outs.append((nd, nv, nl, ne))
             return outs
 
         return run
     from spark_rapids_tpu.exec.stage_compiler import get_or_build
     fn = get_or_build("join.gather", key, build_fn)
-    parrs = [(c.data, c.validity, c.lengths, c.elem_valid)
-             for c in probe.columns]
-    barrs = [(c.data, c.validity, c.lengths, c.elem_valid)
-             for c in build.columns]
+    arrs = [(c.data, c.validity, c.lengths, c.elem_valid) for c in protos]
     zero = np.zeros(0, np.int64)
-    outs = fn(parrs, barrs,
+    outs = fn(arrs,
               zero if l_map is None else l_map,
               zero if r_map is None else r_map,
               rc_traceable(count))
     if isinstance(count, DeferredCount) and count.is_forced:
         count = int(count)
-    cols = []
     from spark_rapids_tpu.columnar.encoding import rewrap_like
-    protos = list(probe.columns) + list(build.columns)
-    for (d, v, ln, ev), proto in zip(outs, protos):
-        # dictionary payload columns gather their code planes and stay
-        # encoded through the join (late materialization)
-        cols.append(rewrap_like(proto, d, v, count, ln, ev))
-    return ColumnarBatch(cols, count, names)
+    # dictionary payload columns gather their code planes and stay
+    # encoded through the join (late materialization)
+    return ColumnarBatch([rewrap_like(proto, d, v, count, ln, ev)
+                          for (d, v, ln, ev), proto in zip(outs, protos)],
+                         count, names)
 
 
 def concat_matched_unmatched(l, r, n, ul, un):

@@ -238,23 +238,26 @@ def _delivered(node: Exec, memo) -> FrozenSet:    # noqa: C901 - dispatch
                          TpuBroadcastHashJoinExec)):
         jt = node.join_type
         left = delivered_dists(node.children[0], memo)
+        # over left ++ right, then through the join's output selection
         out = set()
         if jt in (J.INNER, J.LEFT_OUTER, J.LEFT_SEMI, J.LEFT_ANTI):
             out |= {d for d in left if not isinstance(d, SingleDist)}
-        if jt in (J.LEFT_SEMI, J.LEFT_ANTI) or \
-                isinstance(node, (CpuBroadcastHashJoinExec,
-                                  TpuBroadcastHashJoinExec)):
-            # semi/anti emit the left schema only; broadcast replicates
-            # the build side, so only the stream side's placement holds
-            return frozenset(out)
-        if jt in (J.INNER, J.RIGHT_OUTER):
+        # semi/anti emit the left schema only; broadcast replicates the
+        # build side, so only the stream side's placement holds
+        if jt in (J.INNER, J.RIGHT_OUTER) and not isinstance(
+                node, (CpuBroadcastHashJoinExec, TpuBroadcastHashJoinExec)):
             nl = len(node.children[0].schema.fields)
             for d in delivered_dists(node.children[1], memo):
                 if isinstance(d, HashDist):
                     out.add(HashDist(tuple(_shift_refs(k, nl)
                                            for k in d.keys),
                                      d.n, d.mesh_axis))
-        return frozenset(out)
+        if node.selection is None:
+            return frozenset(out)
+        # a distribution whose key column the join does not hand on is
+        # no longer delivered
+        return _remap_by_map(out, {("ref", o): ("ref", j)
+                                   for j, o in enumerate(node.selection)})
 
     # -- projections (both tiers) ---------------------------------------
     if isinstance(node, XB.CpuProjectExec) or \

@@ -58,6 +58,7 @@ class _Pruner:
         from spark_rapids_tpu.exec import sort as S
         from spark_rapids_tpu.exec import aggregate as AG
         from spark_rapids_tpu.exec import exchange as EX
+        from spark_rapids_tpu.exec import expand as EP
 
         ncols = len(node.schema.fields)
         if required is not None and len(required) >= ncols:
@@ -142,6 +143,23 @@ class _Pruner:
             new = AG.CpuHashAggregateExec(grouping, aggs, node.mode, child)
             return new, _identity(ncols)
 
+        if type(node) is EP.CpuExpandExec:
+            # every projection keeps the columns the parent reads (one at
+            # least: a batch of no column loses its row count); the child
+            # delivers what any of them reads
+            keep = sorted(required) if required is not None \
+                else list(range(ncols))
+            keep = keep or [0]
+            child_req = set()
+            for proj in node.projections:
+                for j in keep:
+                    _refs(proj[j], child_req)
+            child, cmap = self.prune(node.child, child_req)
+            new = EP.CpuExpandExec([[_remap(proj[j], cmap) for j in keep]
+                                    for proj in node.projections],
+                                   [node.names[j] for j in keep], child)
+            return new, {o: i for i, o in enumerate(keep)}
+
         if isinstance(node, JX._CpuJoinCore) and type(node) in (
                 JX.CpuShuffledHashJoinExec, JX.CpuBroadcastHashJoinExec,
                 JX.CpuBroadcastNestedLoopJoinExec):
@@ -167,23 +185,20 @@ class _Pruner:
         return node.with_children(children), _identity(ncols)
 
     def _prune_join(self, node, required: Optional[Set[int]]):
-        from spark_rapids_tpu.exec import joins as JX
+        """The children deliver what the parent reads plus the keys and
+        the condition's columns; the join hands on only what the parent
+        reads (its ``selection``), so a key or a filter-only column no
+        consumer names is never gathered at the pair bucket."""
         import spark_rapids_tpu.ops.join_ops as J
         nl = len(node.left.schema.fields)
-        nr = len(node.right.schema.fields)
-        semi = node.join_type in (J.LEFT_SEMI, J.LEFT_ANTI)
-
-        lreq: Set[int] = set()
-        rreq: Set[int] = set()
-        if required is None:
-            lreq = set(range(nl))
-            rreq = set(range(nr))
-        else:
-            for o in required:
-                if o < nl:
-                    lreq.add(o)
-                elif not semi:
-                    rreq.add(o - nl)
+        # the parent's ordinals over the join's output, and the columns of
+        # left ++ right they name, in output order
+        pair_of = node.selection if node.selection is not None \
+            else range(len(node._pair_fields()))
+        reads = range(len(pair_of)) if required is None else sorted(required)
+        out = [pair_of[o] for o in reads]
+        lreq: Set[int] = {o for o in out if o < nl}
+        rreq: Set[int] = {o - nl for o in out if o >= nl}
         for e in node.left_keys:
             _refs(e, lreq)
         for e in node.right_keys:
@@ -195,10 +210,6 @@ class _Pruner:
                 lreq.add(o)
             else:
                 rreq.add(o - nl)
-        if semi:
-            # right side still feeds keys/condition even though its columns
-            # never reach the output
-            pass
 
         left, lmap = self.prune(node.left, lreq)
         right, rmap = self.prune(node.right, rreq)
@@ -209,16 +220,18 @@ class _Pruner:
 
         cond = None if node.condition is None else \
             _remap(node.condition, {o: pair_map(o) for o in cond_refs})
+        width = nl_new + (0 if node.join_type in (J.LEFT_SEMI, J.LEFT_ANTI)
+                          else len(right.schema.fields))
+        selection = [pair_map(o) for o in out]
+        out_map = {o: j for j, o in enumerate(reads)}
+        if not selection and width:
+            # a batch of no column loses its row count: keep the first
+            selection = [0]
         new = type(node)(
             [_remap(e, lmap) for e in node.left_keys],
             [_remap(e, rmap) for e in node.right_keys],
-            node.join_type, cond, left, right, node.null_safe)
-        out_map: Dict[int, int] = {}
-        for o in lmap:
-            out_map[o] = lmap[o]
-        if not semi:
-            for o in rmap:
-                out_map[nl + o] = nl_new + rmap[o]
+            node.join_type, cond, left, right, node.null_safe,
+            None if selection == list(range(width)) else selection)
         return new, out_map
 
 

@@ -47,7 +47,9 @@ def subtree(lines, at):
 
 def parents_plans() -> dict:
     """``explain()`` of the four texts at one partition at the parent of
-    the PR that added the size rule (6056c83), literals ``nth(0)``."""
+    the PR that added the size rule (6056c83), literals ``nth(0)``; q27's
+    with its scans narrowed since column pruning reads through the
+    roll-up's ``Expand``."""
     with open(os.path.join(HERE, "store_plans_one_partition.json")) as f:
         return json.load(f)
 

@@ -620,6 +620,29 @@ def test_ring_drops_surface_in_prometheus():
     assert samples[name] >= 5
 
 
+def test_a_ring_collected_under_the_drop_lock_does_not_deadlock():
+    """A garbage collection can run a ring's finalizer on a thread that
+    holds the drop lock (a new ring registering its cell): the finalizer
+    must not wait for that lock, and the ring's drops stay counted."""
+    import gc
+    import threading
+    ring = EV.RingBufferSink(capacity=1)
+    for i in range(4):
+        ring.emit(EV.Event("spill", 1, 1, float(i), {}))
+    before = EV.ring_dropped_total()
+    ring.cycle = [ring]
+    del ring
+
+    def collect_holding_the_lock():
+        with EV._DROP_LOCK:
+            gc.collect()
+    t = threading.Thread(target=collect_holding_the_lock, daemon=True)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive(), "the ring's finalizer waited for the drop lock"
+    assert EV.ring_dropped_total() == before
+
+
 def test_prometheus_format_types_escaping_monotonicity():
     s = tpu_session({"spark.rapids.sql.test.enabled": "false"})
     s.create_dataframe({"a": np.arange(200, dtype=np.int64)}).count()

@@ -233,6 +233,26 @@ def test_direct_join_build_probe_and_pairs(tpu_branch):
     assert not TC.sort_operand_counts(prog, specs)
 
 
+def test_join_gather_writes_only_the_planes_handed_on(tpu_branch):
+    """``join.gather`` at a q3's first join: the fact table's planes at
+    4,194,304 rows, the dimension's at the floor's bucket, a 524,288-row
+    pair table, two of the five columns handed on.  One gather a plane it
+    writes, data and validity: the pair maps are read by a slice."""
+    import collections
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import join_ops as J
+    fact = _batch(1 << 22, T.LONG, T.INT, T.DOUBLE)
+    dim = _batch(SMALL, T.INT, T.INT)
+    maps = jnp.zeros(1 << 19, dtype=np.int32)
+    prog, specs = _compiles(tpu_branch, J.gather_join_output, fact, dim,
+                            maps, maps, 400_000, None, 1 << 19, (1, 4))
+    assert prog.kind == "join.gather"
+    traced = prog._fn.trace(*specs)
+    kinds = collections.Counter(
+        eqn.primitive.name for eqn, _ in TC._equations(traced.jaxpr.jaxpr))
+    assert kinds["gather"] == 4, kinds
+
+
 def test_window_over_partition(tpu_branch):
     from spark_rapids_tpu.ops.window_ops import compute_windows
     # payload (string, double) ++ partition key (string) ++ value (double)
